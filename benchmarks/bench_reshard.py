@@ -42,9 +42,9 @@ def main():
             flags +
             f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
-    # A single attached chip cannot exchange with itself; default to the
-    # virtual CPU mesh (override by exporting JAX_PLATFORMS before running
-    # on a real multi-device platform).
+    # One chip cannot exchange with itself: this script is the virtual
+    # CPU mesh rehearsal (chip_smoke.py --chips 4 drives the real
+    # four-chip reshard). Export JAX_PLATFORMS to run it elsewhere.
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -69,7 +69,7 @@ def main():
     valid = np.ones(n, bool)
 
     def sync(cols):
-        _common.sync_fetch(list(cols), all_leaves=True)
+        jax.block_until_ready(list(cols))
 
     # --- Host-staged: permute on host, upload sharded. -------------------
     def run_host():

@@ -2,9 +2,7 @@
 
 Runs large_p.aggregate_blocked with its phase_times profiling hook, so the
 reported breakdown (pass-1 bound+compact, block-offset searchsorted, block
-dispatch+drain) times the shipped implementation, not a replica. Round-3
-context: the pre-rework path spent ~5.8s/11s in device->host transfers of
-full padded columns; the reworked path transfers O(kept) only.
+dispatch+drain) times the shipped implementation, not a replica.
 """
 import os
 
@@ -23,10 +21,10 @@ n = int(os.environ.get("BENCH_ROWS", 2**22))
 _, cfg, stds, (min_v, max_v, min_s, max_s, mid) = _common.build_spec(P)
 pid, pk, values, valid = _common.zipfish_data(n, P)
 
-# Null dispatch + scalar-fetch round trip (shared helper, min-of-3):
-# divide the per-block sync/drain phases below by this to count round
-# trips rather than seconds.
-print(f"null dispatch+fetch round trip: "
+# Null dispatch round trip (shared helper, min-of-3): divide the
+# per-block sync/drain phases below by this to count round trips rather
+# than seconds.
+print(f"null dispatch round trip: "
       f"{_common.null_roundtrip() * 1e3:.1f} ms", flush=True)
 
 

@@ -8,7 +8,7 @@ report, write results to a file.
 
 TPU-first difference: by default the aggregation runs on the fused columnar
 device backend (pipelinedp_tpu.TPUBackend) — one jit-compiled XLA program —
-on whatever accelerator JAX finds (falls back to CPU automatically), and
+on the accelerator JAX finds (JAX_PLATFORMS=cpu runs it CPU-only), and
 file parsing is vectorized (netflix_format.parse_file_columns).
 
 Usage:

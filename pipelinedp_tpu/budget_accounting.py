@@ -277,7 +277,8 @@ class BudgetAccountant(abc.ABC):
         count (0.0 before compute_budgets). The odometer's per-record
         eps values sum to exactly this number — the reconciliation the
         audit trail is checked against."""
-        return sum(
+        from pipelinedp_tpu.runtime import observability
+        return observability.fold_spend(
             m.mechanism_spec._eps * m.mechanism_spec.count
             for m in self._mechanisms
             if m.mechanism_spec._eps is not None)

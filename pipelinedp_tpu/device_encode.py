@@ -244,10 +244,7 @@ def prefers_lookup_codes() -> bool:
     codes, proven by the parity tests. Mirrors the backend dispatch of
     runtime/pipeline._donation_supported.
     """
-    try:
-        return jax.default_backend() == "cpu"
-    except RuntimeError:  # backend init failed; keep the generic kernel
-        return False
+    return jax.default_backend() == "cpu"
 
 
 def build_lookup_table(sorted_hashes: np.ndarray,

@@ -1419,9 +1419,8 @@ def lazy_select_partitions(backend, col, params, data_extractors,
         threshold = getattr(backend, "large_partition_threshold", None)
         if threshold is not None and n_partitions > threshold:
             # Huge partition spaces: neither the dense count vector nor
-            # the bool[P] keep vector (whose wholesale download would
-            # dominate under a remote-attached chip) is ever materialized
-            # — the blocked path transfers O(kept) ids only. With a mesh
+            # the bool[P] keep vector is ever materialized — the
+            # blocked path transfers O(kept) ids only. With a mesh
             # the blocked path itself runs sharded (pid-sharded pass 1,
             # one int32[C] psum per block).
             from pipelinedp_tpu.parallel import large_p

@@ -1,7 +1,10 @@
 """ctypes loader for the native DP primitives library.
 
 Builds `_dp_primitives.so` from dp_primitives.cc on first use (g++, no
-external deps) and exposes typed wrappers. Everything here has a pure
+external deps) — and again whenever the source is newer than the binary,
+so a stale build can never shadow the committed source — and exposes
+typed wrappers. `status()` says which of the two (native / fallback) a
+process is running on and whether the build failed. Everything here has a pure
 Python/numpy fallback elsewhere in the package — `available()` gates use —
 but when present the native library provides:
 
@@ -35,17 +38,33 @@ _i32p = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
 _u8p = np.ctypeslib.ndpointer(dtype=np.uint8, flags="C_CONTIGUOUS")
 
 
+def _needs_build() -> bool:
+    """The binary is missing, or older than the source it was built
+    from (git ignores the binary, so the source can move under it)."""
+    src = os.path.join(_dir, _SRC_NAME)
+    out = os.path.join(_dir, _LIB_NAME)
+    return (not os.path.exists(out) or
+            os.path.getmtime(src) > os.path.getmtime(out))
+
+
 def _try_build() -> bool:
     src = os.path.join(_dir, _SRC_NAME)
     out = os.path.join(_dir, _LIB_NAME)
+    # Build beside the target and rename: concurrent processes (test
+    # workers) never load a half-written library.
+    tmp = f"{out}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", out, src],
+            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", tmp, src],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, out)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         logging.warning("native DP primitives build failed: %s", e)
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _bind(lib) -> None:
@@ -95,7 +114,7 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         path = os.path.join(_dir, _LIB_NAME)
         # staticcheck: disable=lock-order — intentional build serialization: exactly one thread compiles the library while every other caller waits for it; the double-checked fast path above never takes the lock, so steady state is lock-free
-        if not os.path.exists(path) and not _try_build():
+        if _needs_build() and not _try_build():
             _load_failed = True  # staticcheck: disable=thread-escape — double-checked lazy init: this write-once publish happens under _lock; the unlocked fast-path read either sees the final value or falls through to the locked re-check
             return None
         try:
@@ -111,6 +130,18 @@ def _load() -> Optional[ctypes.CDLL]:
 def available() -> bool:
     """True if the native library could be built/loaded."""
     return _load() is not None
+
+
+def status() -> dict:
+    """Which implementation this process runs on: ``in_use`` (the
+    library loaded; False = the numpy/Python fallbacks) and
+    ``build_failed`` (no usable library AND the binary is still
+    missing or stale — _load() builds whenever that is so, hence the
+    build was attempted and failed: no g++, a compile error). Loads
+    the library if nothing has yet."""
+    in_use = available()
+    return {"in_use": in_use,
+            "build_failed": not in_use and _needs_build()}
 
 
 def seed_test_rng(seed: int) -> None:

@@ -31,11 +31,16 @@ dispatches instead:
     no retrace, bit-identical results (the executable IS the program
     jit would have dispatched).
 
-Fallback discipline: AOT is an optimization, never a semantic: any
-failure to bind/lower/compile/execute falls back to the probed jit path
-for that call (lower/compile failures disable the entry for the
-process, with one warning), so an exotic argument mix can slow a call
-down but can never fail it.
+Fallback discipline: exactly two cases degrade to the probed jit path,
+each counted in the ``aot_fallbacks`` telemetry counter (0 on a healthy
+run — the chip smoke asserts it): an argument mix ``.lower()`` rejects
+with a TypeError (the entry is disabled for the process, one warning),
+and a cached executable rejecting its arguments at the call boundary
+(TypeError/ValueError from the compiled object's input type/sharding
+check — a key dimension the fingerprint missed). Everything else — a
+compile error, a runtime failure of the executable — raises: the jit
+path would run the same program and fail the same way, and a service
+that silently runs none of its AOT cache must not look healthy.
 """
 
 import collections
@@ -186,6 +191,17 @@ def fingerprint(dyn_kwargs: Dict[str, Any]):
     return (treedef, tuple(_leaf_sig(leaf) for leaf in leaves))
 
 
+def _has_tracer(tree) -> bool:
+    """Whether any argument leaf is a jax Tracer — i.e. the call sits
+    inside another jit/vmap/shard_map trace (e.g.
+    select_kept_pair_stream called from the sharded pass-1 body). A
+    compiled executable cannot consume tracers; the inner call inlines
+    into the outer program via the jit path instead."""
+    import jax
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
 def aot_probe(name: str, jitted_fn, static_argnames: Tuple[str, ...] = (),
               signature_from=None):
     """Wraps a jitted entry point with AOT routing + probe attribution.
@@ -207,50 +223,40 @@ def aot_probe(name: str, jitted_fn, static_argnames: Tuple[str, ...] = (),
 
     @functools.wraps(jitted_fn)
     def wrapper(*args, **kwargs):
-        if not enabled() or failed:
+        if not enabled() or failed or _has_tracer((args, kwargs)):
             return probed(*args, **kwargs)
         from pipelinedp_tpu.runtime import telemetry
         import jax
-        try:
-            # Inside another jit trace (e.g. select_kept_pair_stream
-            # called from the sharded pass-1 body) arguments are
-            # tracers: a compiled executable cannot consume them — the
-            # inner call inlines into the outer program via the jit
-            # path instead.
-            if not jax.core.trace_state_clean():
-                return probed(*args, **kwargs)
-        except AttributeError:
-            pass
-        try:
-            bound = sig.bind(*args, **kwargs)
-            bound.apply_defaults()
-            static_kw = {k: v for k, v in bound.arguments.items()
-                         if k in statics}
-            dyn_kw = {k: v for k, v in bound.arguments.items()
-                      if k not in statics}
-            key = (name,
-                   tuple((k, repr(v)) for k, v in sorted(static_kw.items())),
-                   fingerprint(dyn_kw), jax.default_backend())
-        except Exception as e:  # noqa: BLE001 - an unfingerprintable argument mix must degrade to the jit path, never fail the dispatch
-            logging.debug("aot: %s key build failed (%s: %s); jit path.",
-                          name, type(e).__name__, e)
-            return probed(*args, **kwargs)
+        bound = sig.bind(*args, **kwargs)
+        bound.apply_defaults()
+        static_kw = {k: v for k, v in bound.arguments.items()
+                     if k in statics}
+        dyn_kw = {k: v for k, v in bound.arguments.items()
+                  if k not in statics}
+        key = (name,
+               tuple((k, repr(v)) for k, v in sorted(static_kw.items())),
+               fingerprint(dyn_kw), jax.default_backend())
         cache = _global_cache
         executable = cache.lookup(name, key)
         if executable is None:
             t0 = time.perf_counter()
-            try:
-                with rt_trace.span("aot_compile:" + name):
-                    executable = jitted_fn.lower(**static_kw,
-                                                 **dyn_kw).compile()
-            except Exception as e:  # noqa: BLE001 - lowering is best-effort: entries that cannot lower (donation, exotic pytrees) permanently fall back to the probed jit path
-                failed.append(True)
-                logging.warning(
-                    "aot: lowering %s failed (%s: %s); this entry point "
-                    "falls back to the traced jit path for the rest of "
-                    "the process. Warning once.", name, type(e).__name__,
-                    e)
-                return probed(*args, **kwargs)
+            with rt_trace.span("aot_compile:" + name):
+                try:
+                    lowered = jitted_fn.lower(**static_kw, **dyn_kw)
+                except TypeError as e:
+                    # The one expected lowering degrade: an argument
+                    # mix .lower() cannot express. Compile errors
+                    # propagate.
+                    failed.append(True)
+                    telemetry.record("aot_fallbacks", entry=name,
+                                     stage="lower")
+                    logging.warning(
+                        "aot: lowering %s failed (%s: %s); this entry "
+                        "point falls back to the traced jit path for the "
+                        "rest of the process. Warning once.", name,
+                        type(e).__name__, e)
+                    return probed(*args, **kwargs)
+                executable = lowered.compile()
             cache.store(name, key, executable)
             dt = time.perf_counter() - t0
             rt_trace.note_compile("aot:" + name, dt)
@@ -260,10 +266,15 @@ def aot_probe(name: str, jitted_fn, static_argnames: Tuple[str, ...] = (),
         try:
             with rt_trace.span("aot:" + name):
                 return executable(**dyn_kw)
-        except Exception as e:  # noqa: BLE001 - classified below: an executable/argument mismatch (a key dimension XLA specializes on that the fingerprint missed) degrades to the jit path; real runtime failures re-raise from it identically
+        except (TypeError, ValueError) as e:
+            # The compiled object's own input check (argument
+            # types/shardings differ from what it was compiled for): a
+            # key dimension the fingerprint missed. Runtime failures of
+            # the executable are neither type and propagate.
+            telemetry.record("aot_fallbacks", entry=name, stage="execute")
             logging.warning(
-                "aot: executing the cached %s executable failed (%s: "
-                "%s); retrying through the traced jit path.", name,
+                "aot: the cached %s executable rejected its arguments "
+                "(%s: %s); retrying through the traced jit path.", name,
                 type(e).__name__, e)
             return probed(*args, **kwargs)
 

@@ -553,6 +553,24 @@ def prune_odometer(accountant=None, job_id: Optional[str] = None) -> int:
     return removed
 
 
+def fold_spend(shares) -> float:
+    """THE epsilon/delta spend fold: a plain left-to-right float64
+    accumulation from 0.0 in iteration order.
+
+    Every "bit-exact naive sum" in the package — the accountant's
+    ``spent_epsilon``, the tenant ledger's spend/snapshot/admission
+    numbers and ``odometer_report`` — goes through this one function,
+    so they reconcile with ``==`` whatever the element type. Builtin
+    ``sum()`` is NOT this fold: on Python >= 3.12 it is compensated
+    for exact ``float`` items and plain for ``np.float64``, i.e. two
+    algorithms chosen by the type a share happens to arrive as.
+    """
+    total = 0.0
+    for share in shares:
+        total += share
+    return total
+
+
 def odometer_report(accountant=None,
                     job_id: Optional[str] = None) -> Dict[str, Any]:
     """Spent-vs-remaining over the ordered audit trail.
@@ -574,16 +592,11 @@ def odometer_report(accountant=None,
         records = [r for r in records if r.accountant() is accountant]
     if job_id is not None:
         records = [r for r in records if r.job_id == job_id]
-    spent_eps = 0.0
-    spent_delta = 0.0
-    pending = 0
-    for r in records:
-        if r.eps is None:
-            pending += 1
-        else:
-            spent_eps += r.eps * r.count
-            if r.delta:
-                spent_delta += r.delta * r.count
+    computed = [r for r in records if r.eps is not None]
+    pending = len(records) - len(computed)
+    spent_eps = fold_spend(r.eps * r.count for r in computed)
+    spent_delta = fold_spend(r.delta * r.count for r in computed
+                             if r.delta)
     report: Dict[str, Any] = {
         "records": [r.to_dict() for r in records],
         "mechanisms": len(records),

@@ -21,7 +21,7 @@ device-resident pipeline instead of one serial batch call:
     encoded chunks append into persistent device buffers sized to
     power-of-two row buckets (``executor.row_bucket``, the same buckets
     ``pad_rows`` uses), with the previous buffer DONATED to XLA on every
-    append/grow so steady-state appends reuse device memory instead of
+    append so steady-state appends reuse device memory instead of
     allocating per chunk. ``finalize()`` returns buffers bit-identical
     to ``executor.pad_rows`` over the concatenated rows — pipelined and
     serial execution therefore feed the fused kernel the exact same
@@ -295,18 +295,15 @@ def _donation_supported() -> bool:
     the accumulator then stages chunks and concatenates once instead of
     copying the whole buffer on every append."""
     import jax
-    try:
-        return jax.default_backend() != "cpu"
-    except RuntimeError:  # backend init failed; stay conservative
-        return False
+    return jax.default_backend() != "cpu"
 
 
 @functools.lru_cache(maxsize=None)
-def _append_fn(donate: bool):
+def _append_fn():
     """Jitted chunk append: writes one bucket-padded chunk into the
-    persistent buffers at a traced row offset. With donate=True the
-    previous buffers are donated to XLA, so the append updates device
-    memory in place instead of allocating a fresh copy per chunk."""
+    persistent buffers at a traced row offset. The previous buffers are
+    donated to XLA, so the append updates device memory in place
+    instead of allocating a fresh copy per chunk."""
     import jax
 
     def _append_impl(bufs, chunk, offset):
@@ -316,18 +313,19 @@ def _append_fn(donate: bool):
 
         return tuple(upd(b, c) for b, c in zip(bufs, chunk))
 
-    jitted = jax.jit(_append_impl,
-                     donate_argnums=(0,) if donate else ())
+    jitted = jax.jit(_append_impl, donate_argnums=(0,))
     return rt_trace.probe_jit("pipeline_append", jitted)
 
 
 @functools.lru_cache(maxsize=None)
-def _grow_fn(donate: bool, fills: tuple = (0, -1, 0)):
+def _grow_fn(fills: tuple = (0, -1, 0)):
     """Jitted buffer growth to a larger power-of-two bucket; pad rows
     carry the accumulator's pad values (the executor.pad_rows pid 0 /
     pk -1 / values 0 on the host-encoded route, hash sentinels on the
     hash-device route) so the tail is indistinguishable from a fresh
-    pad."""
+    pad. Not donating: a larger output can never alias the smaller
+    input (the chip's compiler reports such donated buffers unusable),
+    and the old buffers are released when the accumulator rebinds."""
     import jax
     import jax.numpy as jnp
 
@@ -339,8 +337,7 @@ def _grow_fn(donate: bool, fills: tuple = (0, -1, 0)):
 
         return tuple(grown(b, f) for b, f in zip(bufs, fills))
 
-    jitted = jax.jit(_grow_impl, static_argnames=("new_cap",),
-                     donate_argnums=(0,) if donate else ())
+    jitted = jax.jit(_grow_impl, static_argnames=("new_cap",))
     return rt_trace.probe_jit("pipeline_grow", jitted)
 
 
@@ -354,7 +351,7 @@ class DeviceRowAccumulator:
     Two modes, bit-identical results:
 
       * **donating** (accelerators): persistent (pid, pk, values)
-        buffers sized to power-of-two row buckets; every append/grow
+        buffers sized to power-of-two row buckets; every append
         donates the previous buffers to XLA so device memory is reused
         across chunks instead of reallocated. Appended chunks must
         arrive bucket-padded with the pad_rows pad values (pid 0, pk -1,
@@ -491,10 +488,9 @@ class DeviceRowAccumulator:
             cap = self._bufs[0].shape[0]
             need = self._n + pid.shape[0]
             if need > cap:
-                self._bufs = _grow_fn(True, self.fills)(
+                self._bufs = _grow_fn(self.fills)(
                     self._bufs, new_cap=_pow2_at_least(need))
-            self._bufs = _append_fn(True)(self._bufs, chunk_bufs,
-                                          self._n)
+            self._bufs = _append_fn()(self._bufs, chunk_bufs, self._n)
             self._n += n_real
             self._refresh_accounting()
 

@@ -19,8 +19,9 @@ discipline therefore has two halves:
     released) an output for those partitions.
 
 Error classification is by marker substrings over the PJRT/XLA exception
-text (there is no stable cross-version exception taxonomy to type-match)
-plus the injection harness's typed exceptions.
+text (the installed runtime raises one JaxRuntimeError type for every
+status; the status code is only in its message) plus the injection
+harness's typed exceptions.
 """
 
 import contextlib

@@ -120,6 +120,13 @@ REGISTRY: Dict[str, Metric] = {
                  "executable (first call for a (spec, shape, mesh, "
                  "dtype) key; 0 on a second identical-spec job is the "
                  "cross-job reuse proof)"),
+        _counter("aot_fallbacks",
+                 "AOT entry-point calls that degraded to the traced jit "
+                 "path: .lower() rejected the argument mix, or a cached "
+                 "executable rejected its arguments at the call "
+                 "boundary. 0 on a healthy run — anything else means "
+                 "the AOT cache is not serving the dispatches it "
+                 "claims"),
         _counter("release_dispatches",
                  "device program launches plus blocking host "
                  "materializations on the executor/driver release path "

@@ -1,11 +1,10 @@
 """Span-based pipeline tracing: where the wall clock of a run goes.
 
-The kernel headline (BENCH_r05_builder.json: 60.8M rec/s/chip) and the
-warm end-to-end number (292K rows/s) differ by ~200x, and until this
-module nothing in the repo could *prove where* the other ~199x goes:
-telemetry was a flat counter bag plus coarse min/max/sum phase timings —
-no causality, no per-block timeline, no transfer or compile attribution.
-This module turns every run into an exportable, attributable trace:
+A bare-kernel rate and a file-to-release rate can differ by orders of
+magnitude, and a flat counter bag plus coarse min/max/sum phase timings
+cannot *prove where* the difference goes — no causality, no per-block
+timeline, no transfer or compile attribution. This module turns every
+run into an exportable, attributable trace:
 
   * **Spans** — ``with trace.span("drain", block=b):`` records one timed,
     nested, thread- and job-scoped interval. Spans carry arbitrary

@@ -106,23 +106,25 @@ class TenantLedger:
 
     @staticmethod
     def _job_sums(records: List[Dict[str, Any]]) -> Dict[str, float]:
-        """Per-job eps sums, each folded in record order — the same
-        left-to-right sum BudgetAccountant.spent_epsilon() computes, so
-        a job's ledger spend reproduces its accountant bit-exactly."""
-        sums: Dict[str, float] = {}
+        """Per-job eps sums, each folded in record order through
+        observability.fold_spend — the fold
+        BudgetAccountant.spent_epsilon() uses, so a job's ledger spend
+        reproduces its accountant bit-exactly."""
+        shares: Dict[str, List[float]] = {}
         for r in records:
             if r.get("eps") is None:
                 continue
-            job = r.get("job_id") or ""
-            sums[job] = sums.get(job, 0.0) + r["eps"] * r.get("count", 1)
-        return sums
+            shares.setdefault(r.get("job_id") or "", []).append(
+                r["eps"] * r.get("count", 1))
+        return {job: observability.fold_spend(s)
+                for job, s in shares.items()}
 
     def spent_epsilon(self) -> float:
         """Cumulative recorded spend: the sum of per-job spends (each
         bit-exact vs its accountant), in first-recorded job order."""
         with self._lock:
             records = list(self._records)
-        return sum(self._job_sums(records).values())
+        return observability.fold_spend(self._job_sums(records).values())
 
     def job_spent_epsilon(self, job_id: str) -> float:
         """One job's recorded spend (0.0 when the job never charged)."""
@@ -150,7 +152,7 @@ class TenantLedger:
             if self._pld_cache_version == version:
                 return self._pld_cached
             records = list(self._records)
-        naive = sum(self._job_sums(records).values())
+        naive = observability.fold_spend(self._job_sums(records).values())
         from pipelinedp_tpu.accounting import compose as compose_engine
         try:
             composed, _ = compose_engine.composed_epsilon_from_records(
@@ -232,7 +234,7 @@ class TenantLedger:
             records = list(self._records)
             reserved = dict(self._reserved)
         sums = self._job_sums(records)
-        spent = sum(sums.values())
+        spent = observability.fold_spend(sums.values())
         admission = (spent if self.accounting_mode != "pld" else
                      min(spent, pld_spent * (1.0 + PLD_ADMISSION_HEADROOM)))
         return {

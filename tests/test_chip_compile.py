@@ -1,0 +1,207 @@
+"""The served path's jitted programs, compiled for a described TPU v5e.
+
+No chip is needed: the TPU compiler is installed with jaxlib and compiles
+for a topology that is described, not attached (the on-chip-measurement
+guide, section 2.3). These tests guard every later PR against a program
+the chip's compiler refuses, at no chip time. Nothing runs, so they say
+nothing about results or speed.
+
+Shapes. Widths are the real ones — P = 17,770 (Netflix Prize movies),
+C = 2^20 (the blocked drivers' default block), 2^24-row accumulator
+buffers, a 4-device mesh. ROW counts of the sort-bearing programs are
+REDUCED to 2^12: a sort-bearing TPU compile has a large fixed cost and
+tier-1 cannot carry the full-size ones. Measured for PR 22 in this
+sandbox (8 cores, one compile per core), full size:
+
+    aggregate_release_kernel   2^24 rows, P=17,770   444 s   803 MB
+    aggregate_release_kernel   2^20 rows             615 s*   31 MB
+    blocked_bound_compact      2^22 rows, P=10^7     645 s*  153 MB
+    blocked_block_kernel       C=2^20, cap 3.1M       24 s    82 MB
+    select_kept_pair_stream    2^22 rows             420 s*   89 MB
+    device_factorize           2^22 rows             302 s*   97 MB
+    (* eight compiles side by side; alone they run ~1.5x faster)
+
+against ~18 s for the reduced dense release below. Code that asks
+jax.default_backend() takes its CPU branch under this rehearsal, so each
+test compiles the jitted function itself.
+"""
+
+import dataclasses
+import os
+import warnings
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from benchmarks import _common  # the bench spec (build_spec)
+from pipelinedp_tpu import device_encode, executor
+from pipelinedp_tpu.parallel import large_p, reshard, sharded
+from pipelinedp_tpu.parallel import mesh as mesh_lib
+from pipelinedp_tpu.runtime import pipeline as rt_pipeline
+
+HBM_BYTES = 16 * 2**30  # one v5e chip
+MOVIES = 17_770
+BLOCK = 1 << 20
+ROWS = 1 << 12  # reduced; see the module docstring
+F32, I32, U32 = np.float32, np.int32, np.uint32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described (not attached) chip. Described here, inside a
+    fixture of this one file: only one process at a time may load the
+    TPU library, and xdist workers import every test module."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe the chip means "cannot rehearse here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip; keep these compiles
+    out of it, and quiet."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo, no_persistent_cache):
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype, sharding=one):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    return shape
+
+
+def _x32(spec_fn):
+    """The chip runs with x64 off; this suite runs with it on. Specs
+    and lowerings happen under x64 off so the programs are the chip's."""
+    with jax.enable_x64(False):
+        return spec_fn()
+
+
+def _rows(shape, n, sharding=None):
+    kw = {} if sharding is None else {"sharding": sharding}
+    return (shape((n,), I32, **kw), shape((n,), I32, **kw),
+            shape((n,), F32, **kw), shape((n,), np.bool_, **kw))
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes +
+             m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total
+    return m
+
+
+@pytest.mark.parametrize("numeric_mode", ["fast", "safe"])
+def test_dense_release_compiles(chip, numeric_mode):
+    """The default fused route (executor.aggregate_release_kernel) at
+    P = 17,770 in both accumulation disciplines."""
+
+    def lower():
+        _, cfg, stds, _ = _common.build_spec(MOVIES)
+        cfg = dataclasses.replace(cfg, numeric_mode=numeric_mode)
+        scalars = [chip((), F32)] * 5
+        return executor.aggregate_release_kernel.lower(
+            *_rows(chip, ROWS), *scalars, chip((len(stds),), F32),
+            chip((2,), U32), cfg)
+
+    compiled = _x32(lower).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert text.count(" sort(") >= 3  # bounding, reduce, compaction
+    assert "f64" not in text
+
+
+def test_blocked_block_kernel_compiles(chip):
+    """One partition block at the real C = 2^20 (large_p), its row
+    gather capacity reduced."""
+
+    def lower():
+        _, cfg, stds, _ = _common.build_spec(10_000_000)
+        cfg_block = dataclasses.replace(cfg, n_partitions=BLOCK)
+        scalar_i, scalar_f = chip((), I32), chip((), F32)
+        return large_p._block_kernel_dev.lower(
+            chip((ROWS,), I32), chip((ROWS,), np.bool_),
+            {"sum": chip((ROWS,), F32)}, None, scalar_i, scalar_i,
+            scalar_i, scalar_f, scalar_f, scalar_f,
+            chip((len(stds),), F32), chip((2,), U32), cfg_block,
+            mesh_lib.round_capacity(ROWS))
+
+    compiled = _x32(lower).compile()
+    _fits(compiled)
+    assert " sort(" in compiled.as_text()
+
+
+def test_donated_append_and_grow_compile(chip):
+    """The streaming accumulator's pair at real 2^24-row buffers. The
+    append really donates on the chip (on CPU it is a warned no-op, so
+    no test had ever seen the alias); the grow does not donate."""
+    cap, chunk = 1 << 24, 1 << 20
+
+    def cols(n):
+        return (chip((n,), I32), chip((n,), I32), chip((n,), F32))
+
+    with warnings.catch_warnings():
+        warnings.filterwarnings("error", message=".*onated buffers.*")
+        append = _x32(lambda: rt_pipeline._append_fn().lower(
+            cols(cap), cols(chunk), chip((), I32))).compile()
+        grow = _x32(lambda: rt_pipeline._grow_fn().lower(
+            cols(cap // 2), new_cap=cap)).compile()
+    buffers = 3 * 4 * cap
+    assert _fits(append).alias_size_in_bytes == buffers
+    assert _fits(grow).alias_size_in_bytes == 0
+    assert _fits(grow).output_size_in_bytes >= buffers
+
+
+def test_device_factorize_compiles(chip):
+    """The sort/unique factorize an accelerator takes in hash_device
+    ingest (CPU runs take the lookup kernel instead)."""
+    compiled = _x32(lambda: device_encode._factorize_kernel.lower(
+        chip((ROWS, 3), U32))).compile()
+    _fits(compiled)
+    assert compiled.as_text().count(" sort(") >= 2
+
+
+def test_meshed_release_compiles_with_collectives(topo, chip):
+    """reshard="device" on a 4-device mesh: the all_to_all exchange,
+    then the sharded fused release with its psum."""
+    mesh = Mesh(np.asarray(topo.devices), (mesh_lib.SHARD_AXIS,))
+    rows = NamedSharding(mesh, P(mesh_lib.SHARD_AXIS))
+    repl = NamedSharding(mesh, P())
+    per_shard = mesh_lib.rows_per_shard(ROWS, 4)
+
+    exchange = _x32(lambda: reshard._exchange_kernel.lower(
+        *_rows(chip, 4 * per_shard, rows),
+        mesh_lib.round_capacity(per_shard // 2), per_shard, 4, 0,
+        mesh)).compile()
+    _fits(exchange)
+    assert "all-to-all" in exchange.as_text()
+
+    def lower():
+        _, cfg, stds, _ = _common.build_spec(MOVIES)
+        scalars = [chip((), F32, repl)] * 5
+        return sharded._sharded_release_kernel.lower(
+            *_rows(chip, 4 * per_shard, rows), *scalars,
+            chip((len(stds),), F32, repl), chip((2,), U32, repl), cfg, mesh)
+
+    release = _x32(lower).compile()
+    _fits(release)
+    assert "all-reduce" in release.as_text()

@@ -409,7 +409,7 @@ class TestHostFetchRetryKnobs:
             self.calls += 1
             if self.left > 0:
                 self.left -= 1
-                raise RuntimeError("UNAVAILABLE: tunnel hiccup")
+                raise RuntimeError("UNAVAILABLE: transient hiccup")
             return np.zeros(1)
 
     def test_fetch_retry_scope_threads_budget(self, monkeypatch):

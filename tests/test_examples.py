@@ -1,8 +1,8 @@
 """Smoke tests: every example must run end to end.
 
-Examples run CPU-pinned for determinism; additionally, when a healthy
-accelerator is reachable, the movie-ratings example re-runs on the actual
-device path (fused TPUBackend) with no platform pin.
+Examples run CPU-pinned for determinism; the `slow` device test re-runs
+the movie-ratings example with no platform pin where JAX finds an
+accelerator.
 """
 
 import os
@@ -74,38 +74,24 @@ def test_framework_example_runs(cmd):
     assert marker in proc.stdout
 
 
-def _accelerator_platform():
-    """Probes (in a killable subprocess) for a healthy non-CPU device."""
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    try:
-        probe = subprocess.run(
-            [sys.executable, "-c",
-             "import jax; print(jax.devices()[0].platform)"],
-            capture_output=True, text=True, timeout=90, env=env)
-    except subprocess.TimeoutExpired:
-        return None
-    if probe.returncode != 0 or not probe.stdout.strip():
-        return None
-    platform = probe.stdout.strip().splitlines()[-1]
-    return platform if platform != "cpu" else None
-
-
 @pytest.mark.slow
 def test_movie_example_on_device():
-    """The real-file-format example on the actual device path (TPU smoke).
+    """The real-file-format example on the actual device path.
 
-    `slow`: on an accelerator-less tier-1 box the probe subprocess
-    burns its full 90s timeout just to decide to skip; the example
-    itself is covered on CPU by the `--local` parametrization above.
+    One child, no probe: this process is pinned to CPU by conftest and
+    never holds the chip, so the example is the one process that may
+    take it (a second, probing child would only race it). Where JAX
+    finds no accelerator the child fails at backend start-up and the
+    test skips. chip_smoke.py's dense_file phase is the chip gate for
+    the same parser path; this stays for the example script itself.
     """
-    platform = _accelerator_platform()
-    if platform is None:
-        pytest.skip("no healthy accelerator reachable")
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
     proc = subprocess.run(
         [sys.executable,
          "examples/movie_view_ratings/run_without_frameworks.py",
          "--generate_rows", "20000"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=480)
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=1500)
+    if proc.returncode != 0 and "Unable to initialize backend" in proc.stderr:
+        pytest.skip("JAX found no accelerator")
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "computed DP metrics" in proc.stdout

@@ -306,3 +306,32 @@ class TestVocabEncode:
         if not native.available():
             pytest.skip("native library unavailable")
         assert native.vocab_encode(np.array([1.0, np.nan, 1.0])) is None
+
+
+class TestBuildFreshness:
+
+    def test_stale_binary_is_rebuilt_not_loaded(self, tmp_path, monkeypatch):
+        """git ignores the binary, so the source can move under it: a
+        binary older than dp_primitives.cc is rebuilt, never loaded."""
+        import ctypes
+        import os
+        import shutil
+
+        source = tmp_path / native._SRC_NAME
+        shutil.copy(os.path.join(native._dir, native._SRC_NAME), source)
+        stale = tmp_path / native._LIB_NAME
+        stale.write_bytes(b"not a library")
+        older = os.path.getmtime(source) - 100
+        os.utime(stale, (older, older))
+        monkeypatch.setattr(native, "_dir", str(tmp_path))
+
+        assert native._needs_build()
+        assert native._try_build()
+        assert not native._needs_build()
+        assert ctypes.CDLL(str(stale)).dpn_gaussian_sigma is not None
+        assert sorted(os.listdir(tmp_path)) == sorted(
+            [native._LIB_NAME, native._SRC_NAME])  # no build litter
+
+    def test_status_reports_the_loaded_library(self):
+        status = native.status()
+        assert status["in_use"] and not status["build_failed"]

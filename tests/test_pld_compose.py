@@ -308,10 +308,8 @@ class TestDualSpendLedger:
         led = TenantLedger("acct-a", 1.0, BlockJournal(None))
         n = _admit_until_refused(led, 0.1, 1e-8, cap=50)
         assert n == 10
-        expected = 0.0
-        for _ in range(n):
-            expected += 0.1  # the same left-to-right float64 fold
-        assert led.spent_epsilon() == expected  # bit-exact, not approx
+        # Bit-exact (not approx) against THE named spend fold.
+        assert led.spent_epsilon() == obs.fold_spend([0.1] * n)
         snap = led.snapshot()
         assert snap["accounting_mode"] == "naive"
         assert snap["admission_spent_epsilon"] == snap["spent_epsilon"]
@@ -333,10 +331,7 @@ class TestDualSpendLedger:
         n_pld = _admit_until_refused(pld_led, eps, delta, cap=cap)
         assert n_pld >= max(2 * n_naive, 100)
         # The ledger of record is untouched by the admission mode.
-        expected = 0.0
-        for _ in range(n_pld):
-            expected += eps
-        assert pld_led.spent_epsilon() == expected
+        assert pld_led.spent_epsilon() == obs.fold_spend([eps] * n_pld)
         snap = pld_led.snapshot()
         assert snap["accounting_mode"] == "pld"
         assert snap["pld_spent_epsilon"] < snap["spent_epsilon"]
@@ -346,6 +341,26 @@ class TestDualSpendLedger:
             "tenant_pld_epsilon_saved", {}).get("acct-p")
         assert saved == pytest.approx(
             snap["spent_epsilon"] - snap["pld_spent_epsilon"], abs=1e-9)
+
+    @pytest.mark.parametrize("as_type", [float, np.float64],
+                             ids=["float", "np.float64"])
+    def test_spend_fold_is_one_algorithm_for_both_types(self, as_type):
+        """Builtin sum() is compensated for exact floats and plain for
+        np.float64 on Python >= 3.12; the ledger, the accountant and
+        the odometer must fold identically whichever type a share
+        arrives as."""
+        shares = [as_type(0.1)] * 10
+        expected = 0.0
+        for share in shares:
+            expected += share  # the left-to-right float64 fold
+        assert obs.fold_spend(shares) == expected == 0.9999999999999999
+        assert type(obs.fold_spend(iter(shares))) in (float, np.float64)
+        led = TenantLedger("acct-fold", 10.0, BlockJournal(None))
+        led.charge("acct-fold--j1",
+                   [_gaussian_record(share, 1e-8) for share in shares])
+        assert led.spent_epsilon() == expected
+        assert led.job_spent_epsilon("acct-fold--j1") == expected
+        assert led.snapshot()["spent_epsilon"] == expected
 
     def test_pld_admission_never_looser_than_budget(self):
         # Even in pld mode a request that exceeds the remaining budget
