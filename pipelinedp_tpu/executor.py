@@ -525,12 +525,17 @@ def partial_columns(pid: jnp.ndarray, pk: jnp.ndarray, values: jnp.ndarray,
     percentile mode, the bounded row stream (pk, tree_leaf, keep) feeding
     the per-partition quantile histograms (None otherwise).
     """
-    spk, keep_row, pair_start, reduce_cols, qrows = bounded_row_columns(
-        pid, pk, values, valid, min_v, max_v, min_s, max_s, mid, rows_key,
-        cfg)
-    cols = reduce_rows_to_partitions(spk, keep_row, pair_start, reduce_cols,
-                                     cfg.n_partitions, cfg.vector_size,
-                                     numeric_mode=cfg.numeric_mode)
+    # Named scopes are op metadata only (the north star's phase names):
+    # a device trace then says which phase an HLO op belongs to.
+    with jax.named_scope("bound_sort"):
+        spk, keep_row, pair_start, reduce_cols, qrows = bounded_row_columns(
+            pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
+            rows_key, cfg)
+    with jax.named_scope("segment_reduce"):
+        cols = reduce_rows_to_partitions(spk, keep_row, pair_start,
+                                         reduce_cols, cfg.n_partitions,
+                                         cfg.vector_size,
+                                         numeric_mode=cfg.numeric_mode)
     return cols, qrows
 
 
@@ -915,8 +920,9 @@ def _aggregate_trace(pid, pk, values, valid, min_v, max_v, min_s, max_s,
     rows_key, final_key = jax.random.split(rng_key, 2)
     cols, qrows = partial_columns(pid, pk, values, valid, min_v, max_v, min_s,
                                   max_s, mid, rows_key, cfg)
-    outputs, keep, row_count = finalize(cols, min_v, mid, stds, final_key,
-                                        cfg, secure_tables)
+    with jax.named_scope("select_noise"):
+        outputs, keep, row_count = finalize(cols, min_v, mid, stds,
+                                            final_key, cfg, secure_tables)
     if cfg.quantiles:
         qkey = jax.random.fold_in(rng_key, 7919)
         outputs.update(
@@ -942,9 +948,10 @@ def compact_release(outputs, keep):
     has always compacted this way; this is the dense route catching up.
 
     Returns (n_kept, ids_sorted int32[P], outputs_sorted)."""
-    order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-    outputs_sorted = {name: col[order] for name, col in outputs.items()}
-    return keep.sum(), order, outputs_sorted
+    with jax.named_scope("compact"):
+        order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
+        outputs_sorted = {name: col[order] for name, col in outputs.items()}
+        return keep.sum(), order, outputs_sorted
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -1708,7 +1715,7 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                    if public_partitions is not None else None)
     rows = col  # materialized at execution time
 
-    def generator():
+    def materialise(root):
         encoded = _encode_input(backend, rows, data_extractors, public_list)
         # Chaos ingest seam: the extreme_values fault kind poisons the
         # encoded value column here — AFTER encoding (so partition/pid
@@ -1729,6 +1736,11 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                 selection_budget.delta, params.max_partitions_contributed,
                 params.pre_threshold)
         n_partitions = resolve_n_partitions(backend, encoded.n_partitions)
+        threshold = getattr(backend, "large_partition_threshold", None)
+        blocked = threshold is not None and n_partitions > threshold
+        root.set(rows=encoded.n_rows, n_partitions=n_partitions,
+                 route=("mesh" if backend.mesh is not None else
+                        "blocked" if blocked else "dense"))
         secure = bool(getattr(backend, "secure_noise", False))
         numeric_mode = str(getattr(backend, "numeric_mode", "fast"))
         cfg = make_kernel_config(params, compound, n_partitions, private,
@@ -1747,8 +1759,7 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                              jnp.asarray(gran, dtype=_ftype()))
         key = noise_ops.make_noise_key(getattr(backend, "noise_seed", None))
         min_v, max_v, min_s, max_s, mid = kernel_scalars(params)
-        threshold = getattr(backend, "large_partition_threshold", None)
-        if threshold is not None and n_partitions > threshold:
+        if blocked:
             # Very large partition spaces: never materialize dense [0, P)
             # columns; process the partition axis in blocks
             # (parallel/large_p.py) and emit only kept partitions. Raw
@@ -1829,22 +1840,35 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                 n_kept, order, outputs, _ = result
                 # Fail-closed numeric sentinel: one scalar reduction over
                 # the kept released columns BEFORE any value is decoded.
-                rt_numeric.check_release(outputs, n_kept=n_kept,
-                                         numeric_mode=numeric_mode,
-                                         context="dense release")
+                # Its scalar fetch is the first barrier after the launch:
+                # the host's wait for the release kernel is here.
+                with rt_trace.span("release_wait", what="sentinel"):
+                    rt_numeric.check_release(outputs, n_kept=n_kept,
+                                             numeric_mode=numeric_mode,
+                                             context="dense release")
                 # staticcheck: disable=release-taint — sanctioned release: the compacted ids/columns are the fused kernel's DP-selected partitions and its noised outputs, reordered kept-first inside the program
                 yield from decode_release_results(n_kept, order, outputs,
                                                   encoded.partition_vocab,
                                                   compound)
             else:
                 outputs, keep, _ = result
-                rt_numeric.check_release(outputs, keep=keep,
-                                         numeric_mode=numeric_mode,
-                                         context="dense release (unfused)")
+                with rt_trace.span("release_wait", what="sentinel"):
+                    rt_numeric.check_release(
+                        outputs, keep=keep, numeric_mode=numeric_mode,
+                        context="dense release (unfused)")
                 # staticcheck: disable=release-taint — sanctioned release: decode_results emits only partitions the fused kernel's DP selection kept, and the output columns carry the kernel's noise
                 yield from decode_results(outputs, keep,
                                           encoded.partition_vocab,
                                           compound)
+
+    def generator():
+        # The root span of one materialised aggregation: its `agg`
+        # sequence number is the request identifier every span of the job
+        # inherits. Like post_process it is open across yields, from
+        # the first pull to exhaustion, so its time includes whatever the
+        # consumer does between pulls; no other span may be.
+        with rt_trace.span("aggregate", agg=rt_trace.next_agg()) as root:
+            yield from materialise(root)
 
     return generator()
 
@@ -1864,11 +1888,14 @@ def _decode_rows(outputs, row_idx_pairs, partition_vocab: Sequence[Any],
         # below then waits once for the batch instead of paying one
         # serial round trip per column. On the async dense path that one
         # wait IS the device execution + transfer time.
+        d2h = 0
         for col in outputs.values():
             if isinstance(col, jax.Array):
                 rt_pipeline.copy_to_host_async(col)
+                d2h += int(col.nbytes)
         outputs_np = {name: np.asarray(col) for name, col in outputs.items()}
         rt_telemetry.record("release_dispatches")
+        rt_telemetry.record("d2h_bytes", d2h)
     field_order: List[str] = [
         name for entry in build_plan(compound) for name in entry.outputs
     ]
@@ -1925,7 +1952,8 @@ def decode_release_results(n_kept, order, outputs,
     _decode_rows (the same overlapped-drain discipline as the blocked
     drivers' staged drains). Emits the exact stream decode_results
     yields for the unfused (outputs, keep) pair."""
-    k = int(n_kept)  # the one sync; gates O(kept) transfers
+    with rt_trace.span("release_wait", what="n_kept"):
+        k = int(n_kept)  # the one sync; gates O(kept) transfers
     rt_telemetry.record("release_dispatches")
     if np.shape(order)[0] <= _HOST_SLICE_MAX_ROWS:
         # Micro-release fast path: at small partition buckets the
@@ -1936,11 +1964,17 @@ def decode_release_results(n_kept, order, outputs,
         ids = np.asarray(order)[:k]
         sliced = {name: np.asarray(col)[:k]
                   for name, col in outputs.items()}
+        if isinstance(order, jax.Array):
+            d2h = int(order.nbytes)
+            for col in outputs.values():
+                d2h += int(col.nbytes)
+            rt_telemetry.record("d2h_bytes", d2h)
         return _decode_rows(sliced, enumerate(ids), partition_vocab,
                             compound)
     ids = order[:k]
     sliced = {name: col[:k] for name, col in outputs.items()}
     if isinstance(ids, jax.Array):
         rt_pipeline.copy_to_host_async(ids)
+        rt_telemetry.record("d2h_bytes", int(ids.nbytes))
     return _decode_rows(sliced, enumerate(np.asarray(ids)),
                         partition_vocab, compound)

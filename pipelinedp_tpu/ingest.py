@@ -746,11 +746,13 @@ def _stream_encode_pipelined(chunks, partition_vocab, nonfinite,
                                            pipeline_depth)):
             # Sequential merge in stream order: global codes are exactly
             # what the serial encode assigns.
-            pid = pid_enc.merge(prep.pid_codes, prep.pid_uniques)
-            if partition_vocab is not None:
-                pk = prep.pk_codes
-            else:
-                pk = pk_enc.merge(prep.pk_codes, prep.pk_uniques)
+            with rt_trace.span("ingest.merge", chunk=idx,
+                               rows=len(prep.pid_codes)):
+                pid = pid_enc.merge(prep.pid_codes, prep.pid_uniques)
+                if partition_vocab is not None:
+                    pk = prep.pk_codes
+                else:
+                    pk = pk_enc.merge(prep.pk_codes, prep.pk_uniques)
             n = len(pid)
             n_rows += n
             values = prep.values
@@ -761,7 +763,8 @@ def _stream_encode_pipelined(chunks, partition_vocab, nonfinite,
                     pid, pk, values, executor.row_bucket(n))
             acc.append(pid, pk, values, n, chunk=idx)
         ingest_span.set(rows=n_rows)
-        bufs = acc.finalize()
+        with rt_trace.span("ingest.finalize"):
+            bufs = acc.finalize()
         if bufs is None:
             empty = jnp.zeros(0, jnp.int32)
             return encoded_data(empty, empty, jnp.zeros(0, value_dtype))

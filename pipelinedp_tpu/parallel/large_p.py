@@ -116,6 +116,7 @@ def _block_noise_key(final_key, generation: int, block: int):
         jax.random.fold_in(final_key, _REPLAN_KEY_LANE + generation), block)
 
 
+@jax.named_scope("p1_bound_compact")
 def _bound_compact_trace(pid, pk, values, valid, min_v, max_v, min_s, max_s,
                          mid, key, cfg: executor.KernelConfig):
     """Traceable body shared by the single-device kernel and the per-shard
@@ -152,6 +153,7 @@ _bounded_compact_kernel = rt_aot.aot_probe("blocked_bound_compact",
                                            static_argnames=("cfg",))
 
 
+@jax.named_scope("block_finalize")
 def _block_trace(spk_s, pair_s, cols_s, leaf_s, lo, length, base, min_v,
                  max_v, mid, stds, key, cfg: executor.KernelConfig,
                  cap: int, secure_tables=None, psum_axis=None):
@@ -224,6 +226,18 @@ def _block_kernel_dev(spk_s, pair_s, cols_s, leaf_s, lo, length, base, min_v,
 _block_kernel_dev = rt_aot.aot_probe("blocked_block_kernel",
                                      _block_kernel_dev,
                                      static_argnames=("cfg", "cap"))
+
+
+@jax.jit
+def _block_offsets_dev(spk_s, boundaries):
+    """Row offset of each block boundary in the compacted, spk-sorted
+    stream (a program of its own so that its ops carry the scope)."""
+    with jax.named_scope("block_offsets"):
+        return jnp.searchsorted(spk_s, boundaries, side="left")
+
+
+_block_offsets_dev = rt_trace.probe_jit("_block_offsets_dev",
+                                        _block_offsets_dev)
 
 
 def _chunk_ends(pid_sorted: np.ndarray, row_chunk: int) -> np.ndarray:
@@ -373,7 +387,8 @@ def _dispatch_blocks(block_iter, consume,
                 with rt_watchdog.guard("drain", b), \
                         rt_trace.span("drain", block=b):
                     rt_faults.maybe_hang(b, point="drain")
-                    _sync_scalars(result)
+                    with rt_trace.span("release_wait", block=b):
+                        _sync_scalars(result)
                 break
             except Exception as e:  # noqa: BLE001 - classified below
                 if (not rt_retry.is_transient(e) or
@@ -471,6 +486,7 @@ def _dispatch_blocks_overlapped(block_iter, start, consume_or_oom,
     job_health = rt_health.current()
     fault_schedule = rt_faults.active()
     aot_on = rt_aot.enabled()
+    cause = rt_trace.current()  # the drainer's spans name the driver's
     drain_q: "_queue.Queue" = _queue.Queue(maxsize=max_in_flight)
     drain_err: list = []
     n_dispatched = 0
@@ -481,7 +497,8 @@ def _dispatch_blocks_overlapped(block_iter, start, consume_or_oom,
                        if fault_schedule is not None else
                        _ctx.nullcontext())
         with rt_health.track(job_health), rt_watchdog.activate(active_wd), \
-                rt_aot.activate(aot_on), fault_scope:
+                rt_aot.activate(aot_on), fault_scope, \
+                rt_trace.span("drainer", parent=cause):
             while True:
                 item = drain_q.get()
                 if item is None:
@@ -615,10 +632,14 @@ class _StagedDrain:
         self._drain_n(len(self._staged))
 
     def _drain_n(self, n: int) -> None:
+        nbytes = 0
         for target, arr, transform in self._staged[:n]:
             host = np.asarray(arr)
+            nbytes += int(host.nbytes)
             target.append(transform(host) if transform else host)
         del self._staged[:n]
+        if n:
+            rt_telemetry.record("d2h_bytes", nbytes)
 
 
 def _seed_pass1(seconds: float) -> None:
@@ -642,6 +663,10 @@ def _pad_to(a, cap: int, fill):
     return np.pad(a, widths, constant_values=fill)
 
 
+def _nbytes(*arrays) -> int:
+    return sum(int(a.nbytes) for a in arrays)
+
+
 def _bound_and_compact_host_staged(pid, pk, values, valid, min_v, max_v,
                                    min_s, max_s, mid, rows_key, cfg,
                                    row_chunk):
@@ -649,45 +674,61 @@ def _bound_and_compact_host_staged(pid, pk, values, valid, min_v, max_v,
 
     Chunks split on privacy-id boundaries (L0 bounding is global per id);
     each chunk's survivors arrive already spk-sorted, the host merges them
-    with one argsort over the concatenation.
+    with one argsort over the concatenation. The p1.* spans split the
+    round trip: host sort, per chunk pad + launch / sync / copy-down, then
+    the host merge (the upload of the merged stream is the caller's).
     """
-    order = np.argsort(pid, kind="stable")
-    pid_s, pk_s, values_s, valid_s = (pid[order], pk[order], values[order],
-                                      valid[order])
+    with rt_trace.span("p1.host_sort", rows=len(pid)):
+        order = np.argsort(pid, kind="stable")
+        pid_s, pk_s, values_s, valid_s = (pid[order], pk[order],
+                                          values[order], valid[order])
     b_pk, b_pair, b_leaf = [], [], []
     b_cols = {name: [] for name in executor.reduce_column_names(cfg)}
     start = 0
     for ci, end in enumerate(_chunk_ends(pid_s, row_chunk)):
+        end = int(end)  # a numpy scalar would not export as a span attr
         sl = slice(start, end)
         cap = round_capacity(end - start)
-        spk, pair, cols, leaf, n_kept = _bounded_compact_kernel(
-            _pad_to(pid_s[sl], cap, 0), _pad_to(pk_s[sl], cap, 0),
-            _pad_to(values_s[sl], cap, 0), _pad_to(valid_s[sl], cap, False),
-            min_v, max_v, min_s, max_s, mid, jax.random.fold_in(rows_key, ci),
-            cfg)
-        k = int(n_kept)  # the only per-chunk sync; bounds the d2h volume
-        b_pk.append(np.asarray(spk[:k]))
-        b_pair.append(np.asarray(pair[:k]))
-        if cfg.quantiles:
-            b_leaf.append(np.asarray(leaf[:k]))
-        for name, col in cols.items():
-            b_cols[name].append(np.asarray(col[:k]))
+        with rt_trace.span("p1.chunk", chunk=ci, rows=end - start, cap=cap):
+            rows_in = (_pad_to(pid_s[sl], cap, 0), _pad_to(pk_s[sl], cap, 0),
+                       _pad_to(values_s[sl], cap, 0),
+                       _pad_to(valid_s[sl], cap, False))
+            rt_telemetry.record("h2d_bytes", _nbytes(*rows_in))
+            spk, pair, cols, leaf, n_kept = _bounded_compact_kernel(
+                *rows_in, min_v, max_v, min_s, max_s, mid,
+                jax.random.fold_in(rows_key, ci), cfg)
+            del rows_in  # the padded host copies die with the launch
+        with rt_trace.span("p1.chunk_wait", chunk=ci):
+            k = int(n_kept)  # the only per-chunk sync; bounds the d2h volume
+        with rt_trace.span("p1.fetch", chunk=ci, rows=k) as sp:
+            b_pk.append(np.asarray(spk[:k]))
+            b_pair.append(np.asarray(pair[:k]))
+            if cfg.quantiles:
+                b_leaf.append(np.asarray(leaf[:k]))
+            for name, col in cols.items():
+                b_cols[name].append(np.asarray(col[:k]))
+            nbytes = _nbytes(b_pk[-1], b_pair[-1],
+                             *(chunks[-1] for chunks in b_cols.values()),
+                             *b_leaf[-1:])
+            sp.set(bytes=nbytes)
+            rt_telemetry.record("d2h_bytes", nbytes)
         start = end
 
-    spk_all = np.concatenate(b_pk) if b_pk else np.zeros(0, np.int32)
-    pair_all = np.concatenate(b_pair) if b_pair else np.zeros(0, bool)
-    cols_all = {
-        name: (np.concatenate(chunks) if chunks else np.zeros(0))
-        for name, chunks in b_cols.items()
-    }
-    order2 = np.argsort(spk_all, kind="stable")
-    leaf_all = None
-    if cfg.quantiles:
-        leaf_all = (np.concatenate(b_leaf)
-                    if b_leaf else np.zeros(0, np.int32))[order2]
-    return spk_all[order2], pair_all[order2], {
-        name: col[order2] for name, col in cols_all.items()
-    }, leaf_all
+    with rt_trace.span("p1.merge", chunks=len(b_pk)):
+        spk_all = np.concatenate(b_pk) if b_pk else np.zeros(0, np.int32)
+        pair_all = np.concatenate(b_pair) if b_pair else np.zeros(0, bool)
+        cols_all = {
+            name: (np.concatenate(chunks) if chunks else np.zeros(0))
+            for name, chunks in b_cols.items()
+        }
+        order2 = np.argsort(spk_all, kind="stable")
+        leaf_all = None
+        if cfg.quantiles:
+            leaf_all = (np.concatenate(b_leaf)
+                        if b_leaf else np.zeros(0, np.int32))[order2]
+        return spk_all[order2], pair_all[order2], {
+            name: col[order2] for name, col in cols_all.items()
+        }, leaf_all
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
@@ -1408,7 +1449,6 @@ def aggregate_blocked(pid,
                       block_partitions: int = 1 << 20,
                       row_chunk: int = 1 << 24,
                       secure_tables=None,
-                      phase_times: Optional[dict] = None,
                       overlap: bool = False,
                       retry: Optional[rt_retry.RetryPolicy] = None,
                       journal: Optional[rt_journal.BlockJournal] = None,
@@ -1421,11 +1461,9 @@ def aggregate_blocked(pid,
     memory) over the block's own rows — but the partition axis is processed
     in blocks of `block_partitions` and only kept partitions are returned.
 
-    phase_times: optional dict populated with per-phase wall-clock seconds
-    (p1_bound_compact, block_offsets, p2_blocks_total, p2_sync_wait,
-    p2_drain, blocks_dispatched, total) — the profiling hook used by
-    benchmarks/profile_large_p.py so the profiler times THIS code, not a
-    replica. Adds one device sync after pass 1; leave None in production.
+    Where the wall time goes is read from rt_trace: contribution_bounding
+    (staged=host|device) with its p1.* children, block_offsets, and per
+    block dispatch / drain (release_wait inside) / consume.
 
     retry/journal/job_id: failure-semantics knobs (module docstring).
     Journaled runs materialize each block's results at consume time (one
@@ -1434,7 +1472,6 @@ def aggregate_blocked(pid,
 
     Returns (kept_partition_ids int64[M], {metric: f[M]}).
     """
-    profiling = phase_times is not None
     t0 = time.perf_counter()
     # Chaos ingest seam (no-op without an active extreme_values fault).
     _poisoned = rt_faults.maybe_extreme_rows(values, pk)
@@ -1459,17 +1496,23 @@ def aggregate_blocked(pid,
     stds = jnp.asarray(stds)
 
     # --- Pass 1: bound rows, compact + spk-sort the survivors. ------------
-    with rt_trace.span("contribution_bounding", rows=n):
-        if n <= row_chunk:
+    host_staged = n > row_chunk
+    with rt_trace.span("contribution_bounding", rows=n,
+                       staged="host" if host_staged else "device"):
+        if not host_staged:
             # Device-resident: one kernel call, rows stay in HBM for
             # pass 2.
             cap = round_capacity(n)
-            spk_all, pair_all, cols_all, leaf_all, _ = \
-                _bounded_compact_kernel(
-                    _pad_to(pid, cap, 0), _pad_to(pk, cap, 0),
-                    _pad_to(values, cap, 0), _pad_to(valid, cap, False),
-                    min_v, max_v, min_s, max_s, mid,
-                    jax.random.fold_in(rows_key, 0), cfg)
+            with rt_trace.span("p1.chunk", chunk=0, rows=n, cap=cap):
+                rows_in = (_pad_to(pid, cap, 0), _pad_to(pk, cap, 0),
+                           _pad_to(values, cap, 0),
+                           _pad_to(valid, cap, False))
+                if not device_resident:
+                    rt_telemetry.record("h2d_bytes", _nbytes(*rows_in))
+                spk_all, pair_all, cols_all, leaf_all, _ = \
+                    _bounded_compact_kernel(
+                        *rows_in, min_v, max_v, min_s, max_s, mid,
+                        jax.random.fold_in(rows_key, 0), cfg)
         else:
             if device_resident:
                 # Host staging re-chunks on privacy-id boundaries with
@@ -1477,6 +1520,8 @@ def aggregate_blocked(pid,
                 pid, pk, values, valid = (np.asarray(pid), np.asarray(pk),
                                           np.asarray(values),
                                           np.asarray(valid))
+                rt_telemetry.record("d2h_bytes",
+                                    _nbytes(pid, pk, values, valid))
             spk_all, pair_all, cols_all, leaf_all = \
                 _bound_and_compact_host_staged(
                     pid, pk, values, valid, min_v, max_v, min_s, max_s,
@@ -1484,22 +1529,23 @@ def aggregate_blocked(pid,
             # Blocks gather from device-resident arrays either way;
             # per-block inputs are O(block rows), so upload the merged
             # stream once.
-            spk_all = jnp.asarray(spk_all)
-            pair_all = jnp.asarray(pair_all)
-            cols_all = {
-                name: jnp.asarray(col) for name, col in cols_all.items()
-            }
-            if leaf_all is not None:
-                leaf_all = jnp.asarray(leaf_all)
-    if profiling:
-        # Wait for pass 1 so its tail cost is not billed to the
-        # block_offsets bucket.
-        jax.block_until_ready(spk_all)
-        phase_times["p1_bound_compact"] = time.perf_counter() - t0
-    # Without profiling, pass 1 was dispatched async — the wall time here
-    # under-measures, but the watchdog floors the auto deadline and takes
-    # the max over later completed-guard observations, so the seed only
-    # has to be the right order of magnitude.
+            with rt_trace.span("p1.upload") as sp:
+                nbytes = _nbytes(spk_all, pair_all, *cols_all.values(),
+                                 *([] if leaf_all is None else [leaf_all]))
+                sp.set(bytes=nbytes)
+                rt_telemetry.record("h2d_bytes", nbytes)
+                spk_all = jnp.asarray(spk_all)
+                pair_all = jnp.asarray(pair_all)
+                cols_all = {
+                    name: jnp.asarray(col)
+                    for name, col in cols_all.items()
+                }
+                if leaf_all is not None:
+                    leaf_all = jnp.asarray(leaf_all)
+    # Pass 1 was dispatched async — the wall time here under-measures on
+    # the device-resident branch, but the watchdog floors the auto
+    # deadline and takes the max over later completed-guard observations,
+    # so the seed only has to be the right order of magnitude.
     _seed_pass1(time.perf_counter() - t0)
 
     # --- Pass 2: bin by partition block, finalize each block. -------------
@@ -1511,8 +1557,6 @@ def aggregate_blocked(pid,
     kept_ids = []
     kept_outputs = {name: [] for name in output_names}
     job = job_id or "aggregate_blocked"
-    n_dispatched_total = 0
-    offsets_seconds = 0.0
 
     drain = _StagedDrain()
 
@@ -1523,15 +1567,13 @@ def aggregate_blocked(pid,
                 kept_outputs.setdefault(name, []).append(col)
 
     def run_range(base, C, gen, end):
-        nonlocal n_dispatched_total, offsets_seconds
-        to = time.perf_counter()
         n_blocks = -(-(end - base) // C)
-        block_starts = host_fetch(
-            jnp.searchsorted(spk_all,
-                             jnp.asarray(_block_boundaries(base, C,
-                                                           n_blocks)),
-                             side="left"))
-        offsets_seconds += time.perf_counter() - to
+        # The fetch waits for pass 1 (dispatched async) and the search.
+        with rt_trace.span("block_offsets", blocks=n_blocks):
+            block_starts = host_fetch(
+                _block_offsets_dev(
+                    spk_all,
+                    jnp.asarray(_block_boundaries(base, C, n_blocks))))
         row_cap = _range_row_cap(block_starts)
 
         def consume(j, result):
@@ -1548,9 +1590,7 @@ def aggregate_blocked(pid,
                 outputs_sorted, n_kept=n_kept,
                 numeric_mode=cfg.numeric_mode,
                 context=f"blocked release (base {b_base})")
-            ts = time.perf_counter()
             k = int(n_kept)  # sync; gates O(kept) transfers
-            ta = time.perf_counter()
             if journal is not None:
                 # Journaled runs materialize per block (one sync each) so
                 # the record is durable the moment the block is consumed —
@@ -1570,18 +1610,6 @@ def aggregate_blocked(pid,
                 for name, col in outputs_sorted.items():
                     drain.stage(kept_outputs.setdefault(name, []), col[:k])
             drain.end_block()
-            if profiling:
-                # Sync wait (device still computing) and drain are
-                # attributed separately — conflating them would re-create
-                # the transfer-bound misdiagnosis this hook exists to
-                # prevent. Per-block drain time is stage/flush overhead
-                # (the O(kept) transfers are async and mostly land in the
-                # post-loop materialize() increment, or in end_block()
-                # flushes of blocks older than the window).
-                phase_times["p2_sync_wait"] = (
-                    phase_times.get("p2_sync_wait", 0.0) + ta - ts)
-                phase_times["p2_drain"] = (phase_times.get("p2_drain", 0.0) +
-                                           time.perf_counter() - ta)
 
         def block_iter():
             for j in range(n_blocks):
@@ -1609,24 +1637,12 @@ def aggregate_blocked(pid,
                     _block_noise_key(final_key, gen, j), cfg_block,
                     row_cap, secure_tables))
 
-        n_dispatched_total += _dispatch_blocks(block_iter(), consume,
-                                               retry_policy=retry,
-                                               overlap=overlap)
+        _dispatch_blocks(block_iter(), consume, retry_policy=retry,
+                         overlap=overlap)
 
-    t2 = time.perf_counter()
     rt_retry.run_with_degradation(run_range, P, C0, journal=journal,
                                   job_id=job)
-    td = time.perf_counter()
     drain.materialize()
-    if profiling:
-        now = time.perf_counter()
-        phase_times["block_offsets"] = offsets_seconds
-        phase_times["p2_drain"] = (phase_times.get("p2_drain", 0.0) +
-                                   now - td)
-        phase_times["p2_blocks_total"] = now - t2
-        phase_times["blocks_dispatched"] = n_dispatched_total
-        phase_times["total"] = now - t0
-
     # Each block emits kept partitions in ascending relative id (the compact
     # sort is stable) and blocks are consumed in ascending order, so the
     # concatenation is already globally ascending.

@@ -383,6 +383,7 @@ def host_fetch(arr, max_retries: Optional[int] = None) -> np.ndarray:
                 with rt_trace.span("host_fetch") as sp:
                     out = np.asarray(arr)
                     sp.set(bytes=int(out.nbytes))
+                    rt_telemetry.record("d2h_bytes", int(out.nbytes))
                     return out
             except Exception as e:  # noqa: BLE001 - classified below
                 if not rt_retry.is_transient(e) or attempt >= max_retries:

@@ -634,6 +634,10 @@ class TPUBackend(LocalBackend):
         self.metrics_path = metrics_path
         self.numeric_mode = numeric_mode
         self.snap_grid_bits = snap_grid_bits
+        # The compile count that works with tracing off
+        # (backend_compiles): one process-wide listener, idempotent.
+        from pipelinedp_tpu.runtime import telemetry as rt_telemetry
+        rt_telemetry.install_compile_listener()
         if trace:
             from pipelinedp_tpu.runtime import trace as rt_trace
             rt_trace.enable()

@@ -84,6 +84,7 @@ def runtime_entry(kind: str, fallback: Optional[Callable] = None):
                     **kwargs):
             job = job_id or kind
             input_validators.validate_job_id(job, kind)
+            rt_telemetry.install_compile_listener()
             if timeout_s is not None:
                 input_validators.validate_timeout_s(timeout_s, kind)
             if kwargs.get("retry") is not None:

@@ -204,8 +204,13 @@ def map_overlapped(items: Iterable,
     pool = _futures.ThreadPoolExecutor(max_workers=encode_threads,
                                        thread_name_prefix="pdp-encode")
 
+    # Captured at submission (this generator's first pull runs on the
+    # consumer, inside its `ingest` span): a worker's pipeline_encode
+    # span names the span that caused it, and so its job and agg.
+    cause = rt_trace.current()
+
     def encode(idx, item):
-        with rt_trace.span("pipeline_encode", chunk=idx):
+        with rt_trace.span("pipeline_encode", parent=cause, chunk=idx):
             return fn(item)
 
     def feed():
@@ -230,15 +235,19 @@ def map_overlapped(items: Iterable,
     n_consumed = 0
     try:
         while True:
-            tag, idx, payload = _staged_get(q, n_consumed)
-            if tag == "end":
-                return
-            if tag == "producer_error":
-                raise payload
-            try:
-                result = _staged_result(payload, idx)
-            finally:
-                slots.release()
+            # The consumer's blocking wait on the staging queue: long
+            # waits mean encode starves it, short ones that the consumer
+            # itself (merge, append) is the serial ceiling.
+            with rt_trace.span("ingest.wait", chunk=n_consumed):
+                tag, idx, payload = _staged_get(q, n_consumed)
+                if tag == "end":
+                    return
+                if tag == "producer_error":
+                    raise payload
+                try:
+                    result = _staged_result(payload, idx)
+                finally:
+                    slots.release()
             wd = rt_watchdog.active()
             if wd is not None:
                 wd.beat("pipeline")
@@ -445,32 +454,42 @@ class DeviceRowAccumulator:
             return
         import numpy as _np
         n = self._batch_n
-        pid = _np.concatenate([c[0] for c in self._batch])
-        pk = _np.concatenate([c[1] for c in self._batch])
-        values = _np.concatenate([c[2] for c in self._batch])
-        self._batch = []
-        self._batch_n = 0
-        if self.donating:
-            # Re-pad the batch to its row bucket with this
-            # accumulator's pad values — byte-identical to what the
-            # per-chunk path would have left in the buffer tail.
-            from pipelinedp_tpu import executor
-            cap = executor.row_bucket(n)
-            pad = cap - n
-            if pad:
-                f0, f1, f2 = self.fills
-                pid = _np.concatenate(
-                    [pid, _np.full((pad,) + pid.shape[1:], f0, pid.dtype)])
-                pk = _np.concatenate(
-                    [pk, _np.full((pad,) + pk.shape[1:], f1, pk.dtype)])
-                values = _np.concatenate(
-                    [values,
-                     _np.full((pad,) + values.shape[1:], f2, values.dtype)])
+        # The host copies before the upload: concatenate (a copy even of
+        # a batch of one chunk) and the pad to the row bucket.
+        with rt_trace.span("ingest.stage", chunk=chunk, rows=n,
+                           chunks=len(self._batch)):
+            pid = _np.concatenate([c[0] for c in self._batch])
+            pk = _np.concatenate([c[1] for c in self._batch])
+            values = _np.concatenate([c[2] for c in self._batch])
+            self._batch = []
+            self._batch_n = 0
+            if self.donating:
+                # Re-pad the batch to its row bucket with this
+                # accumulator's pad values — byte-identical to what the
+                # per-chunk path would have left in the buffer tail.
+                from pipelinedp_tpu import executor
+                cap = executor.row_bucket(n)
+                pad = cap - n
+                if pad:
+                    f0, f1, f2 = self.fills
+                    pid = _np.concatenate(
+                        [pid,
+                         _np.full((pad,) + pid.shape[1:], f0, pid.dtype)])
+                    pk = _np.concatenate(
+                        [pk, _np.full((pad,) + pk.shape[1:], f1, pk.dtype)])
+                    values = _np.concatenate(
+                        [values,
+                         _np.full((pad,) + values.shape[1:], f2,
+                                  values.dtype)])
         self._append_now(pid, pk, values, n, chunk)
 
     def _append_now(self, pid, pk, values, n_real: int, chunk: int) -> None:
         import jax.numpy as jnp
-        with rt_trace.span("pipeline_append", chunk=chunk, rows=n_real):
+        nbytes = int(pid.nbytes) + int(pk.nbytes) + int(values.nbytes)
+        if not isinstance(pid, jnp.ndarray):
+            rt_telemetry.record("h2d_bytes", nbytes)
+        with rt_trace.span("pipeline_append", chunk=chunk, rows=n_real,
+                           bytes=nbytes):
             if not self.donating:
                 self._staged.append((jnp.asarray(pid), jnp.asarray(pk),
                                      jnp.asarray(values), n_real))

@@ -109,7 +109,29 @@ REGISTRY: Dict[str, Metric] = {
                  "are the double-spend bug no_new_mechanisms guards)"),
         _counter("jit_cache_misses",
                  "probed jit entry-point calls that compiled (grew the "
-                 "jit cache) instead of hitting it"),
+                 "jit cache) instead of hitting it — counted only while "
+                 "rt_trace is enabled (trace.probe_jit's per-entry-point "
+                 "attribution); backend_compiles is the count that works "
+                 "with tracing off"),
+        _counter("backend_compiles",
+                 "programs the backend compiled, or loaded from the "
+                 "persistent compilation cache, in this process: one per "
+                 "jax.monitoring backend_compile_duration event, tracing "
+                 "on or off (install_compile_listener); never fires on a "
+                 "dispatch of a program already built"),
+        _counter("backend_compile_ms",
+                 "milliseconds (rounded per event) those backend "
+                 "compiles or cache loads took"),
+        _counter("h2d_bytes",
+                 "bytes of row data copied host->device on the release "
+                 "path, from nbytes where they cross: the ingest "
+                 "accumulator's appends, blocked pass 1's chunk inputs "
+                 "and its re-upload of the merged survivors"),
+        _counter("d2h_bytes",
+                 "bytes copied device->host on the release path, from "
+                 "nbytes where they cross: pass 1's survivor fetches, "
+                 "control-table host_fetch, the blocked staged drains and "
+                 "the decode barrier's released columns"),
         _counter("aot_cache_hits",
                  "warm-path dispatches served by an ahead-of-time "
                  "compiled executable from the process-wide "
@@ -312,7 +334,7 @@ _gauges: Dict[tuple, float] = {}
 # receipt builders read; staticcheck's lock-discipline rule enforces the
 # declaration (readers use snapshot()/delta(), never the bare maps).
 _GUARDED_BY = guarded_by("_lock", "counters", "_timings", "_job_timings",
-                         "_gauges")
+                         "_gauges", "_compile_listener_installed")
 
 # Sentinel distinguishing "no job_id passed" (attribute to the current
 # job scope) from an explicit job_id=None (process-level gauge).
@@ -346,6 +368,32 @@ def record(name: str, n: int = 1, **attrs) -> None:
     # would be circular; the hook only fires on failure-path events).
     from pipelinedp_tpu.runtime import health
     health.observe_counter(name, n)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_installed = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        record("backend_compiles")
+        record("backend_compile_ms", int(round(duration_secs * 1e3)))
+
+
+def install_compile_listener() -> None:
+    """Registers the ONE process-wide jax.monitoring listener behind
+    backend_compiles / backend_compile_ms (idempotent). Called where the
+    runtime is first used (TPUBackend, the driver entry wrapper), not at
+    import: importing this module touches neither JAX nor its listeners.
+    JAX calls the listener on compiles and cache loads only, on the
+    thread that built the program."""
+    global _compile_listener_installed
+    with _lock:
+        if _compile_listener_installed:
+            return
+        _compile_listener_installed = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def set_gauge(name: str, value, job_id=_CURRENT_JOB) -> None:

@@ -11,10 +11,23 @@ run into an exportable, attributable trace:
     attributes (set at creation or via ``sp.set(bytes=n)`` on the yielded
     token), nest naturally per thread, and self-account exclusive time
     (inclusive minus the time spent in child spans) at close — so a
-    summary needs no tree reconstruction. When tracing is disabled,
-    ``span()`` returns a shared null token: one module-global bool check
-    and no allocation — near-zero cost on the hot block stream
-    (tests/test_trace.py guards the disabled overhead).
+    summary needs no tree reconstruction. Every span has an ``id`` and
+    the ``parent`` id of the span that caused it: the span open on the
+    thread's stack, or — where work crosses threads — a token captured
+    with ``trace.current()`` and handed over as ``span(name,
+    parent=token)``. A child inherits its parent's ``agg``, the request
+    identifier the executor's root ``aggregate`` span stamps on every
+    span of one materialised aggregation (``next_agg()``), and, where
+    its own thread has no job scope, the parent's ``job``. When tracing
+    is disabled, ``span()`` returns a shared null token and ``current()``
+    None: one module-global bool check and no allocation — near-zero
+    cost on the hot block stream (tests/test_trace.py guards the
+    disabled overhead).
+  * **One clock with the device trace** — while tracing is enabled a
+    span also enters ``jax.profiler.TraceAnnotation("rt:" + name)``, so
+    an ``.xplane.pb`` taken by anyone carries the program's spans on the
+    profiler's host plane beside ``XLA Ops``. The annotation class is
+    imported at ``enable()``, so this module still imports without JAX.
   * **Instants** — ``trace.instant(name, **attrs)`` marks a point event.
     telemetry.record() forwards every counter increment here, so every
     runtime incident the counters already record (retry, timeout, OOM
@@ -43,6 +56,7 @@ health states so long-running processes and tests cannot mix epochs.
 
 import contextlib
 import functools
+import itertools
 import json
 import logging
 import os
@@ -68,6 +82,16 @@ _compile: Dict[str, list] = {}
 
 _local = threading.local()
 
+# Span ids and aggregation sequence numbers: next() on an
+# itertools.count is one C call under the GIL, so neither needs the lock.
+_span_ids = itertools.count(1)
+_agg_ids = itertools.count(1)
+
+# jax.profiler.TraceAnnotation, bound by enable() (None until then, and
+# where JAX is not installed): an enabled span enters "rt:<name>" so the
+# profiler's host plane carries the program's spans on its own clock.
+_annotation = None
+
 # Optional per-span memory sampler (runtime/observability.py installs
 # memory_watermark here via enable_memory_sampling): when set, every
 # span close attaches mem_live_bytes/mem_peak_bytes attrs so the
@@ -89,7 +113,14 @@ def enabled() -> bool:
 
 def enable(buffer_limit: int = 1_000_000) -> None:
     """Turns span/instant recording on (process-wide)."""
-    global _enabled, _buffer_limit, _t0
+    global _enabled, _buffer_limit, _t0, _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            pass
+        else:
+            _annotation = TraceAnnotation
     with _lock:
         _buffer_limit = int(buffer_limit)
         if not _events:
@@ -176,13 +207,16 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One open span on the current thread (returned by span())."""
+    """One open span on the current thread (returned by span()); also
+    the token current() hands out for span(name, parent=token)."""
 
-    __slots__ = ("name", "attrs", "_start", "_child_s", "_job", "_tid")
+    __slots__ = ("name", "attrs", "id", "parent", "job", "agg", "_cause",
+                 "_start", "_child_s", "_tid", "_ann")
 
-    def __init__(self, name: str, attrs: Optional[dict]):
+    def __init__(self, name: str, attrs: Optional[dict], cause=None):
         self.name = name
         self.attrs = attrs or None
+        self._cause = cause
 
     def set(self, **attrs) -> None:
         """Attaches/overwrites attributes on the open span (e.g. a byte
@@ -196,15 +230,40 @@ class _Span:
         stack = getattr(_local, "stack", None)
         if stack is None:
             stack = _local.stack = []
-        self._job = _current_job()
+        # The span that caused this one: the token handed over (work that
+        # crossed threads), else the span open on this thread.
+        cause = self._cause if self._cause is not None else (
+            stack[-1] if stack else None)
+        self.id = next(_span_ids)
+        self.job = _current_job()
+        if cause is None:
+            self.parent = self.agg = None
+        else:
+            self.parent, self.agg = cause.id, cause.agg
+            if self.job is None:
+                self.job = cause.job
+        if self.attrs is not None and "agg" in self.attrs:
+            self.agg = self.attrs["agg"]  # the root stamps its own
+        self._cause = None  # a token must not keep its ancestors alive
         self._tid = threading.get_ident()
         self._child_s = 0.0
         stack.append(self)
+        self._ann = None
+        if _annotation is not None:
+            # id (and agg) ride along as the event's stats, so a reader of
+            # the .xplane.pb can join an annotation to the exported span.
+            stats = {"id": self.id}
+            if self.agg is not None:
+                stats["agg"] = self.agg
+            self._ann = _annotation("rt:" + self.name, **stats)
+            self._ann.__enter__()
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._start
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = getattr(_local, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -216,21 +275,40 @@ class _Span:
                 self.set(**_memory_sampler())
             except Exception:  # noqa: BLE001 - a failed memory sample must never fail the traced operation; the span simply lacks the mem attrs
                 pass
-        _append(("X", self.name, self._tid, self._job,
-                 self._start, dur, exclusive, self.attrs))
+        _append(("X", self.name, self._tid, self.job, self._start, dur,
+                 exclusive, self.attrs, self.id, self.parent, self.agg))
         return False
 
 
-def span(name: str, **attrs):
+def span(name: str, parent=None, **attrs):
     """Context manager timing one nested, attributed interval.
 
     ``with trace.span("drain", block=b, rows=n) as sp: ...`` — the token
-    supports ``sp.set(**attrs)`` for values known only at close. Returns
-    a shared no-op token when tracing is disabled.
+    supports ``sp.set(**attrs)`` for values known only at close.
+    ``parent`` is a token from ``current()`` captured on the thread that
+    caused this work; without it the parent is the span open on this
+    thread. Returns a shared no-op token when tracing is disabled.
     """
     if not _enabled:
         return _NULL_SPAN
-    return _Span(name, attrs or None)
+    return _Span(name, attrs or None, parent)
+
+
+def current():
+    """The span open on this thread, as a token for ``span(name,
+    parent=token)`` on another thread; None when tracing is disabled or
+    no span is open."""
+    if not _enabled:
+        return None
+    stack = getattr(_local, "stack", None)
+    return stack[-1] if stack else None
+
+
+def next_agg() -> int:
+    """The next process-wide aggregation sequence number: the executor's
+    root ``aggregate`` span takes one as ``agg=``, and every span it
+    causes inherits it — the request identifier of one job's spans."""
+    return next(_agg_ids)
 
 
 def instant(name: str, **attrs) -> None:
@@ -267,8 +345,11 @@ def probe_jit(name: str, fn):
     `name` in compile_stats(), a ``jit_compile:<name>`` instant lands on
     the timeline, and the ``jit_cache_misses`` telemetry counter
     increments. With tracing disabled the wrapper is one bool check and
-    a tail call. The underlying jit attributes (clear_cache, lower,
-    _cache_size) are re-exposed on the wrapper.
+    a tail call, so this attribution exists in traced runs only; the
+    count that works with tracing off is telemetry's ``backend_compiles``
+    (one jax.monitoring listener, every program of the process). The
+    underlying jit attributes (clear_cache, lower, _cache_size) are
+    re-exposed on the wrapper.
     """
     cache_size = getattr(fn, "_cache_size", None)
 
@@ -313,7 +394,8 @@ def note_compile(name: str, seconds: float) -> None:
 
 
 def compile_stats() -> Dict[str, Dict[str, float]]:
-    """{entry point: {"misses": n, "compile_s": seconds}} from probe_jit."""
+    """{entry point: {"misses": n, "compile_s": seconds}} from probe_jit
+    and note_compile: traced runs only (see probe_jit)."""
     with _lock:
         return {
             name: {"misses": entry[0], "compile_s": round(entry[1], 6)}
@@ -348,7 +430,7 @@ def trace_summary(job_id: Optional[str] = None) -> Dict[str, Any]:
     events = _snapshot_events(job_id)
     for ev in events:
         if ev[0] == "X":
-            _, name, _tid, _job, _start, dur, excl, attrs = ev
+            _, name, _tid, _job, _start, dur, excl, attrs = ev[:8]
             entry = spans.setdefault(name, [0, 0.0, 0.0, 0.0])
             entry[0] += 1
             entry[1] += dur
@@ -405,10 +487,16 @@ def to_trace_events(job_id: Optional[str] = None,
     }]
     for ev in _snapshot_events(job_id):
         if ev[0] == "X":
-            _, name, tid, job, start, dur, excl, attrs = ev
+            (_, name, tid, job, start, dur, excl, attrs, span_id, parent,
+             agg) = ev
             args = dict(attrs) if attrs else {}
             if job is not None:
                 args["job"] = job
+            args["id"] = span_id
+            if parent is not None:
+                args["parent"] = parent
+            if agg is not None:
+                args["agg"] = agg
             args["exclusive_us"] = round(excl * 1e6, 3)
             out.append({
                 "name": name,

@@ -971,7 +971,6 @@ KNOB_VALIDATORS: Dict[str, str] = {
 KNOB_EXEMPT = frozenset({
     # driver data/geometry knobs
     "block_partitions", "row_chunk", "secure_tables", "reshard",
-    "phase_times",
     # TPUBackend configuration
     "mesh", "max_partitions", "noise_seed", "secure_noise",
     "large_partition_threshold",

@@ -1,0 +1,135 @@
+"""Every span and counter a per-layer metric reads is one the program has.
+
+A per-layer metric of the benchmark is a data file
+(perfbench/layer_metrics/<metric>.json) that names rt_trace spans
+(reader ``span_ms_per_job``) or a telemetry counter (``counter_per_job``).
+Nothing ties those names to the program but this test: each span a listed
+metric names must be recorded by one of two tiny runs — a dense
+ChunkSource job through the engine, a blocked job whose pass 1 takes the
+host-staged branch — and each counter must be declared in
+telemetry.REGISTRY. A rename in the program then fails here instead of
+leaving a null in the ledger.
+"""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+import pipelinedp_tpu as pdp
+from pipelinedp_tpu.runtime import telemetry
+from pipelinedp_tpu.runtime import trace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+READERS = ("span_ms_per_job", "counter_per_job")
+
+
+def _load(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return json.load(f)
+
+
+def _listed_specs():
+    """The layer-metric files BENCHMARK.json lists, of the two readers
+    that name something inside the program."""
+    specs = [_load("perfbench", "layer_metrics", m["name"] + ".json")
+             for m in _load("BENCHMARK.json")["per_layer"]]
+    return [s for s in specs if s["reader"] in READERS]
+
+
+SPECS = _listed_specs()
+
+
+def _dense_chunk_run():
+    rng = np.random.default_rng(0)
+    n = 8000
+    pid, pk = rng.integers(0, 900, n), rng.integers(0, 300, n)
+    values = rng.uniform(1, 5, n)
+    chunks = [(pid[i:i + 2000], pk[i:i + 2000], values[i:i + 2000])
+              for i in range(0, n, 2000)]
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=2,
+        max_contributions_per_partition=1,
+        min_value=1.0,
+        max_value=5.0)
+    ex = pdp.DataExtractors(privacy_id_extractor=lambda r: r[0],
+                            partition_extractor=lambda r: r[1],
+                            value_extractor=lambda r: r[2])
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0, total_delta=1e-6)
+    engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=1))
+    result = engine.aggregate(pdp.ChunkSource(chunks, encode_mode="host"),
+                              params, ex)
+    acc.compute_budgets()
+    assert dict(result)
+
+
+def _blocked_host_staged_run():
+    import jax
+    from pipelinedp_tpu import combiners, executor
+    from pipelinedp_tpu.aggregate_params import MechanismType
+    from pipelinedp_tpu.ops import selection_ops
+    from pipelinedp_tpu.parallel import large_p
+
+    P, n = 1 << 11, 3000
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=2,
+        max_contributions_per_partition=3,
+        min_value=0.0,
+        max_value=5.0)
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=100.0, total_delta=1e-6)
+    compound = combiners.create_compound_combiner(params, acc)
+    budget = acc.request_budget(MechanismType.GENERIC)
+    acc.compute_budgets()
+    selection = selection_ops.selection_params_from_host(
+        params.partition_selection_strategy, budget.eps, budget.delta,
+        params.max_partitions_contributed, None)
+    cfg = executor.make_kernel_config(params, compound, P,
+                                      private_selection=True,
+                                      selection_params=selection)
+    rng = np.random.default_rng(2)
+    large_p.aggregate_blocked(
+        rng.integers(0, 150, n).astype(np.int32),
+        rng.integers(0, P, n).astype(np.int32), rng.uniform(0, 5, n),
+        np.ones(n, bool), *executor.kernel_scalars(params),
+        np.asarray(executor.compute_noise_stds(compound, params)),
+        jax.random.PRNGKey(5), cfg, block_partitions=1 << 9,
+        row_chunk=1000)  # below n: the host-staged pass 1
+
+
+@pytest.fixture(scope="module")
+def recorded_spans():
+    """Names of the spans the two tiny runs recorded."""
+    telemetry.reset()
+    trace.enable()
+    try:
+        _dense_chunk_run()
+        _blocked_host_staged_run()
+        return set(trace.trace_summary()["spans"])
+    finally:
+        trace.disable()
+        telemetry.reset()
+
+
+def test_benchmark_lists_metrics_that_read_the_program():
+    assert {s["reader"] for s in SPECS} == set(READERS)
+
+
+@pytest.mark.parametrize("spec", SPECS, ids=[s["name"] for s in SPECS])
+def test_metric_reads_what_the_program_records(spec, recorded_spans):
+    if spec["reader"] == "span_ms_per_job":
+        assert spec["spans"], spec["name"]
+        missing = [name for name in spec["spans"]
+                   if name not in recorded_spans]
+        assert not missing, (
+            f"{spec['name']} reads rt_trace spans {missing} that neither "
+            f"tiny run recorded; recorded: {sorted(recorded_spans)}")
+    else:
+        metric = telemetry.REGISTRY.get(spec["counter"])
+        assert metric is not None and metric.kind == "counter", (
+            f"{spec['name']} reads telemetry counter {spec['counter']!r}, "
+            f"which telemetry.REGISTRY does not declare as a counter")

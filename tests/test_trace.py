@@ -15,8 +15,15 @@ Three contracts:
     and trace_summary's inclusive/exclusive accounting is coherent.
   * Disabled cost: with tracing off, span() must be a near-free bool
     check — the blocked-driver hot path takes two of them per block.
+  * Causality: every span has an id and the id of the span that caused
+    it, across threads too; the spans of one materialised aggregation
+    share the root's ``agg``; the leaf spans cover their parents; the
+    same spans appear as ``rt:`` annotations on the profiler's clock.
+  * Counters that count with tracing off: backend_compiles, h2d_bytes,
+    d2h_bytes.
 """
 
+import functools
 import json
 import time
 
@@ -272,6 +279,61 @@ class TestSpans:
         scoped = trace.trace_summary(job_id="job-a")["spans"]
         assert set(scoped) == {"work"}
 
+    def test_id_and_parent_on_nested_spans(self):
+        trace.enable()
+        with trace.span("outer", agg=41) as outer:
+            assert trace.current() is outer
+            with trace.span("inner"):
+                with trace.span("leaf"):
+                    pass
+            with trace.span("sibling"):
+                pass
+        assert trace.current() is None
+        args = {e["name"]: e["args"]
+                for e in trace.to_trace_events()["traceEvents"]
+                if e["ph"] == "X"}
+        ids = [a["id"] for a in args.values()]
+        assert len(set(ids)) == 4
+        assert "parent" not in args["outer"]
+        assert args["inner"]["parent"] == args["outer"]["id"]
+        assert args["leaf"]["parent"] == args["inner"]["id"]
+        assert args["sibling"]["parent"] == args["outer"]["id"]
+        # The root's agg reaches every descendant.
+        assert {a["agg"] for a in args.values()} == {41}
+
+    def test_parent_token_crosses_threads(self):
+        """A span opened on another thread with parent=token names the
+        span that caused it and inherits its job and agg, without
+        touching the parent's exclusive time."""
+        import threading
+        trace.enable()
+
+        def work(token):
+            with trace.span("child", parent=token):
+                time.sleep(0.01)
+
+        with rt_health.job_scope("job-t"):
+            with trace.span("root", agg=7):
+                t = threading.Thread(target=work, args=(trace.current(),))
+                t.start()
+                t.join(timeout=10)
+                assert not t.is_alive()
+        events = {e["name"]: e
+                  for e in trace.to_trace_events()["traceEvents"]
+                  if e["ph"] == "X"}
+        child, root = events["child"], events["root"]
+        assert child["tid"] != root["tid"]
+        assert child["args"]["parent"] == root["args"]["id"]
+        assert child["args"]["agg"] == 7
+        assert child["args"]["job"] == "job-t"
+        summary = trace.trace_summary()["spans"]
+        assert summary["root"]["exclusive_s"] == pytest.approx(
+            summary["root"]["inclusive_s"], abs=5e-6)
+
+    def test_current_is_none_when_disabled(self):
+        with trace.span("ghost"):
+            assert trace.current() is None
+
     def test_instants_from_counters(self):
         trace.enable()
         telemetry.record("journal_replays", block=5)
@@ -381,6 +443,76 @@ class TestJitProbe:
         assert trace.compile_stats() == {}
 
 
+def _chunk_job(noise_seed, n=40_000, n_chunks=4, **backend_kw):
+    """A small ChunkSource(encode_mode="host") aggregation through the
+    engine; returns (release dict, rows, number of released columns)."""
+    rng = np.random.default_rng(noise_seed)
+    pid = rng.integers(0, 6000, n)
+    pk = rng.integers(0, 6000, n)
+    values = rng.uniform(1, 5, n)
+    step = n // n_chunks
+    chunks = [(pid[i:i + step], pk[i:i + step], values[i:i + step])
+              for i in range(0, n, step)]
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=2,
+        max_contributions_per_partition=1,
+        min_value=1.0,
+        max_value=5.0)
+    ex = pdp.DataExtractors(privacy_id_extractor=lambda r: r[0],
+                            partition_extractor=lambda r: r[1],
+                            value_extractor=lambda r: r[2])
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0, total_delta=1e-6)
+    engine = pdp.DPEngine(
+        acc, pdp.TPUBackend(noise_seed=noise_seed, **backend_kw))
+    result = engine.aggregate(pdp.ChunkSource(chunks, encode_mode="host"),
+                              params, ex)
+    acc.compute_budgets()
+    return dict(result), n, 2
+
+
+def _blocked_args(n=4000, P=1 << 12, epsilon=1.0):
+    """Arguments of large_p.aggregate_blocked for a small COUNT+SUM job."""
+    import jax
+    from pipelinedp_tpu import combiners, executor
+    from pipelinedp_tpu.aggregate_params import MechanismType
+    from pipelinedp_tpu.ops import selection_ops
+
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=2,
+        max_contributions_per_partition=3,
+        min_value=0.0,
+        max_value=5.0)
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=epsilon,
+                                    total_delta=1e-6)
+    compound = combiners.create_compound_combiner(params, acc)
+    budget = acc.request_budget(MechanismType.GENERIC)
+    acc.compute_budgets()
+    selection = selection_ops.selection_params_from_host(
+        params.partition_selection_strategy, budget.eps, budget.delta,
+        params.max_partitions_contributed, None)
+    cfg = executor.make_kernel_config(params, compound, P,
+                                      private_selection=True,
+                                      selection_params=selection)
+    stds = executor.compute_noise_stds(compound, params)
+    scalars = executor.kernel_scalars(params)
+    rng = np.random.default_rng(3)
+    pid = rng.integers(0, 200, n).astype(np.int32)
+    pk = rng.integers(0, P, n).astype(np.int32)
+    values = rng.uniform(0, 5, n)
+    valid = np.ones(n, bool)
+    return (pid, pk, values, valid, *scalars, np.asarray(stds),
+            jax.random.PRNGKey(11), cfg)
+
+
+def _span_events():
+    return [e for e in trace.to_trace_events()["traceEvents"]
+            if e["ph"] == "X"]
+
+
 class TestBackendIntegration:
 
     def test_trace_knob_validation(self):
@@ -426,67 +558,229 @@ class TestBackendIntegration:
             payload = json.load(f)
         assert len(payload["traceEvents"]) > 5
 
-    def test_blocked_driver_spans_and_phase_partition(self):
+    @pytest.mark.parametrize("row_chunk", [1 << 24, 1000],
+                             ids=["device_resident", "host_staged"])
+    def test_blocked_driver_spans_and_phase_partition(self, row_chunk):
         """A blocked run's spans decompose its wall time: per-block
         dispatch/drain spans exist and the sum of exclusive times
-        reconciles (within 10%) with the driver's entry span."""
-        import jax
-        from pipelinedp_tpu import combiners, executor
-        from pipelinedp_tpu.aggregate_params import MechanismType
-        from pipelinedp_tpu.ops import selection_ops
+        reconciles (within 10%) with the driver's entry span. With
+        row_chunk below the row count pass 1 takes the host-staged
+        branch, whose p1.* leaf spans cover contribution_bounding."""
         from pipelinedp_tpu.parallel import large_p
 
-        P = 1 << 12
-        params = pdp.AggregateParams(
-            metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
-            noise_kind=pdp.NoiseKind.LAPLACE,
-            max_partitions_contributed=2,
-            max_contributions_per_partition=3,
-            min_value=0.0,
-            max_value=5.0)
-        acc = pdp.NaiveBudgetAccountant(total_epsilon=1.0,
-                                        total_delta=1e-6)
-        compound = combiners.create_compound_combiner(params, acc)
-        budget = acc.request_budget(MechanismType.GENERIC)
-        acc.compute_budgets()
-        selection = selection_ops.selection_params_from_host(
-            params.partition_selection_strategy, budget.eps, budget.delta,
-            params.max_partitions_contributed, None)
-        cfg = executor.make_kernel_config(params, compound, P,
-                                          private_selection=True,
-                                          selection_params=selection)
-        stds = executor.compute_noise_stds(compound, params)
-        scalars = executor.kernel_scalars(params)
-        rng = np.random.default_rng(3)
-        n = 4000
-        pid = rng.integers(0, 200, n).astype(np.int32)
-        pk = rng.integers(0, P, n).astype(np.int32)
-        values = rng.uniform(0, 5, n)
-        valid = np.ones(n, bool)
-        args = (pid, pk, values, valid, *scalars, np.asarray(stds),
-                jax.random.PRNGKey(11), cfg)
-        large_p.aggregate_blocked(*args, block_partitions=1 << 10)  # warm
+        args = _blocked_args()
+        run = functools.partial(large_p.aggregate_blocked, *args,
+                                block_partitions=1 << 10,
+                                row_chunk=row_chunk)
+        run()  # warm
         trace.enable()
         # Serial consume loop (overlap=False): the one-thread timeline
         # whose exclusive span times partition the root span by
         # construction. The overlapped drainer records the SAME spans
         # on its own thread — they overlap the dispatch timeline, so
         # only presence (not partition) is asserted for it below.
-        large_p.aggregate_blocked(*args, block_partitions=1 << 10,
-                                  overlap=False)
+        run(overlap=False)
         spans = trace.trace_summary()["spans"]
         for expected in ("aggregate_blocked", "contribution_bounding",
-                         "dispatch", "drain", "consume"):
+                         "p1.chunk", "block_offsets", "dispatch", "drain",
+                         "release_wait", "consume"):
             assert expected in spans, (expected, sorted(spans))
         assert spans["dispatch"]["count"] >= 2  # several blocks
         root = spans["aggregate_blocked"]["inclusive_s"]
         attributed = sum(s["exclusive_s"] for s in spans.values())
         assert abs(attributed - root) <= 0.1 * root + 1e-3, (
             attributed, root)
+        json.dumps(trace.to_trace_events())  # every attribute exports
+        staged = {e["args"]["staged"] for e in _span_events()
+                  if e["name"] == "contribution_bounding"}
+        if row_chunk < len(args[0]):
+            assert staged == {"host"}
+            leaves = ("p1.host_sort", "p1.chunk", "p1.chunk_wait",
+                      "p1.fetch", "p1.merge", "p1.upload")
+            for name in leaves:
+                assert name in spans, (name, sorted(spans))
+            assert spans["p1.chunk"]["count"] >= 2  # several chunks
+            pass1 = spans["contribution_bounding"]["inclusive_s"]
+            covered = sum(spans[name]["inclusive_s"] for name in leaves)
+            assert abs(covered - pass1) <= 0.1 * pass1 + 1e-3, (covered,
+                                                               pass1)
+        else:
+            assert staged == {"device"}
+            assert spans["p1.chunk"]["count"] == 1
+            assert "p1.host_sort" not in spans
         trace.reset()
-        large_p.aggregate_blocked(*args, block_partitions=1 << 10)
+        run(overlap=True)
         spans_overlapped = trace.trace_summary()["spans"]
         for expected in ("aggregate_blocked", "contribution_bounding",
                          "dispatch", "drain", "consume"):
             assert expected in spans_overlapped, (
                 expected, sorted(spans_overlapped))
+        # The drainer thread's spans name the driver's span as their
+        # cause, so they carry the job the dispatch thread ran under.
+        by_id = {e["args"]["id"]: e for e in _span_events()}
+        drainer = next(e for e in by_id.values() if e["name"] == "drainer")
+        driver = by_id[drainer["args"]["parent"]]
+        assert driver["tid"] != drainer["tid"]
+        drains = [e for e in by_id.values() if e["name"] == "drain"]
+        assert drains and all(e["args"]["parent"] == drainer["args"]["id"]
+                              and e["tid"] == drainer["tid"]
+                              for e in drains)
+
+    def test_chunk_aggregate_spans_share_one_root(self):
+        """A ChunkSource aggregation records the ingest and release-wait
+        leaf spans under ONE `aggregate` root: every span of the job
+        carries the root's agg (the pool threads' pipeline_encode spans
+        too, whose parent is the ingest span), and the root's direct
+        children cover its wall time within 10%."""
+        _chunk_job(3)  # warm: compile outside the traced job
+        trace.enable()
+        released, n, _ = _chunk_job(4)
+        assert released
+        events = _span_events()
+        by_name = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        for expected in ("aggregate", "ingest", "ingest.wait",
+                         "ingest.merge", "ingest.stage", "ingest.finalize",
+                         "pipeline_encode", "pipeline_append", "dispatch",
+                         "post_process", "release_wait", "drain"):
+            assert expected in by_name, (expected, sorted(by_name))
+        (root,) = by_name["aggregate"]
+        assert root["args"]["route"] == "dense"
+        assert root["args"]["rows"] >= n
+        agg = root["args"]["agg"]
+        # graph_build ran at graph time, before the root opened.
+        in_job = [e for e in events if e["name"] != "graph_build"]
+        assert {e["args"].get("agg") for e in in_job} == {agg}
+        (ingest,) = by_name["ingest"]
+        assert ingest["args"]["parent"] == root["args"]["id"]
+        assert len(by_name["pipeline_encode"]) == 4
+        for e in by_name["pipeline_encode"]:
+            assert e["args"]["parent"] == ingest["args"]["id"]
+            assert e["tid"] != ingest["tid"]  # a pool thread
+        for name in ("ingest.wait", "ingest.merge"):
+            assert all(e["args"]["parent"] == ingest["args"]["id"]
+                       for e in by_name[name])
+        assert {e["args"]["what"] for e in by_name["release_wait"]} == {
+            "sentinel", "n_kept"}
+        children = sum(e["dur"] for e in events
+                       if e["args"].get("parent") == root["args"]["id"]
+                       and e["tid"] == root["tid"])
+        assert abs(children - root["dur"]) <= 0.1 * root["dur"], (
+            children, root["dur"])
+        # A second aggregation is a second request.
+        trace.reset()
+        _chunk_job(4)
+        (root2,) = [e for e in _span_events() if e["name"] == "aggregate"]
+        assert root2["args"]["agg"] == agg + 1
+
+    def test_rt_spans_are_profiler_annotations_on_one_clock(self, tmp_path):
+        """While tracing is on every span is also a
+        jax.profiler.TraceAnnotation("rt:<name>"): a profiler trace taken
+        around a tiny job holds rt:ingest on the host plane, at the start
+        the rt_trace export gives it (mapped by one anchor) within 1 ms,
+        with the span's id and agg as the event's stats."""
+        import glob
+
+        import jax
+        _chunk_job(5)  # warm
+        trace.enable()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            trace.instant("anchor")
+            with jax.profiler.TraceAnnotation("test_anchor"):
+                pass
+            _chunk_job(6)
+        finally:
+            jax.profiler.stop_trace()
+        (xplane,) = glob.glob(
+            str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        annotations = {}
+        for plane in jax.profiler.ProfileData.from_file(xplane).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("rt:") or e.name == "test_anchor":
+                        annotations.setdefault(e.name, []).append(
+                            (e.start_ns, dict(e.stats)))
+        exported = {e["name"]: e
+                    for e in trace.to_trace_events()["traceEvents"]
+                    if e["name"] in ("anchor", "ingest")}
+        (anchor_ns, _), = annotations["test_anchor"]
+        (ingest_ns, stats), = annotations["rt:ingest"]
+        on_profiler_us = (ingest_ns - anchor_ns) / 1e3
+        on_rt_trace_us = exported["ingest"]["ts"] - exported["anchor"]["ts"]
+        assert abs(on_profiler_us - on_rt_trace_us) < 1e3, (
+            on_profiler_us, on_rt_trace_us)
+        assert stats["id"] == exported["ingest"]["args"]["id"]
+        assert stats["agg"] == exported["ingest"]["args"]["agg"]
+        for name in ("rt:aggregate", "rt:ingest.wait", "rt:release_wait"):
+            assert name in annotations, (name, sorted(annotations))
+
+
+class TestUntracedCounters:
+    """The counters an operator has with rt_trace off."""
+
+    def test_backend_compiles_counts_a_fresh_jit_untraced(self):
+        import jax
+        import jax.numpy as jnp
+        assert not trace.enabled()
+        telemetry.install_compile_listener()
+        telemetry.install_compile_listener()  # idempotent: one listener
+        fresh = jax.jit(lambda x: x * 3 + 11)
+        x = jnp.ones(24)  # its own small program: built before the count
+        before = telemetry.snapshot()
+        fresh(x).block_until_ready()
+        built = telemetry.delta(before)
+        assert built["backend_compiles"] == 1
+        assert all(isinstance(v, int) for v in telemetry.snapshot().values())
+        before = telemetry.snapshot()
+        fresh(x).block_until_ready()  # a dispatch, no compile
+        assert "backend_compiles" not in telemetry.delta(before)
+        assert "jit_cache_misses" not in telemetry.snapshot()
+
+    def test_dense_chunk_job_bytes_follow_its_shapes(self):
+        """h2d_bytes is the encoded rows the accumulator uploaded, d2h_bytes
+        the kept ids and released columns the decode fetched: both from
+        the run's shapes alone, with tracing off."""
+        from pipelinedp_tpu import executor
+        _chunk_job(8)  # warm
+        assert not trace.enabled()
+        before = telemetry.snapshot()
+        released, n, n_columns = _chunk_job(9)
+        moved = telemetry.delta(before)
+        f = np.dtype(executor._ftype()).itemsize
+        assert moved["h2d_bytes"] == n * (4 + 4 + f)
+        # More than _HOST_SLICE_MAX_ROWS partitions: the decode slices to
+        # the kept count on the device and fetches exactly that.
+        assert moved["d2h_bytes"] == len(released) * (4 + n_columns * f)
+
+    def test_blocked_host_staged_bytes_follow_its_shapes(self):
+        from pipelinedp_tpu import executor
+        from pipelinedp_tpu.parallel import large_p
+        args = _blocked_args(epsilon=200.0)
+        run = functools.partial(large_p.aggregate_blocked, *args,
+                                block_partitions=1 << 10, row_chunk=1000)
+        run()  # warm
+        trace.enable()  # for the chunk capacities and the fetch sizes
+        before = telemetry.snapshot()
+        kept, outputs = run()
+        moved = telemetry.delta(before)
+        assert len(kept) > 0
+        f = np.dtype(executor._ftype()).itemsize
+        events = _span_events()
+        caps = [e["args"]["cap"] for e in events if e["name"] == "p1.chunk"]
+        survivors = sum(e["args"]["rows"] for e in events
+                        if e["name"] == "p1.fetch")
+        survivor_bytes = survivors * (4 + 1 + f)  # spk, pair flag, sum
+        control = sum(e["args"]["bytes"] for e in events
+                      if e["name"] == "host_fetch")
+        # Up: each chunk padded to its capacity (pid, pk, value, valid),
+        # then the merged survivors once more.
+        assert moved["h2d_bytes"] == sum(caps) * (4 + 4 + f + 1) + \
+            survivor_bytes
+        # Down: the survivors, the block-offset table, and per kept
+        # partition its id and released columns.
+        assert moved["d2h_bytes"] == survivor_bytes + control + \
+            len(kept) * (4 + len(outputs) * f)
