@@ -20,16 +20,21 @@ axis no longer fits. This module shards the PARTITION axis instead:
      only O(kept) values ever cross the device->host boundary instead of
      dense [C] outputs per block.
 
-Two row-staging regimes, switched on whether the rows fit one device chunk:
+Two row-staging regimes, switched on whether the rows fit one device chunk.
+The chunk is what the device can hold of pass 1: a share of its memory
+limit over the pass-1 program's bytes per row (_pass1_row_budget; 8.5e7
+rows of COUNT+SUM on a 16 GB chip, 2^24 where the platform reports no
+memory stats). An explicit row_chunk= overrides it.
 
-  * **Device-resident** (n <= row_chunk, the common case): rows never
+  * **Device-resident** (n <= the budget, the common case): rows never
     return to the host between passes; per-block inputs are device-side
-    gathers at host-known offsets. Host traffic = block offsets + kept
-    results.
-  * **Host-staged** (n > row_chunk): row chunks split on privacy-id
-    boundaries are bounded+compacted on device, the compacted survivors
-    staged back to host, merged, and re-uploaded per block — preserving
-    the O(row_chunk + C) device-memory bound at any n.
+    gathers at host-known offsets. Host traffic = the padded input columns
+    up once, block offsets + kept results down.
+  * **Host-staged** (n > the budget): the host sorts the rows by privacy
+    id, chunks split on privacy-id boundaries are bounded+compacted on
+    device, the compacted survivors staged back to host, merged, and
+    re-uploaded — preserving the O(budget + C) device-memory bound at
+    any n.
 
 The meshed variants (aggregate_blocked_sharded /
 select_partitions_blocked_sharded) scale both passes D-way: rows shard by
@@ -82,6 +87,7 @@ from pipelinedp_tpu.runtime import aot as rt_aot
 from pipelinedp_tpu.runtime import entry as rt_entry
 from pipelinedp_tpu.runtime import faults as rt_faults
 from pipelinedp_tpu.runtime import journal as rt_journal
+from pipelinedp_tpu.runtime import observability as rt_observability
 from pipelinedp_tpu.runtime import pipeline as rt_pipeline
 from pipelinedp_tpu.runtime import retry as rt_retry
 from pipelinedp_tpu.runtime import telemetry as rt_telemetry
@@ -665,6 +671,62 @@ def _pad_to(a, cap: int, fill):
 
 def _nbytes(*arrays) -> int:
     return sum(int(a.nbytes) for a in arrays)
+
+
+# How many rows pass 1 keeps device-resident: a share of the device's
+# memory limit over _bounded_compact_kernel's bytes per padded row.
+#
+# Bytes per row are memory_analysis() of the program (argument + output +
+# temp; f32 values, i32 keys, as the chip runs), compiled on a TPU v5e
+# for the keys-1e7 cfg and for the described v5e at the other sizes; the
+# two agree to the byte where both were read (PERF.md §6, PR 28):
+#
+#     rows          cfg              argument  output  temp    total
+#     4,194,304     COUNT            13.0      5.0     16.2    34.2
+#     4,194,304     COUNT+SUM        13.0      9.0     16.3    38.3
+#     23,068,672    COUNT+SUM        13.0      9.0     24.1    46.1
+#     23,068,672    +PERCENTILE      13.0      13.0    20.1    46.1
+#     100,663,296   COUNT+SUM        13.0      9.0     28.0    50.0
+#
+# 13 B in (pid, pk, value, valid), 5 B out (spk, pair flag) and 4 B out
+# per carried column (a reduce column; the leaf index with percentiles).
+# The sort's temp grows in 4 B steps with the row count, and a second
+# carried column found room inside it at the sizes read; the model takes
+# the largest temp seen and charges every column in full: 46 + 4 each,
+# i.e. 50 B for COUNT+SUM. After a window of keys-1e7 jobs (23,068,672
+# padded rows) the device's peak_bytes_in_use read 582.8 MB, 25.3 B a
+# row: the analysis bounds what the allocator really holds.
+#
+# A quarter of the limit: what pass 1 leaves must hold the caller's own
+# columns where the input was device-resident (13 B a row again), the
+# compacted outputs that stay for pass 2, and PIPELINE_DEPTH block
+# programs in flight. On a v5e (bytes_limit 16,909,336,064) that is
+# 84.5 M rows of COUNT+SUM; the log's job peaks at 3.4 % of the limit,
+# 7.3 times under the quarter.
+_PASS1_MEMORY_SHARE = 0.25
+_PASS1_BASE_ROW_BYTES = 46.0
+_PASS1_COLUMN_ROW_BYTES = 4.0
+# Where the platform reports no memory stats (the CPU): the fixed chunk
+# every job had before the budget followed the device.
+_PASS1_ROWS_WITHOUT_MEMORY_STATS = 1 << 24
+
+
+def _pass1_bytes_per_row(cfg: executor.KernelConfig) -> float:
+    """Device bytes _bounded_compact_kernel needs per padded row: cfg
+    moves it only through the columns the compaction sort carries — the
+    reduce columns and, with percentiles, the leaf index."""
+    carried = len(executor.reduce_column_names(cfg)) + bool(cfg.quantiles)
+    return _PASS1_BASE_ROW_BYTES + _PASS1_COLUMN_ROW_BYTES * carried
+
+
+def _pass1_row_budget(cfg: executor.KernelConfig, device) -> int:
+    """The rows pass 1 may hold on `device` at once: its share of the
+    device's memory limit over the program's bytes per row. Rows within
+    it stay device-resident; more are host-staged in chunks of it."""
+    limit = rt_observability.device_bytes_limit([device])
+    if limit is None:
+        return _PASS1_ROWS_WITHOUT_MEMORY_STATS
+    return int(_PASS1_MEMORY_SHARE * limit / _pass1_bytes_per_row(cfg))
 
 
 def _bound_and_compact_host_staged(pid, pk, values, valid, min_v, max_v,
@@ -1447,7 +1509,7 @@ def aggregate_blocked(pid,
                       cfg: executor.KernelConfig,
                       *,
                       block_partitions: int = 1 << 20,
-                      row_chunk: int = 1 << 24,
+                      row_chunk: Optional[int] = None,
                       secure_tables=None,
                       overlap: bool = False,
                       retry: Optional[rt_retry.RetryPolicy] = None,
@@ -1460,6 +1522,12 @@ def aggregate_blocked(pid,
     whose per-block quantile trees descend lazily (O(C * branching) peak
     memory) over the block's own rows — but the partition axis is processed
     in blocks of `block_partitions` and only kept partitions are returned.
+
+    row_chunk: pass 1 stays device-resident while the rows fit it and is
+    host-staged in chunks of it above. None (what every served call
+    passes) takes _pass1_row_budget: what the device's memory holds of
+    this cfg's pass-1 program. An integer is that many rows, whatever
+    the device (the tests' seam).
 
     Where the wall time goes is read from rt_trace: contribution_bounding
     (staged=host|device) with its p1.* children, block_offsets, and per
@@ -1496,23 +1564,32 @@ def aggregate_blocked(pid,
     stds = jnp.asarray(stds)
 
     # --- Pass 1: bound rows, compact + spk-sort the survivors. ------------
+    if row_chunk is None:
+        # One host's devices are of one kind: the first one's limit is
+        # the limit of whichever holds the rows.
+        row_chunk = _pass1_row_budget(cfg, jax.local_devices()[0])
     host_staged = n > row_chunk
     with rt_trace.span("contribution_bounding", rows=n,
                        staged="host" if host_staged else "device"):
         if not host_staged:
             # Device-resident: one kernel call, rows stay in HBM for
             # pass 2.
+            rt_telemetry.record("pass1_device_resident")
             cap = round_capacity(n)
             with rt_trace.span("p1.chunk", chunk=0, rows=n, cap=cap):
                 rows_in = (_pad_to(pid, cap, 0), _pad_to(pk, cap, 0),
                            _pad_to(values, cap, 0),
                            _pad_to(valid, cap, False))
                 if not device_resident:
-                    rt_telemetry.record("h2d_bytes", _nbytes(*rows_in))
+                    nbytes = _nbytes(*rows_in)
+                    rt_telemetry.record("h2d_bytes", nbytes)
+                    with rt_trace.span("p1.upload", bytes=nbytes):
+                        rows_in = tuple(jnp.asarray(a) for a in rows_in)
                 spk_all, pair_all, cols_all, leaf_all, _ = \
                     _bounded_compact_kernel(
                         *rows_in, min_v, max_v, min_s, max_s, mid,
                         jax.random.fold_in(rows_key, 0), cfg)
+                del rows_in  # pass 2 reads the compacted outputs only
         else:
             if device_resident:
                 # Host staging re-chunks on privacy-id boundaries with

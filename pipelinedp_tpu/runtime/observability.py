@@ -372,6 +372,25 @@ def _device_memory_stats() -> Optional[Dict[str, int]]:
         return None
 
 
+def device_bytes_limit(devices=None) -> Optional[int]:
+    """Summed ``bytes_limit`` of the given devices' memory stats (default:
+    every locally-addressable device) — None where the platform reports
+    none (CPU) or before jax is imported."""
+    import sys
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        total = 0
+        for device in (jax.local_devices() if devices is None else devices):
+            stats = device.memory_stats()
+            if stats and stats.get("bytes_limit"):
+                total += int(stats["bytes_limit"])
+        return total or None
+    except Exception:  # noqa: BLE001 - absent/partial memory-stats support means "no platform limit": the service's memory_limit_bytes and large_p's CPU row budget exist for exactly that
+        return None
+
+
 def memory_watermark() -> Dict[str, Any]:
     """{"live_bytes", "peak_bytes", "source"}: the device runtime's own
     memory stats where available ("device"), else the byte-accounted

@@ -125,13 +125,19 @@ REGISTRY: Dict[str, Metric] = {
         _counter("h2d_bytes",
                  "bytes of row data copied host->device on the release "
                  "path, from nbytes where they cross: the ingest "
-                 "accumulator's appends, blocked pass 1's chunk inputs "
-                 "and its re-upload of the merged survivors"),
+                 "accumulator's appends, blocked pass 1's padded rows or "
+                 "chunk inputs and its re-upload of the merged survivors"),
         _counter("d2h_bytes",
                  "bytes copied device->host on the release path, from "
                  "nbytes where they cross: pass 1's survivor fetches, "
                  "control-table host_fetch, the blocked staged drains and "
                  "the decode barrier's released columns"),
+        _counter("pass1_device_resident",
+                 "aggregate_blocked calls whose pass 1 stayed "
+                 "device-resident: the rows fit the device's row budget "
+                 "(large_p._pass1_row_budget) or the explicit row_chunk, "
+                 "so no host sort, no survivor round trip; a call that "
+                 "took the host-staged branch does not count"),
         _counter("aot_cache_hits",
                  "warm-path dispatches served by an ahead-of-time "
                  "compiled executable from the process-wide "

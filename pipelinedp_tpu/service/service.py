@@ -673,7 +673,7 @@ class DPAggregationService:
         stats where available, the byte accountant elsewhere)."""
         limit = self._memory_limit_bytes
         if limit is None:
-            limit = _device_bytes_limit()
+            limit = rt_observability.device_bytes_limit()
         if not limit:
             return
         wm = rt_observability.memory_watermark()
@@ -992,22 +992,3 @@ class DPAggregationService:
             "ledgers": self.ledgers(),
             "ledgers_reconciled": self.ledgers_reconciled(),
         }
-
-
-def _device_bytes_limit() -> Optional[int]:
-    """Summed per-device memory limit from the platform's memory stats
-    (None where unsupported — CPU — or before jax imports; the shed
-    check then needs an explicit memory_limit_bytes)."""
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return None
-    try:
-        total = 0
-        for device in jax.local_devices():
-            stats = device.memory_stats()
-            if stats and stats.get("bytes_limit"):
-                total += int(stats["bytes_limit"])
-        return total or None
-    except Exception:  # noqa: BLE001 - absent/partial memory-stats support means "no platform limit", exactly what memory_limit_bytes exists to override
-        return None

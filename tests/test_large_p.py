@@ -8,6 +8,7 @@ from pipelinedp_tpu import combiners, executor
 from pipelinedp_tpu.aggregate_params import MechanismType
 from pipelinedp_tpu.ops import selection_ops
 from pipelinedp_tpu.parallel import large_p
+from pipelinedp_tpu.runtime import telemetry
 
 import jax
 
@@ -376,14 +377,71 @@ class TestBlockedAggregation:
             assert abs(outputs["percentile_50"][j] -
                        true_median) < 3 * leaf + 0.05
 
+class _FakeDevice:
+    """A device whose memory_stats() reports the given limit (None: the
+    platform reports no stats, as the CPU does)."""
+
+    def __init__(self, bytes_limit):
+        self._bytes_limit = bytes_limit
+
+    def memory_stats(self):
+        if self._bytes_limit is None:
+            return None
+        return {"bytes_limit": self._bytes_limit}
+
+
 class TestStagingRegimesAgree:
 
-    def test_device_resident_and_host_staged_agree(self):
+    LOG_ROWS = 21_011_340  # the AOL log of the keys-1e7 deployment
+
+    @pytest.mark.parametrize("case", [
+        "log_fits_16gb", "log_over_a_small_limit", "no_memory_stats",
+        "percentiles_cost_rows"
+    ])
+    def test_row_budget_follows_device_memory(self, case):
+        """row_chunk=None asks _pass1_row_budget: a share of the device's
+        memory limit over the pass-1 program's bytes per row of this
+        cfg; 2^24 rows where the platform reports no memory stats."""
+        cfg, _, _ = _spec(10_154_742)
+
+        def budget(cfg, bytes_limit):
+            return large_p._pass1_row_budget(cfg, _FakeDevice(bytes_limit))
+
+        if case == "log_fits_16gb":
+            # Device-resident with room to spare, and still a bound.
+            assert 2 * self.LOG_ROWS < budget(cfg, 16 << 30) < 1 << 31
+        elif case == "log_over_a_small_limit":
+            # 2 GiB cannot hold the log's pass 1 in its share: host-staged.
+            assert 1 << 20 < budget(cfg, 2 << 30) < self.LOG_ROWS
+        elif case == "no_memory_stats":
+            assert budget(cfg, None) == 1 << 24
+            assert large_p._pass1_row_budget(cfg,
+                                             jax.local_devices()[0]) == 1 << 24
+        else:
+            cfg_pct, _, _ = _spec(10_154_742,
+                                  metrics_list=[
+                                      pdp.Metrics.COUNT, pdp.Metrics.SUM,
+                                      pdp.Metrics.PERCENTILE(50)
+                                  ])
+            cfg_count, _, _ = _spec(10_154_742,
+                                    metrics_list=[pdp.Metrics.COUNT])
+            assert (budget(cfg_pct, 16 << 30) < budget(cfg, 16 << 30) <
+                    budget(cfg_count, 16 << 30))
+            # Proportional to the limit: one rule, no size classes.
+            assert budget(cfg, 16 << 30) == pytest.approx(
+                8 * budget(cfg, 2 << 30), rel=1e-6)
+
+    @pytest.mark.parametrize("chosen", [False, True],
+                             ids=["explicit_row_chunk", "row_chunk_none"])
+    def test_device_resident_and_host_staged_agree(self, chosen,
+                                                   monkeypatch):
         """The two row-staging regimes (rows fit one chunk vs chunked host
         staging) must produce the same kept set and noise-free values on
         bounded data at huge epsilon — per-chunk RNG folding differs, so
         agreement must come from determinism of the bounded computation,
-        not shared draws."""
+        not shared draws. With row_chunk=None the regime follows the
+        device's memory limit: none reported (the CPU) keeps these rows
+        on the device, a limit too small for them stages them."""
         rng = np.random.default_rng(2)
         P = 1 << 12
         # Bounded by construction: each user in exactly l0=4 partitions,
@@ -422,8 +480,25 @@ class TestStagingRegimesAgree:
                                              block_partitions=1 << 10,
                                              row_chunk=row_chunk)
 
-        kept_fast, outs_fast = run(1 << 20)
-        kept_host, outs_host = run(1024)
+        def resident_calls():
+            return telemetry.snapshot().get("pass1_device_resident", 0)
+
+        before = resident_calls()
+        if chosen:
+            kept_fast, outs_fast = run(None)
+            assert resident_calls() == before + 1
+            # A device whose share holds about 1,000 of the 4,804 rows.
+            small = int(1000 * large_p._pass1_bytes_per_row(cfg) /
+                        large_p._PASS1_MEMORY_SHARE)
+            monkeypatch.setattr(large_p.rt_observability,
+                                "device_bytes_limit",
+                                lambda devices=None: small)
+            kept_host, outs_host = run(None)
+        else:
+            kept_fast, outs_fast = run(1 << 20)
+            assert resident_calls() == before + 1
+            kept_host, outs_host = run(1024)
+        assert resident_calls() == before + 1  # the host regime: no count
         assert np.array_equal(kept_fast, kept_host)
         assert len(kept_fast) == 120  # the 30*4 dense partitions
         assert np.all(np.diff(kept_fast) > 0)

@@ -573,6 +573,8 @@ class TestBackendIntegration:
                                 block_partitions=1 << 10,
                                 row_chunk=row_chunk)
         run()  # warm
+        resident_before = telemetry.snapshot().get("pass1_device_resident",
+                                                   0)
         trace.enable()
         # Serial consume loop (overlap=False): the one-thread timeline
         # whose exclusive span times partition the root span by
@@ -580,6 +582,8 @@ class TestBackendIntegration:
         # on its own thread — they overlap the dispatch timeline, so
         # only presence (not partition) is asserted for it below.
         run(overlap=False)
+        resident = (telemetry.snapshot().get("pass1_device_resident", 0) -
+                    resident_before)
         spans = trace.trace_summary()["spans"]
         for expected in ("aggregate_blocked", "contribution_bounding",
                          "p1.chunk", "block_offsets", "dispatch", "drain",
@@ -595,6 +599,7 @@ class TestBackendIntegration:
                   if e["name"] == "contribution_bounding"}
         if row_chunk < len(args[0]):
             assert staged == {"host"}
+            assert resident == 0
             leaves = ("p1.host_sort", "p1.chunk", "p1.chunk_wait",
                       "p1.fetch", "p1.merge", "p1.upload")
             for name in leaves:
@@ -606,8 +611,19 @@ class TestBackendIntegration:
                                                                pass1)
         else:
             assert staged == {"device"}
+            assert resident == 1  # counted once per call, not per block
             assert spans["p1.chunk"]["count"] == 1
-            assert "p1.host_sort" not in spans
+            # The host columns' one explicit copy up, inside the chunk.
+            assert spans["p1.upload"]["count"] == 1
+            upload = next(e for e in _span_events()
+                          if e["name"] == "p1.upload")
+            # pid + pk + value (f64 under the tests' x64) + valid, padded.
+            row_bytes = 9 + np.dtype(large_p.executor._ftype()).itemsize
+            assert upload["args"]["bytes"] == row_bytes * \
+                large_p.round_capacity(len(args[0]))
+            for name in ("p1.host_sort", "p1.chunk_wait", "p1.fetch",
+                         "p1.merge"):
+                assert name not in spans, name
         trace.reset()
         run(overlap=True)
         spans_overlapped = trace.trace_summary()["spans"]
