@@ -15,7 +15,9 @@ observed = {
                  ProgramsBuilt, from jax.monitoring),
   "trace":       the reduction of the profiler trace (trace_reduce.
                  reduce_trace): busy_s, window_s, jobs (traced), ...
-  "rows_per_job", "kept_per_job", "released_columns", "device_kind"
+  "min_bytes_per_job": the least bytes one job has to move through device
+                 memory, as the configuration's law counts them,
+  "device_kind": as JAX reports it (the key of trace_reduce.PEAKS)
 }
 """
 
@@ -75,14 +77,15 @@ def device_idle_pct(spec, observed):
 
 
 def min_bytes_roofline_pct(spec, observed):
-    """Least time for the job's bytes at peak HBM rate ÷ device busy time
-    per job (trace_reduce.min_bytes)."""
+    """Least time for the job's bytes (its law's `min_bytes`) at the peak
+    HBM rate of every device the trace shows working ÷ their mean busy
+    time per job."""
     busy = _busy_s_per_job(observed)
     if busy is None:
         return None
     return trace_reduce.min_bytes_roofline_pct(
-        observed["rows_per_job"], observed["kept_per_job"],
-        observed["released_columns"], busy, observed["device_kind"])
+        observed["min_bytes_per_job"], busy, observed["device_kind"],
+        observed["trace"]["devices"])
 
 
 READERS = {

@@ -3,8 +3,9 @@
     python3 -m perfbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Run from the root of a checkout. Finds the cell, its configuration and its
-per-layer metrics BY FILE NAME from what BENCHMARK.json lists, so a later
-PR adds any of them as files plus entries (README.md). Raises, and prints
+per-layer metrics BY FILE NAME from what BENCHMARK.json lists, and through
+them the deployment's generator, job form and law (perfbench.find), so a
+later PR adds any of them as files plus entries (README.md). Raises, and prints
 no result, when JAX reports anything but a TPU or fewer chips than the
 cell asks for; `--rehearse` relaxes that for a run on the CPU at the
 configuration's `rehearsal` sizes, says so in `device`, and is never a
@@ -94,8 +95,18 @@ PERSIST_S = 1.0  # JAX's default
 SMALL_S = 0.2
 # What one drain can explain: the program slices the released columns to
 # the kept count on the device, two `dynamic_slice` programs for a kept
-# count the process has not seen (measured: 0 or 2 per drain).
+# count the process has not seen (measured: 0 or 2 per drain). A cell on
+# a route whose slices build otherwise states its own count, with the
+# measurement, as `traffic.small_programs_per_drain`.
 SMALL_PER_DRAIN = 2
+
+
+def small_allowed(cell):
+    """Small programs one job of the cell may build: what its drains can
+    explain."""
+    spec = cell["traffic"]
+    return int(spec.get("small_programs_per_drain", SMALL_PER_DRAIN)) * int(
+        spec["drains_per_job"])
 
 
 class ProgramsBuilt:
@@ -112,9 +123,10 @@ class ProgramsBuilt:
     (executor.decode_release_results, `col[:k]`), a new `dynamic_slice`
     program for every kept count not yet seen in the process — no warm-up
     can cover them, every user's job pays them, and they are never in the
-    persistent cache. A job may build SMALL_PER_DRAIN of them for each
-    drain it makes (the cell's `traffic.drains_per_job`); more fails the
-    job, whatever they are. They are counted, per job, as the per-layer
+    persistent cache. A job may build SMALL_PER_DRAIN of them (or the
+    cell's own `traffic.small_programs_per_drain`) for each drain it makes
+    (the cell's `traffic.drains_per_job`); more fails the job, whatever
+    they are. They are counted, per job, as the per-layer
     metric `window_builds_per_job`.
     `seconds`: every build's duration in order, a cache load negative, so
     that the result's `run` notes show what was built where."""
@@ -168,11 +180,14 @@ def require_device(jax, chips, rehearse):
     return stamp
 
 
-def sized(config, rehearse):
-    """(configuration, rows per job) as they are run: the file's own, or
-    with `rehearse` its `rehearsal` sizes."""
+def sized(cell, config, rehearse):
+    """(configuration, rows per job) as they are run. Rows: the cell's own
+    `traffic.rows_per_job` where it states one (a cut or growth its file
+    lists under `reduced`), else the configuration's. With `rehearse`, the
+    configuration's `rehearsal` sizes."""
     if not rehearse:
-        return config, int(config["scale"]["rows_per_job"])
+        return config, int(cell["traffic"].get(
+            "rows_per_job", config["scale"]["rows_per_job"]))
     toy = config["rehearsal"]
     generator = dict(config["generator"], args=toy["generator_args"])
     config = dict(config, generator=generator,
@@ -232,13 +247,13 @@ def program_spans(rt_trace, anchor_s):
             for e in events if e.get("ph") == "X"]
 
 
-def release_arrays(release, columns):
-    """A job's release dict as (keys, values[n, columns]) for the
-    comparison."""
+def release_arrays(release):
+    """A job's release dict (never empty: such a job failed) as (keys,
+    values[n, released columns]) for the comparison."""
     import numpy as np
     keys = np.fromiter(release.keys(), dtype=np.int64, count=len(release))
     values = np.array(list(release.values()), dtype=np.float64).reshape(
-        len(release), columns)
+        len(release), len(next(iter(release.values()))))
     return keys, values
 
 
@@ -258,7 +273,8 @@ def execute(args):
     from perfbench import data, readers, reference, trace_reduce, traffic
 
     # ---- set-up: rows from the seed, one warm-up job --------------------
-    config, rows_per_job = sized(config, args.rehearse)
+    config, rows_per_job = sized(cell, config, args.rehearse)
+    law = reference.law_of(config)
     t = time.perf_counter()
     imports_s = t - T0  # python, jax, the device, the program's modules
     columns = data.generate(config["generator"], rows_per_job, args.seed)
@@ -280,10 +296,10 @@ def execute(args):
     setup_s = time.perf_counter() - T0
 
     # ---- the measured window ------------------------------------------
-    small_allowed = SMALL_PER_DRAIN * int(cell["traffic"]["drains_per_job"])
+    allowed = small_allowed(cell)
     builds_before = len(built.seconds)
     records, (w_start, w_end) = traffic.closed_loop(
-        job, args.seed, args.seconds, rows_per_job, built, small_allowed,
+        job, args.seed, args.seconds, rows_per_job, built, allowed,
         profiler.on_job_start if tracing else None)
 
     # ---- what the program and the device recorded -----------------------
@@ -301,16 +317,16 @@ def execute(args):
         if r["failed"]:
             print(f"[perfbench] job {r['index']} failed: error={r['error']} "
                   f"large programs built={r['programs_built']} small="
-                  f"{r['small_programs_built']} (allowed {small_allowed})",
+                  f"{r['small_programs_built']} (allowed {allowed})",
                   file=sys.stderr)
 
     # ---- the comparison, on what the window's jobs released -------------
     g = config["guarantees"]
     t = time.perf_counter()
-    expect = reference.expectations(*columns, g)
-    releases = [release_arrays(r["release"], len(g["metrics"])) for r in done]
-    correct, compared = reference.decide(
-        reference.compare(expect, releases), cell["limits"])
+    expect = law.expectations(*columns, g)
+    releases = [release_arrays(r["release"]) for r in done]
+    correct, compared = reference.decide(law.compare(expect, releases),
+                                         cell["limits"])
     reference_s = time.perf_counter() - t
 
     # ---- the metrics ------------------------------------------------------
@@ -336,13 +352,12 @@ def execute(args):
             with open(kept + ".reduced.json", "w") as f:
                 json.dump(reduced, f, indent=1)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        kept_per_job = (float(np.mean([len(r["release"]) for r in done]))
+                        if done else 0.0)
         observed = {
             "jobs": len(records), "spans": spans, "counters": counters,
-            "window_builds": built.small - setup_small,
-            "trace": reduced, "rows_per_job": rows_per_job,
-            "kept_per_job": float(np.mean([len(r["release"]) for r in done]))
-            if done else 0.0,
-            "released_columns": len(g["metrics"]),
+            "window_builds": built.small - setup_small, "trace": reduced,
+            "min_bytes_per_job": law.min_bytes(rows_per_job, kept_per_job, g),
             "device_kind": stamp["kind"],
         }
         for spec in layers:
@@ -367,6 +382,8 @@ def execute(args):
                               for r in records]
                        for name in ("ingest", "post_process")}
         if tracing else {},
+        "span_ms_per_job": {name: 1e3 * row["inclusive_s"] / len(records)
+                            for name, row in spans.items()},
         "kept": [len(r["release"]) for r in done][:4],
         "imports_s": imports_s, "generate_s": generate_s,
         "warm_job_s": warm_s,

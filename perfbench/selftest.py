@@ -1,9 +1,10 @@
 """`python3 -m perfbench.run --selftest`: the benchmark's own arithmetic,
 checked without a chip — the interval reduction on hand-made intervals
 and on the recorded trace in fixtures/, the roofline's byte count against
-the two cells' hand-computed values, the closed forms of the reference
-against brute force, and the reference against its own simulator (sound:
-passes; each break: caught)."""
+hand-computed values on one chip and on four, the closed forms of the
+reference against brute force, the reference against its own simulator
+(sound: passes; each break: caught), and that every generator, form and
+law a listed file names is there to be found."""
 
 import json
 import math
@@ -11,7 +12,9 @@ import os
 
 import numpy as np
 
+import perfbench
 from perfbench import reference, trace_reduce
+from perfbench.laws import bounded_laplace_geometric as law
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -92,10 +95,14 @@ def roofline():
     x 4 B = 136,000 B; at 819 GB/s that is 0.26647 ms, so 0.1 % of a job
     whose device time is 266.47 ms. 2^22 rows x 13 B = 54,525,952 B, + 3,000
     kept x 8 B."""
-    a = trace_reduce.min_bytes(1 << 24, 17_000, 2)
-    b = trace_reduce.min_bytes(1 << 22, 3_000, 2)
-    pct = trace_reduce.min_bytes_roofline_pct(1 << 24, 17_000, 2, 0.26647,
-                                              "TPU v5 lite")
+    two = {"metrics": ["count", "sum"]}
+    a = law.min_bytes(1 << 24, 17_000, two)
+    b = law.min_bytes(1 << 22, 3_000, two)
+    pct = trace_reduce.min_bytes_roofline_pct(a, 0.26647, "TPU v5 lite", 1)
+    # Four chips, each busy the same 266.47 ms for a job of 2^26 rows: four
+    # times the bytes over four times the bandwidth, the same 0.1 %.
+    pct4 = trace_reduce.min_bytes_roofline_pct(
+        law.min_bytes(1 << 26, 68_000, two), 0.26647, "TPU v5 lite", 4)
     unknown = False
     try:
         trace_reduce.peaks_for("TPU v9")
@@ -105,9 +112,11 @@ def roofline():
         check("min_bytes dense", a == 218_103_808 + 136_000, str(a)),
         check("min_bytes blocked", b == 54_525_952 + 24_000, str(b)),
         check("roofline share", abs(pct - 0.1) < 1e-4, str(pct)),
+        check("roofline share over four chips", abs(pct4 - 0.1) < 1e-4,
+              str(pct4)),
         check("unknown device kind is an error", unknown),
         check("nothing ran -> no share", trace_reduce.min_bytes_roofline_pct(
-            1 << 24, 17_000, 2, 0.0, "TPU v5 lite") is None),
+            a, 0.0, "TPU v5 lite", 1) is None),
     ])
 
 
@@ -120,8 +129,8 @@ GUARANTEES = {"epsilon": 1.0, "delta": 1e-6, "l0": 2, "linf": 1,
 def selection():
     """π(n) by the recurrence it is defined by (Desfontaines et al.):
     π(n) = min(e^ε' π(n−1) + δ', 1 − e^{−ε'}(1 − π(n−1) − δ'), 1)."""
-    b = reference.budgets(GUARANTEES)
-    sel = reference.TruncatedGeometric(b["select_eps"], b["select_delta"], 2)
+    b = law.budgets(GUARANTEES)
+    sel = law.TruncatedGeometric(b["select_eps"], b["select_delta"], 2)
     e, d = sel.eps1, sel.delta1
     pi, worst = 0.0, 0.0
     for n in range(1, 600):
@@ -158,23 +167,52 @@ def reference_against_itself():
               "count_noise": 0.4, "sum_noise": 0.4, "ids_noise": 0.4,
               "max_abs_z": 14}
     rows = small_rows()
-    expect = reference.expectations(*rows, GUARANTEES)
-    pairs = reference.Pairs(*rows, GUARANTEES)
+    expect = law.expectations(*rows, GUARANTEES)
+    pairs = law.Pairs(*rows, GUARANTEES)
     ok = True
-    for broken in (None,) + reference.BREAKS:
+    for broken in (None,) + law.BREAKS:
         rng = np.random.default_rng(5)
-        releases = [reference.simulate_release(pairs, GUARANTEES, rng, broken)
+        releases = [law.simulate_release(pairs, GUARANTEES, rng, broken)
                     for _ in range(12)]
         correct, table = reference.decide(
-            reference.compare(expect, releases), limits)
+            law.compare(expect, releases), limits)
         over = [k for k, row in table.items() if not row["ok"]]
         ok &= check(f"simulator {broken or 'sound'}",
                     correct == (broken is None), f"over: {over}")
     return ok
 
 
+def found_by_name():
+    """Every generator, form and law that a configuration or a cell of
+    BENCHMARK.json names is a file under perfbench/, with what the harness
+    calls on it; a name that is not there is an error that lists what is."""
+    from perfbench import run as perfbench_run
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        cells = [w["name"] for w in json.load(f)["workloads"]]
+    ok = True
+    for name in cells:  # every configuration is some cell's
+        cell, config, _, _ = perfbench_run.load_cell(name)
+        generator = perfbench.find("generators", config["generator"]["name"])
+        form = perfbench.find("forms", cell["traffic"]["input_form"])
+        law_found = reference.law_of(config)
+        ok &= check(f"{name}: generator, form and law",
+                    callable(generator.generate) and
+                    callable(form.build_job) and all(
+                        callable(getattr(law_found, part, None)) for part in
+                        ("expectations", "compare", "min_bytes",
+                         "simulate_release", "Pairs")))
+    missing = ""
+    try:
+        perfbench.find("forms", "no_such_form")
+    except SystemExit as e:
+        missing = str(e)
+    return ok & check("a missing name lists what exists",
+                      all(name in missing
+                          for name in perfbench.names("forms")), missing)
+
+
 def main():
     parts = [intervals(), roofline(), selection(), reference_against_itself(),
-             fixture()]
+             fixture(), found_by_name()]
     print(f"[selftest] {'passed' if all(parts) else 'FAILED'}")
     return 0 if all(parts) else 1

@@ -1,8 +1,9 @@
-"""The control, at a size a test run can hold: the plain reference put in
-the program's place passes every cell's comparison at the cell's own
-limits, and with ONE stated guarantee broken it fails — for each break
-the cell's file lists under `controls` (every guarantee its traffic binds
-on; PERF.md §2 says which one a configuration's data cannot show)."""
+"""The control, at a size a test run can hold: the plain reference (the law
+the cell's configuration names) put in the program's place passes every
+cell's comparison at the cell's own limits, and with ONE stated guarantee
+broken it fails — for each break the cell's file lists under `controls`
+(every guarantee its traffic binds on; PERF.md §2 says which one a
+configuration's data cannot show)."""
 
 import json
 import os
@@ -11,6 +12,7 @@ import numpy as np
 import pytest
 
 from perfbench import data, reference
+from perfbench import run as perfbench_run
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELLS = sorted(f[:-5] for f in os.listdir(os.path.join(HERE, "workloads")))
@@ -25,23 +27,22 @@ def load(kind, name):
 
 @pytest.fixture(scope="module", params=CELLS)
 def cell(request):
+    """The cell at its rehearsal size, with the law its configuration
+    names: (cell, guarantees, law, expectations, pairs)."""
     cell = load("workloads", request.param)
-    config = load("configs", cell["config"])
-    generator = dict(config["generator"],
-                     args=config["rehearsal"]["generator_args"])
-    rows = data.generate(generator, config["rehearsal"]["rows_per_job"], 4242)
-    g = config["guarantees"]
-    return (cell, g, reference.expectations(*rows, g),
-            reference.Pairs(*rows, g))
+    config, rows_per_job = perfbench_run.sized(
+        cell, load("configs", cell["config"]), rehearse=True)
+    rows = data.generate(config["generator"], rows_per_job, 4242)
+    g, law = config["guarantees"], reference.law_of(config)
+    return cell, g, law, law.expectations(*rows, g), law.Pairs(*rows, g)
 
 
 def decide(cell, broken, seed):
-    cell, g, expect, pairs = cell
+    cell, g, law, expect, pairs = cell
     rng = np.random.default_rng(seed)
-    releases = [reference.simulate_release(pairs, g, rng, broken)
+    releases = [law.simulate_release(pairs, g, rng, broken)
                 for _ in range(JOBS)]
-    return reference.decide(reference.compare(expect, releases),
-                            cell["limits"])
+    return reference.decide(law.compare(expect, releases), cell["limits"])
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])

@@ -4,25 +4,40 @@ program is patched where it produces its answers, and `correct` must come
 out false. Once for each fault these cells can have:
 
   half_batch      half of the rows never reach the engine (every second
-                  chunk / every second row left out), the release made
-                  over the rest;
+                  row of every chunk / of the columns left out), the
+                  release made over the rest;
   answer_altered  one job's release has the answers of two partitions (its
                   largest and its smallest count) exchanged where the
-                  program decodes them.
+                  program decodes them;
+  psum_left_out   (a cell on several chips) the shards' partial columns
+                  are not combined: the release is one shard's own;
+  exchange_left_out  (a cell on several chips) the rows are laid over the
+                  mesh but never exchanged, so a privacy id's rows stay on
+                  several shards and each bounds them on its own.
 
-(A step that returns its state unchanged and an exchange between chips
-left out are faults of a training loop and of a mesh: neither cell has
-one.) The sound program, driven the same way, is correct.
+(A step that returns its state unchanged is a fault of a training loop: no
+cell has one.) The sound program, driven the same way, is correct.
 """
 
 import argparse
+import json
+import os
 
 import numpy as np
 import pytest
 
 from perfbench import run as perfbench_run
 
-CELLS = ("netflix-sum-chunks", "keys1e7-sum-blocked")
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELLS = sorted(f[:-5] for f in os.listdir(os.path.join(HERE, "workloads")))
+
+
+def cell_file(workload):
+    with open(os.path.join(HERE, "workloads", workload + ".json")) as f:
+        return json.load(f)
+
+
+MESHED = [c for c in CELLS if cell_file(c)["chips"] > 1]
 
 
 def drive(workload, seed=99):
@@ -36,6 +51,16 @@ def over(result):
             if limit is not None and not value <= limit]
 
 
+@pytest.fixture
+def fresh_programs():
+    """A fault planted inside a jitted program only takes once the sound
+    program's trace is dropped, and must not outlive its test."""
+    import jax
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("workload", CELLS)
 def test_sound_program_is_correct(workload):
     result = drive(workload)
@@ -47,21 +72,26 @@ def test_sound_program_is_correct(workload):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_half_batch_is_not_correct(workload, monkeypatch):
+    """Where the cell's job is given its rows: every second row of every
+    chunk, or of the encoded columns, never reaches the engine."""
     from pipelinedp_tpu import columnar
     from pipelinedp_tpu.runtime import pipeline
 
-    chunk_init = pipeline.ChunkSource.__init__
+    if cell_file(workload)["traffic"]["input_form"].startswith("chunks"):
+        chunk_init = pipeline.ChunkSource.__init__
 
-    def half_chunks(self, chunks, *a, **kw):
-        chunk_init(self, list(chunks)[::2], *a, **kw)
+        def half_chunks(self, chunks, *a, **kw):
+            chunk_init(self, [tuple(c[::2] for c in chunk)
+                              for chunk in chunks], *a, **kw)
 
-    encoded_init = columnar.EncodedData.__init__
+        monkeypatch.setattr(pipeline.ChunkSource, "__init__", half_chunks)
+    else:
+        encoded_init = columnar.EncodedData.__init__
 
-    def half_rows(self, pid, pk, values, *a, **kw):
-        encoded_init(self, pid[::2], pk[::2], values[::2], *a, **kw)
+        def half_rows(self, pid, pk, values, *a, **kw):
+            encoded_init(self, pid[::2], pk[::2], values[::2], *a, **kw)
 
-    monkeypatch.setattr(pipeline.ChunkSource, "__init__", half_chunks)
-    monkeypatch.setattr(columnar.EncodedData, "__init__", half_rows)
+        monkeypatch.setattr(columnar.EncodedData, "__init__", half_rows)
     result = drive(workload)
     assert not result["correct"]
     assert "count_bias_z" in over(result)
@@ -93,3 +123,30 @@ def test_altered_answer_is_not_correct(workload, monkeypatch):
     assert calls["n"] >= 2
     assert not result["correct"]
     assert "max_abs_z" in over(result)
+
+
+@pytest.mark.parametrize("workload", MESHED)
+def test_psum_left_out_is_not_correct(workload, monkeypatch, fresh_programs):
+    from pipelinedp_tpu.parallel import sharded
+
+    monkeypatch.setattr(sharded, "_combine_partials", lambda cols, cfg: cols)
+    result = drive(workload)
+    assert result["device"]["count"] > 1
+    assert not result["correct"]
+    assert "count_bias_z" in over(result)
+
+
+@pytest.mark.parametrize("workload", MESHED)
+def test_exchange_left_out_is_not_correct(workload, monkeypatch,
+                                          fresh_programs):
+    from pipelinedp_tpu.parallel import reshard
+
+    def lay_out_only(mesh, pid, pk, values, valid, salt=0):
+        per_shard = reshard.rows_per_shard(pid.shape[0], mesh.devices.size)
+        return reshard._pad_and_shard(mesh, per_shard, pid, pk, values, valid)
+
+    monkeypatch.setattr(reshard, "device_reshard_rows_by_pid", lay_out_only)
+    result = drive(workload)
+    assert result["device"]["count"] > 1
+    assert not result["correct"]
+    assert "ids_bias_z" in over(result)

@@ -2,12 +2,13 @@
 
   lower readings  the program, on `--seeds` seeds, a window of `--seconds`
                   each, in ONE process (one set-up): the numbers of
-                  reference.compare for each seed;
+                  the law's `compare` for each seed;
   upper readings  the controls, on the first `--control-seeds` of those
                   seeds at the cell's own size: the plain reference put in
-                  the program's place (reference.simulate_release), sound
-                  and with each guarantee of the cell's `controls` broken,
-                  `--control-jobs` jobs each.
+                  the program's place (the `simulate_release` of the law
+                  the configuration names), sound and with each guarantee
+                  of the cell's `controls` broken, `--control-jobs` jobs
+                  each.
 
 Every reading goes through the harness's own comparison (reference.decide
 at the cell's limits) and carries its verdict: `correct`, and the numbers
@@ -19,7 +20,9 @@ that were over.
 Writes <dir>/<cell>.calibrate.jsonl, one line per reading. By hand, on the
 chip; not part of a benchmark run. `--controls-only` touches no chip (the
 control is numpy alone), so it can run beside a `--program-only` process
-in one call; `--rehearse` is for the sandbox.
+in one call; `--rehearse` is for the sandbox. The chips are the cell's
+(`chips` in its file): run a four-chip cell's program on the four-chip
+host.
 """
 
 import argparse
@@ -48,11 +51,10 @@ def main(argv=None):
     args = parser.parse_args(argv)
 
     cell, config, _, _ = perfbench_run.load_cell(args.workload)
-    config, rows_per_job = perfbench_run.sized(config, args.rehearse)
+    config, rows_per_job = perfbench_run.sized(cell, config, args.rehearse)
     generator, g = config["generator"], config["guarantees"]
-    columns_released = len(g["metrics"])
-    small_allowed = perfbench_run.SMALL_PER_DRAIN * int(
-        cell["traffic"]["drains_per_job"])
+    law = reference.law_of(config)
+    small_allowed = perfbench_run.small_allowed(cell)
 
     def verdict(numbers):
         correct, table = reference.decide(numbers, cell["limits"])
@@ -78,7 +80,7 @@ def main(argv=None):
             seed = args.first_seed + 7919 * i
             columns = data.generate(generator, rows_per_job, seed)
             t = time.perf_counter()
-            expect = reference.expectations(*columns, g)
+            expect = law.expectations(*columns, g)
             expect_s = time.perf_counter() - t
             if not args.controls_only:
                 job = traffic.build_job(cell, config, columns)
@@ -90,9 +92,9 @@ def main(argv=None):
                     job, seed, args.seconds, rows_per_job, built,
                     small_allowed)
                 done = [r for r in records if not r["failed"]]
-                releases = [perfbench_run.release_arrays(
-                    r["release"], columns_released) for r in done]
-                emit(dict(verdict(reference.compare(expect, releases)),
+                releases = [perfbench_run.release_arrays(r["release"])
+                            for r in done]
+                emit(dict(verdict(law.compare(expect, releases)),
                           kind="program", seed=seed, jobs=len(done),
                           failed=len(records) - len(done),
                           rows_per_s=len(done) * rows_per_job / (end - start),
@@ -103,14 +105,13 @@ def main(argv=None):
                           expect_s=expect_s, sure=int(expect["sure"].sum())))
                 del job, records, done, releases
             if i < args.control_seeds and not args.program_only:
-                pairs = reference.Pairs(*columns, g)
+                pairs = law.Pairs(*columns, g)
                 for broken in [None] + list(cell["controls"]):
                     rng = np.random.default_rng(seed)
                     t = time.perf_counter()
-                    releases = [reference.simulate_release(pairs, g, rng,
-                                                           broken)
+                    releases = [law.simulate_release(pairs, g, rng, broken)
                                 for _ in range(args.control_jobs)]
-                    emit(dict(verdict(reference.compare(expect, releases)),
+                    emit(dict(verdict(law.compare(expect, releases)),
                               kind="control", broken=broken or "sound",
                               seed=seed, jobs=args.control_jobs,
                               simulate_s=time.perf_counter() - t))

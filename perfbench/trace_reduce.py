@@ -1,5 +1,6 @@
 """From a jax.profiler trace (.xplane.pb) to device busy/idle, per-op time
-and attributed idle gaps; the table of peaks; the roofline's byte count.
+and attributed idle gaps; the table of peaks; the roofline's share (the
+bytes themselves travel with the deployment's law: laws/<law>.min_bytes).
 
 Read with nothing but JAX (`jax.profiler.ProfileData`). What a trace of
 this system looks like on a TPU v5e (looked at by hand, PERF.md §5):
@@ -45,23 +46,16 @@ def peaks_for(device_kind):
     return PEAKS[device_kind]
 
 
-def min_bytes(rows, kept_partitions, released_columns):
-    """The fewest bytes a release of this job has to move through HBM:
-    every row read once — privacy id 4 B, partition id 4 B, value 4 B,
-    valid flag 1 B — and every kept partition's released columns (4 B
-    each) written once. From shapes alone; the operations are negligible
-    beside it (a handful per row), so the roofline is the memory one."""
-    return rows * 13 + kept_partitions * released_columns * 4
-
-
-def min_bytes_roofline_pct(rows, kept_partitions, released_columns,
-                           busy_s_per_job, device_kind):
-    """Least time for the job's bytes at the chip's peak HBM rate, as a
-    share of the time the device was busy for it. None when nothing ran."""
+def min_bytes_roofline_pct(min_bytes, busy_s_per_job, device_kind, devices):
+    """Least time for a job's bytes at the peak HBM rate of ALL the devices
+    that worked on it, as a share of the time they were busy for it.
+    `min_bytes`: the whole job's least bytes (its law's `min_bytes`);
+    `busy_s_per_job`: busy time per job averaged over the `devices` the
+    trace shows working (reduce_trace's busy_s), so a job spread over four
+    chips is held to four chips' bandwidth. None when nothing ran."""
     if not busy_s_per_job or busy_s_per_job <= 0:
         return None
-    least_s = (min_bytes(rows, kept_partitions, released_columns) /
-               peaks_for(device_kind)["hbm_bytes_per_s"])
+    least_s = min_bytes / (devices * peaks_for(device_kind)["hbm_bytes_per_s"])
     return 100.0 * least_s / busy_s_per_job
 
 

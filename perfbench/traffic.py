@@ -1,31 +1,36 @@
 """The one traffic driver: a closed-loop batch-job loop.
 
-An analyst's batch job, again and again: raw rows in -> `DPEngine` ->
-`compute_budgets` -> the release materialised as a Python dict. One job in
-flight; the next starts when the last has returned. The window opens at the
-first job's start and closes when the job in flight at `seconds` returns:
-no job is cut and none is dropped from the count.
+An analyst's batch job, again and again: raw rows in -> the release
+materialised as a Python dict. One job in flight; the next starts when the
+last has returned. The window opens at the first job's start and closes
+when the job in flight at `seconds` returns: no job is cut and none is
+dropped from the count.
 
-What a job is comes from the cell's file (`traffic` there):
-  input_form  chunks_host      host chunks -> ChunkSource(encode_mode="host")
-              encoded_columns  pre-encoded integer columns (EncodedData)
-  chunk_rows  rows per host chunk (chunks_host)
-and the configuration's `guarantees` give the engine's parameters,
-`metrics` among them. A new cell of these forms is a new file, not new
-code; a new form or entry point comes with the cell that runs it.
+What a job IS comes from a file found by name: the cell's
+`traffic.input_form` names perfbench/forms/<input_form>.py, whose
+
+    build_job(cell, config, columns) -> job(noise_seed) -> the release,
+        a dict {partition key: tuple of released values}
+
+builds the job from the cell's `traffic` parameters and the
+configuration's `guarantees`. A form builds the job; it never times it.
+What stays here, out of a form's hands: the closed loop, the window's start
+and end, each job's noise seed, the `pb:job` annotation around the whole
+job, the failed-job rules and the count of programs built
+(run.ProgramsBuilt).
 
 Every job gets a noise seed of its own, derived from --seed and the job's
 index, so the window's releases are independent draws over the same rows.
-Each job and each call it makes is wrapped in a `jax.profiler.
-TraceAnnotation` (`pb:job`, `pb:source`, `pb:aggregate`, `pb:budgets`,
-`pb:materialise`): free when no trace is being taken, and what the traced
-run attributes the device's idle gaps to.
+Each job, and (in `engine_job`) each call it makes, is wrapped in a
+`jax.profiler.TraceAnnotation` (`pb:job`, `pb:source`, `pb:aggregate`,
+`pb:budgets`, `pb:materialise`): free when no trace is being taken, and
+what the traced run attributes the device's idle gaps to.
 """
 
 import math
 import time
 
-INPUT_FORMS = ("chunks_host", "encoded_columns")
+import perfbench
 
 
 def noise_seed(seed, index):
@@ -40,19 +45,34 @@ def chunked(columns, chunk_rows):
 
 
 def build_job(cell, config, columns):
-    """job(noise_seed) -> the release, a dict {partition key: one value
-    per metric of the configuration's `metrics`, in that order}."""
+    """The cell's job, built by the form its `traffic.input_form` names,
+    inside the `pb:job` annotation the traced run's window is made of."""
+    import jax
+
+    form = perfbench.find("forms", cell["traffic"]["input_form"])
+    inner = form.build_job(cell, config, columns)
+
+    def job(seed):
+        with jax.profiler.TraceAnnotation("pb:job"):
+            return inner(seed)
+
+    return job
+
+
+def engine_job(g, source, backend=None):
+    """A helper for forms whose job is ONE `DPEngine.aggregate` over rows of
+    (privacy id, partition key, value) under guarantees of the law
+    `bounded_laplace_geometric`: job(noise_seed) -> {partition key: one
+    value per metric of g["metrics"], in that order}.
+
+    `source()` gives the job's input anew for every job; `backend(seed)`
+    the backend it runs on (default: `TPUBackend(noise_seed=seed,
+    numeric_mode=g["numeric_mode"])`, one chip)."""
     import jax
     import pipelinedp_tpu as pdp
-    from pipelinedp_tpu import columnar
 
-    traffic, g = cell["traffic"], config["guarantees"]
-    form = traffic["input_form"]
-    if form not in INPUT_FORMS:
-        raise ValueError(f"cell {cell['name']}: input form {form!r} not in "
-                         f"{INPUT_FORMS}")
     if g["noise"] != "laplace" or g["selection"] != "truncated_geometric":
-        raise ValueError("guarantees: this driver knows Laplace noise and "
+        raise ValueError("engine_job knows Laplace noise and "
                          "truncated-geometric selection")
     metrics = {"count": pdp.Metrics.COUNT, "sum": pdp.Metrics.SUM,
                "privacy_id_count": pdp.Metrics.PRIVACY_ID_COUNT}
@@ -69,39 +89,24 @@ def build_job(cell, config, columns):
                                     partition_extractor=lambda r: r[1],
                                     value_extractor=lambda r: r[2])
     annotate = jax.profiler.TraceAnnotation
-
-    if form == "encoded_columns":
-        encoded = config["encoded"]  # the id spaces the columns index
-        pid, pk, values = columns
-
-        def source():
-            return columnar.EncodedData(
-                pid=pid, pk=pk, values=values,
-                partition_vocab=range(encoded["partitions"]),
-                n_privacy_ids=encoded["privacy_ids"])
-    else:
-        chunks = chunked(columns, int(traffic["chunk_rows"]))
-
-        def source():
-            return pdp.ChunkSource(chunks, encode_mode="host")
+    if backend is None:
+        def backend(seed):
+            return pdp.TPUBackend(noise_seed=seed,
+                                  numeric_mode=g["numeric_mode"])
 
     def job(seed):
-        with annotate("pb:job"):
-            accountant = pdp.NaiveBudgetAccountant(
-                total_epsilon=g["epsilon"], total_delta=g["delta"])
-            engine = pdp.DPEngine(
-                accountant, pdp.TPUBackend(noise_seed=seed,
-                                           numeric_mode=g["numeric_mode"]))
-            with annotate("pb:source"):
-                rows = source()
-            with annotate("pb:aggregate"):
-                result = engine.aggregate(rows, params, extractors)
-            with annotate("pb:budgets"):
-                accountant.compute_budgets()
-            with annotate("pb:materialise"):
-                return {key: tuple(float(getattr(m, name))
-                                   for name in released)
-                        for key, m in result}
+        accountant = pdp.NaiveBudgetAccountant(
+            total_epsilon=g["epsilon"], total_delta=g["delta"])
+        engine = pdp.DPEngine(accountant, backend(seed))
+        with annotate("pb:source"):
+            rows = source()
+        with annotate("pb:aggregate"):
+            result = engine.aggregate(rows, params, extractors)
+        with annotate("pb:budgets"):
+            accountant.compute_budgets()
+        with annotate("pb:materialise"):
+            return {key: tuple(float(getattr(m, name)) for name in released)
+                    for key, m in result}
 
     return job
 
