@@ -23,6 +23,7 @@ from pipelinedp_tpu.aggregate_params import (
     PrivateContributionBounds,
     SelectPartitionsParams,
     SumParams,
+    ValueColumn,
     VarianceParams,
 )
 from pipelinedp_tpu.budget_accounting import (

@@ -8,6 +8,7 @@ TPU-native framework (parameters here additionally feed static shapes /
 traced scalars of the XLA aggregation kernels).
 """
 
+import dataclasses
 import logging
 import math
 from dataclasses import dataclass, field
@@ -137,8 +138,59 @@ class PrivateContributionBounds:
 
 
 @dataclass
+class ValueColumn:
+    """One scalar value column of a several-columns aggregation
+    (AggregateParams.value_columns): its own clamp and its own metrics.
+
+    name: prefix of the released fields (`<name>_sum`, `<name>_mean`).
+    metrics: a non-empty subset of {SUM, MEAN}.
+    """
+    name: str
+    min_value: float
+    max_value: float
+    metrics: List[Metric]
+
+    def __post_init__(self):
+        if not isinstance(self.name, str) or not self.name.isidentifier():
+            raise ValueError(f"ValueColumn: name must be an identifier, "
+                             f"got {self.name!r}")
+        for bound in (self.min_value, self.max_value):
+            if bound is None or _not_a_proper_number(bound):
+                raise ValueError(f"ValueColumn {self.name}: min_value and "
+                                 f"max_value must be finite numbers")
+        if self.min_value > self.max_value:
+            raise ValueError(f"ValueColumn {self.name}: max_value must be "
+                             f"equal to or greater than min_value")
+        self.metrics = list(self.metrics or [])
+        not_allowed = set(self.metrics) - {Metrics.SUM, Metrics.MEAN}
+        if not self.metrics or not_allowed:
+            raise ValueError(f"ValueColumn {self.name}: metrics must be a "
+                             f"non-empty subset of SUM, MEAN; got "
+                             f"{self.metrics}")
+
+
+@dataclass
 class AggregateParams:
     """Parameters of DPEngine.aggregate().
+
+    Several value columns in ONE pass (`value_columns`): each row's value
+    is then a sequence of d scalars (the value extractor yields d values,
+    or EncodedData.values is [n, d]) and column j has its own
+    [min_value, max_value] and its own subset of {SUM, MEAN}; `metrics`
+    may add COUNT and PRIVACY_ID_COUNT, released once. Semantics: per
+    (privacy id, partition) a uniform sample of at most
+    max_contributions_per_partition rows, per privacy id at most
+    max_partitions_contributed partitions - ONE sample shared by all
+    columns; each sampled value clamped to its column's range; per column
+    the single-column rule of combiners.create_compound_combiner (a column
+    with MEAN is one mean combiner that also yields its SUM as mean x
+    count, a column with SUM alone is a sum combiner), every mechanism one
+    equal share of the budget under the naive accountant (a mean combiner
+    holds two: its count and its normalised sum), sensitivities per column
+    as for one column. COUNT is released by the first MEAN column's count
+    mechanism (as for one column), else by a count combiner of its own.
+    Released fields: `count`, `privacy_id_count`, `<name>_sum`,
+    `<name>_mean`. TPC-H Q1 is the example (README "Several value columns").
 
     Validation rules replicate the reference semantics
     (pipeline_dp/aggregate_params.py:166-365):
@@ -168,15 +220,29 @@ class AggregateParams:
     partition_selection_strategy: PartitionSelectionStrategy = (
         PartitionSelectionStrategy.TRUNCATED_GEOMETRIC)
     pre_threshold: Optional[int] = None
+    value_columns: Optional[Sequence[ValueColumn]] = None
 
     @property
     def metrics_str(self) -> str:
         if self.custom_combiners:
             return (f"custom combiners="
                     f"{[c.metrics_names() for c in self.custom_combiners]}")
+        columns = "".join(
+            f" {c.name}[{c.min_value}, {c.max_value}]="
+            f"{[str(m) for m in c.metrics]}"
+            for c in self.value_columns or ())
         if self.metrics:
-            return f"metrics={[str(m) for m in self.metrics]}"
-        return "metrics=[]"
+            return f"metrics={[str(m) for m in self.metrics]}" + columns
+        return "metrics=[]" + columns
+
+    def column_params(self, column: ValueColumn) -> 'AggregateParams':
+        """The one-column AggregateParams a value column stands for: its
+        clamp and its metrics (plus this job's COUNT / PRIVACY_ID_COUNT),
+        every other parameter this job's."""
+        return dataclasses.replace(
+            self, metrics=list(self.metrics or []) + list(column.metrics),
+            min_value=column.min_value, max_value=column.max_value,
+            value_columns=None)
 
     @property
     def bounds_per_contribution_are_set(self) -> bool:
@@ -198,6 +264,8 @@ class AggregateParams:
         if value_bound and partition_bound:
             raise ValueError(
                 "min_value and min_sum_per_partition can not be both set.")
+        if self.value_columns is not None:
+            self._check_value_columns(value_bound or partition_bound)
 
         if value_bound:
             self._check_range("min_value", "max_value")
@@ -269,6 +337,33 @@ class AggregateParams:
                                    "max_contributions_per_partition")
         if self.pre_threshold is not None:
             _check_is_positive_int(self.pre_threshold, "pre_threshold")
+
+    def _check_value_columns(self, scalar_bound: bool):
+        self.value_columns = tuple(self.value_columns)
+        if not self.value_columns or not all(
+                isinstance(c, ValueColumn) for c in self.value_columns):
+            raise ValueError("AggregateParams: value_columns must be a "
+                             "non-empty sequence of ValueColumn")
+        names = [c.name for c in self.value_columns]
+        if len(set(names)) != len(names):
+            raise ValueError(f"AggregateParams: value_columns names must "
+                             f"differ, got {names}")
+        if scalar_bound:
+            raise ValueError(
+                "AggregateParams: with value_columns every column has its "
+                "own min_value/max_value; min_value, max_value, "
+                "min_sum_per_partition and max_sum_per_partition must be "
+                "None")
+        if self.custom_combiners:
+            raise ValueError("AggregateParams: value_columns can not be "
+                             "used with custom combiners")
+        not_allowed = set(self.metrics or []) - {Metrics.COUNT,
+                                                 Metrics.PRIVACY_ID_COUNT}
+        if not_allowed:
+            raise ValueError(
+                f"AggregateParams: with value_columns, metrics may hold "
+                f"COUNT and PRIVACY_ID_COUNT only (SUM and MEAN belong to a "
+                f"column); got {not_allowed}")
 
     def _check_both_set_or_unset(self, name1: str, name2: str):
         v1, v2 = getattr(self, name1), getattr(self, name2)

@@ -254,6 +254,10 @@ def _to_tune_result(
 
 def _check_tune_args(options: TuneOptions, is_public_partitions: bool):
     tune_metrics = options.aggregate_params.metrics
+    if options.aggregate_params.value_columns:
+        raise NotImplementedError(
+            "parameter tuning (analysis/) models one value column: "
+            "AggregateParams.value_columns is not supported on this route")
     if not tune_metrics:
         # Empty metrics means tuning for select_partitions.
         if is_public_partitions:

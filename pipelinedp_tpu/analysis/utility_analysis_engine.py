@@ -171,6 +171,10 @@ def _check_utility_analysis_params(
     params = options.aggregate_params
     if params.custom_combiners is not None:
         raise NotImplementedError("custom combiners are not supported")
+    if params.value_columns:
+        raise NotImplementedError(
+            "utility analysis (analysis/) models one value column: "
+            "AggregateParams.value_columns is not supported on this route")
     supported = {
         agg.Metrics.COUNT, agg.Metrics.SUM, agg.Metrics.PRIVACY_ID_COUNT
     }

@@ -547,6 +547,63 @@ class VectorSumCombiner(Combiner):
         return self._params.mechanism_spec
 
 
+def column_field_name(label: str, metric: str) -> str:
+    """The released field of a value column's metric: `sum` / `mean` carry
+    the column's name, the per-job counts keep theirs. THE naming rule of
+    AggregateParams.value_columns (the combiners and the columnar plan
+    both read it)."""
+    if metric in ('count', 'privacy_id_count'):
+        return metric
+    return f"{label}_{metric}"
+
+
+class ColumnCombiner(Combiner):
+    """One value column of a several-columns aggregation
+    (AggregateParams.value_columns): the existing sum or mean combiner
+    `inner`, built from the column's own one-column params, fed column
+    `column` of every sampled row's values and releasing `sum` / `mean`
+    as `<label>_sum` / `<label>_mean` (`count` keeps its name). Every
+    child of the compound sees the SAME sampled rows, so one contribution
+    bounding serves all columns."""
+
+    def __init__(self, inner: Combiner, column: int, label: str):
+        self.inner = inner
+        self.column = column
+        self.label = label
+
+    def create_accumulator(self, values):
+        return self.inner.create_accumulator(
+            [row[self.column] for row in values])
+
+    def merge_accumulators(self, accumulator1, accumulator2):
+        return self.inner.merge_accumulators(accumulator1, accumulator2)
+
+    def compute_metrics(self, accumulator) -> dict:
+        return {
+            column_field_name(self.label, metric): value for metric, value in
+            self.inner.compute_metrics(accumulator).items()
+        }
+
+    def metrics_names(self) -> List[str]:
+        return [column_field_name(self.label, m)
+                for m in self.inner.metrics_names()]
+
+    def explain_computation(self) -> ExplainComputationReport:
+        return self.inner.explain_computation()
+
+    def expects_per_partition_sampling(self) -> bool:
+        return self.inner.expects_per_partition_sampling()
+
+
+def unwrap_column(child: Combiner):
+    """(combiner, column, label) of a compound's child: a value column's
+    inner combiner with its column index and label, any other child as it
+    is with column -1 (the one scalar column)."""
+    if isinstance(child, ColumnCombiner):
+        return child.inner, child.column, child.label
+    return child, -1, ''
+
+
 # Cache for namedtuple result types (Beam-style serialization support).
 # Guarded: the service's worker pool builds CompoundCombiners on
 # concurrent threads, and an unlocked get-or-create can install TWO
@@ -675,6 +732,9 @@ def create_compound_combiner(
             return budget_accountant.request_budget(
                 mechanism_type, weight=params.budget_weight)
 
+    if params.value_columns:
+        return CompoundCombiner(_column_combiners(params, request),
+                                return_named_tuple=True)
     if Metrics.VARIANCE in params.metrics:
         budget_variance = request('variance')
         metrics_to_compute = ['variance']
@@ -720,6 +780,38 @@ def create_compound_combiner(
                 percentiles_to_compute))
 
     return CompoundCombiner(combiners, return_named_tuple=True)
+
+
+def _column_combiners(params: aggregate_params.AggregateParams,
+                      request: Callable) -> List[Combiner]:
+    """The children of a several-columns aggregation, by the single-column
+    rule above applied to each column: MEAN -> one mean combiner (also
+    yielding the column's SUM where asked), SUM alone -> a sum combiner.
+    COUNT is released once: by the first mean combiner's count mechanism,
+    else by a count combiner of its own."""
+    combiners = []
+    count_wanted = Metrics.COUNT in params.metrics
+    for column, spec in enumerate(params.value_columns):
+        column_params = params.column_params(spec)
+        if Metrics.MEAN in spec.metrics:
+            metrics_to_compute = ['mean']
+            if count_wanted:
+                metrics_to_compute.append('count')
+                count_wanted = False
+            if Metrics.SUM in spec.metrics:
+                metrics_to_compute.append('sum')
+            inner = MeanCombiner(request(f'{spec.name}_count'),
+                                 request(f'{spec.name}_sum'), column_params,
+                                 metrics_to_compute)
+        else:
+            inner = SumCombiner(request(f'{spec.name}_sum'), column_params)
+        combiners.append(ColumnCombiner(inner, column, spec.name))
+    if count_wanted:
+        combiners.insert(0, CountCombiner(request('count'), params))
+    if Metrics.PRIVACY_ID_COUNT in params.metrics:
+        combiners.append(
+            PrivacyIdCountCombiner(request('privacy_id_count'), params))
+    return combiners
 
 
 def create_compound_combiner_with_custom_combiners(

@@ -84,7 +84,19 @@ class DPEngine:
             runtime.pipeline.ChunkSource of raw column chunks (streamed
             through the device-resident pipeline; extractors are not
             consulted for either).
-          params: metrics to compute and computation parameters.
+          params: metrics to compute and computation parameters. With
+            `params.value_columns` (several scalar value columns, each
+            with its own clamp and its own SUM / MEAN, bounded in ONE
+            pass; see AggregateParams) a row's value is the sequence of
+            its d column values, or EncodedData.values is [n, d]. TPC-H
+            Q1 with the customer as privacy unit: COUNT plus
+            ValueColumn("quantity", 1, 50, [SUM, MEAN]),
+            ValueColumn("extendedprice", 0, 70000, [SUM, MEAN]),
+            ValueColumn("disc_price", 0, 70000, [SUM]),
+            ValueColumn("charge", 0, 70000, [SUM]),
+            ValueColumn("discount", 0, 0.1, [MEAN]) releases its eight
+            aggregates from one contribution bounding (README "Several
+            value columns in one pass").
           data_extractors: how to obtain (privacy_id, partition_key, value)
             from an element.
           public_partitions: optional collection of partition keys that appear

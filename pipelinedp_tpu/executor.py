@@ -75,6 +75,24 @@ class MetricPlanEntry:
     kind: str  # count | privacy_id_count | sum | mean | variance
     outputs: Tuple[str, ...]  # metric names in the child's output order
     n_stds: int  # number of noise stddevs the entry consumes
+    # Several value columns (AggregateParams.value_columns): the column of
+    # values[n, d] a sum / mean entry reads and the label its released
+    # fields carry; -1 = the one scalar column.
+    column: int = -1
+    label: str = ''
+
+    @property
+    def released(self) -> Tuple[str, ...]:
+        """The released field names, in output order."""
+        if self.column < 0:
+            return self.outputs
+        return tuple(dp_combiners.column_field_name(self.label, o)
+                     for o in self.outputs)
+
+    def col(self, name: str) -> str:
+        """The reduce column `name` ('sum' / 'nsum') of this entry's
+        value column."""
+        return name if self.column < 0 else f'{name}{self.column}'
 
 
 @dataclass(frozen=True)
@@ -122,6 +140,15 @@ class KernelConfig:
     # (pipelinedp_tpu/numeric.py).
     numeric_mode: str = "fast"
 
+    @property
+    def value_columns(self) -> int:
+        """Several scalar value columns bounded in one pass
+        (AggregateParams.value_columns): values are (n, value_columns)
+        rows, the traced min_v / max_v / mid are [value_columns] arrays and
+        each column has the one sum / mean plan entry that names it.
+        0 = the one scalar column (or vector mode)."""
+        return sum(entry.column >= 0 for entry in self.plan)
+
 
 SUPPORTED_COLUMNAR_METRICS = (Metrics.COUNT, Metrics.PRIVACY_ID_COUNT,
                               Metrics.SUM, Metrics.MEAN, Metrics.VARIANCE,
@@ -144,17 +171,19 @@ def build_plan(
     """Builds the static metric plan from a CompoundCombiner's children."""
     plan = []
     for child in compound.combiners:
+        child, column, label = dp_combiners.unwrap_column(child)
         if isinstance(child, dp_combiners.CountCombiner):
             plan.append(MetricPlanEntry('count', ('count',), 1))
         elif isinstance(child, dp_combiners.PrivacyIdCountCombiner):
             plan.append(
                 MetricPlanEntry('privacy_id_count', ('privacy_id_count',), 1))
         elif isinstance(child, dp_combiners.SumCombiner):
-            plan.append(MetricPlanEntry('sum', ('sum',), 1))
+            plan.append(MetricPlanEntry('sum', ('sum',), 1, column, label))
         elif isinstance(child, dp_combiners.MeanCombiner):
             names = child.metrics_names()
             outputs = ['mean'] + [m for m in ('count', 'sum') if m in names]
-            plan.append(MetricPlanEntry('mean', tuple(outputs), 2))
+            plan.append(
+                MetricPlanEntry('mean', tuple(outputs), 2, column, label))
         elif isinstance(child, dp_combiners.VarianceCombiner):
             # True output order = VarianceCombiner.compute_metrics insertion
             # order (variance, then count/sum/mean as requested).
@@ -184,6 +213,7 @@ def compute_noise_stds(compound: dp_combiners.CompoundCombiner,
     """
     stds: List[float] = []
     for child in compound.combiners:
+        child, _, _ = dp_combiners.unwrap_column(child)
         if isinstance(
                 child,
             (dp_combiners.CountCombiner, dp_combiners.PrivacyIdCountCombiner,
@@ -220,6 +250,7 @@ def compute_noise_sensitivities(compound: dp_combiners.CompoundCombiner,
     snapping introduces."""
     sens: List[float] = []
     for child in compound.combiners:
+        child, _, _ = dp_combiners.unwrap_column(child)
         if isinstance(
                 child,
             (dp_combiners.CountCombiner, dp_combiners.PrivacyIdCountCombiner,
@@ -337,6 +368,7 @@ def bounded_row_columns(pid: jnp.ndarray, pk: jnp.ndarray,
     key_total, key_linf, key_l0 = jax.random.split(rows_key, 3)
 
     vector = bool(cfg.vector_size)
+    width = cfg.vector_size or cfg.value_columns  # 0 = one scalar column
     # Single source of truth for which reduce columns exist; out-of-band
     # assemblers (parallel/large_p.py) read the same list.
     col_names = reduce_column_names(cfg)
@@ -348,11 +380,10 @@ def bounded_row_columns(pid: jnp.ndarray, pk: jnp.ndarray,
     pid_sent = jnp.where(valid, pid, jnp.iinfo(i32).max).astype(i32)
 
     def value_cols(vals):
-        return [vals[:, d] for d in range(cfg.vector_size)] if vector \
-            else [vals]
+        return [vals[:, d] for d in range(width)] if width else [vals]
 
     def from_cols(cols_):
-        return jnp.stack(cols_, axis=1) if vector else cols_[0]
+        return jnp.stack(cols_, axis=1) if width else cols_[0]
 
     if cfg.bounds_enforced:
         # No privacy ids: every row is its own contribution group; no
@@ -405,7 +436,22 @@ def bounded_row_columns(pid: jnp.ndarray, pk: jnp.ndarray,
         qrows = (spk, leaf, keep_row)
 
     # --- Contribution columns (Linf value/pair-sum clipping regimes). ---
-    if vector:
+    if cfg.value_columns:
+        # Several scalar columns, ONE sample: each clamped to its own range
+        # (min_v / max_v / mid are [value_columns] arrays).
+        reduce_cols = {}
+        for entry in cfg.plan:
+            if entry.column < 0:
+                continue
+            j = entry.column
+            clipped = jnp.clip(sval[:, j], min_v[j], max_v[j])
+            if entry.kind == 'sum':
+                reduce_cols[entry.col('sum')] = jnp.where(
+                    keep_row, clipped, 0.0)
+            else:
+                reduce_cols[entry.col('nsum')] = jnp.where(
+                    keep_row, clipped - mid[j], 0.0)
+    elif vector:
         vcontrib = jnp.where(keep_row[:, None], sval, 0.0)
         reduce_cols = {'v%d' % d: vcontrib[:, d]
                        for d in range(cfg.vector_size)}
@@ -442,6 +488,9 @@ def reduce_column_names(cfg: KernelConfig) -> List[str]:
     on empty inputs) build them from here, not from observed outputs."""
     if cfg.vector_size:
         return ['v%d' % d for d in range(cfg.vector_size)]
+    if cfg.value_columns:
+        return [e.col('sum' if e.kind == 'sum' else 'nsum')
+                for e in cfg.plan if e.column >= 0]
     names = []
     if any(e.kind == 'sum' for e in cfg.plan):
         names.append('sum')
@@ -603,17 +652,20 @@ def finalize(cols, min_v, mid, stds: jnp.ndarray, final_key: jax.Array,
             outputs['privacy_id_count'] = noised(cols['pid_count'],
                                                  std_offset, 0)
         elif entry.kind == 'sum':
-            outputs['sum'] = noised(cols['sum'], std_offset, 0)
+            outputs[entry.released[0]] = noised(cols[entry.col('sum')],
+                                                std_offset, 0)
         elif entry.kind == 'mean':
             dp_count = noised(cols['count'], std_offset, 0)
-            dp_nsum = noised(cols['nsum'], std_offset + 1, 1)
+            dp_nsum = noised(cols[entry.col('nsum')], std_offset + 1, 1)
             denom = jnp.maximum(1.0, dp_count)
-            dp_mean = mid + dp_nsum / denom
-            outputs['mean'] = dp_mean
+            dp_mean = (mid if entry.column < 0 else
+                       mid[entry.column]) + dp_nsum / denom
+            named = dict(zip(entry.outputs, entry.released))
+            outputs[named['mean']] = dp_mean
             if 'count' in entry.outputs:
                 outputs['count'] = dp_count
             if 'sum' in entry.outputs:
-                outputs['sum'] = dp_mean * dp_count
+                outputs[named['sum']] = dp_mean * dp_count
         elif entry.kind == 'vector_sum':
             clipped_vsum = _clip_rows_to_norm_ball(cols['vsum'],
                                                    cfg.vector_max_norm,
@@ -1600,7 +1652,15 @@ def make_kernel_config(
 
 
 def kernel_scalars(params: AggregateParams):
-    """Traced clipping scalars (0.0 placeholders when unused)."""
+    """Traced clipping scalars (0.0 placeholders when unused); with
+    several value columns min_v, max_v and mid are [d] arrays."""
+    if params.value_columns:
+        min_v = np.asarray([c.min_value for c in params.value_columns],
+                           dtype=np.float64)
+        max_v = np.asarray([c.max_value for c in params.value_columns],
+                           dtype=np.float64)
+        mid = dp_computations.compute_middle(min_v, max_v)
+        return min_v, max_v, 0.0, 0.0, mid
     min_v = params.min_value if params.min_value is not None else 0.0
     max_v = params.max_value if params.max_value is not None else 0.0
     min_s = (params.min_sum_per_partition
@@ -1610,6 +1670,11 @@ def kernel_scalars(params: AggregateParams):
     mid = (dp_computations.compute_middle(min_v, max_v)
            if params.min_value is not None else 0.0)
     return min_v, max_v, min_s, max_s, mid
+
+
+def _nbytes(*arrays) -> int:
+    """Bytes of the arrays as they cross the link."""
+    return sum(int(a.nbytes) for a in arrays)
 
 
 def _round_up_pow2(n: int) -> int:
@@ -1644,6 +1709,12 @@ def pad_rows(encoded: columnar.EncodedData):
         return (encoded.pid, encoded.pk, encoded.values,
                 encoded.valid)
     pad = n_pad - n
+    with rt_trace.span("dense.pad", rows=n, padded=n_pad):
+        return _padded_copies(encoded, pad)
+
+
+def _padded_copies(encoded: columnar.EncodedData, pad: int):
+    """pad_rows' copy of every column, `pad` invalid rows longer."""
     if isinstance(encoded.pid, jax.Array):
         pid = jnp.concatenate([encoded.pid, jnp.zeros(pad, jnp.int32)])
         pk = jnp.concatenate([encoded.pk, jnp.full(pad, -1, jnp.int32)])
@@ -1729,6 +1800,13 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
             got = encoded.values.shape[1:]
             if got != expected:
                 raise TypeError(f"Shape mismatch: {got} != {expected}")
+        n_columns = len(params.value_columns or ())
+        if n_columns and encoded.values.shape[1:] != (n_columns,):
+            raise TypeError(
+                f"value_columns names {n_columns} columns; a row's values "
+                f"have shape {encoded.values.shape[1:]}")
+        root.set(value_columns=n_columns or 1)
+        rt_telemetry.record("value_columns", n_columns or 1)
         selection_params = None
         if private:
             selection_params = selection_ops.selection_params_from_host(
@@ -1806,7 +1884,10 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                 "fused aggregation execution"), rt_aot.activate(aot_flag):
             batched = None
             interceptor = _active_launch_interceptor()
-            if _offerable(interceptor, fused, pid, backend):
+            # Several value columns carry array scalars, which a batch's
+            # launch fingerprint cannot hold: such a job runs solo.
+            if _offerable(interceptor, fused, pid, backend) and \
+                    not cfg.value_columns:
                 batched = interceptor(ReleaseLaunch(
                     kind="aggregate", mesh=backend.mesh,
                     reshard=getattr(backend, "reshard", "auto"),
@@ -1826,14 +1907,24 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                     **_dense_runtime_kwargs(backend,
                                             "sharded_aggregate_arrays"))
             else:
+                columns = (pid, pk, values, valid)
+                if isinstance(pid, np.ndarray):
+                    # Host columns (a pre-encoded EncodedData, or rows
+                    # encoded here): narrowed to the device dtypes and sent
+                    # up; the span closes when they ARE up (the kernel
+                    # cannot start before). Device-resident inputs were
+                    # counted where they went up
+                    # (DeviceRowAccumulator._append_now).
+                    with rt_trace.span("dense.upload"):
+                        columns = jax.block_until_ready(
+                            tuple(jnp.asarray(c) for c in columns))
+                        rt_telemetry.record("h2d_bytes", _nbytes(*columns))
                 with rt_trace.span("dispatch"):
                     kernel = (aggregate_release_kernel
                               if fused else aggregate_kernel)
-                    result = kernel(
-                        jnp.asarray(pid), jnp.asarray(pk),
-                        jnp.asarray(values), jnp.asarray(valid), min_v,
-                        max_v, min_s, max_s, mid, jnp.asarray(stds), key,
-                        cfg, secure_tables)
+                    result = kernel(*columns, min_v, max_v, min_s, max_s,
+                                    mid, jnp.asarray(stds), key, cfg,
+                                    secure_tables)
             rt_telemetry.record("release_dispatches")
         with rt_trace.span("post_process"):
             if fused:
@@ -1897,7 +1988,7 @@ def _decode_rows(outputs, row_idx_pairs, partition_vocab: Sequence[Any],
         rt_telemetry.record("release_dispatches")
         rt_telemetry.record("d2h_bytes", d2h)
     field_order: List[str] = [
-        name for entry in build_plan(compound) for name in entry.outputs
+        name for entry in build_plan(compound) for name in entry.released
     ]
     n_real = len(partition_vocab)
     row_idx_pairs = list(row_idx_pairs)

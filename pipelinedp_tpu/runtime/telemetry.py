@@ -126,7 +126,8 @@ REGISTRY: Dict[str, Metric] = {
                  "bytes of row data copied host->device on the release "
                  "path, from nbytes where they cross: the ingest "
                  "accumulator's appends, blocked pass 1's padded rows or "
-                 "chunk inputs and its re-upload of the merged survivors"),
+                 "chunk inputs and its re-upload of the merged survivors, "
+                 "the dense route's upload of host columns (dense.upload)"),
         _counter("d2h_bytes",
                  "bytes copied device->host on the release path, from "
                  "nbytes where they cross: pass 1's survivor fetches, "
@@ -138,6 +139,10 @@ REGISTRY: Dict[str, Metric] = {
                  "(large_p._pass1_row_budget) or the explicit row_chunk, "
                  "so no host sort, no survivor round trip; a call that "
                  "took the host-staged branch does not count"),
+        _counter("value_columns",
+                 "scalar value columns bounded in one pass, added once per "
+                 "materialised aggregation: len(AggregateParams."
+                 "value_columns), 1 for a one-column job"),
         _counter("aot_cache_hits",
                  "warm-path dispatches served by an ahead-of-time "
                  "compiled executable from the process-wide "

@@ -55,6 +55,10 @@ class PeekerEngine:
         col: (partition_key, per_user_aggregated_value, partition_count).
         Returns (partition_key, MetricsTuple).
         """
+        if params.value_columns:
+            raise NotImplementedError(
+                "the sketch route (utility_analysis/) aggregates one value "
+                "column: AggregateParams.value_columns is not supported")
         if len(params.metrics) != 1 or params.metrics[0] not in (
                 agg.Metrics.SUM, agg.Metrics.COUNT):
             raise ValueError("Sketch only supports a single aggregation and "

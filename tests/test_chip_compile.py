@@ -127,7 +127,53 @@ def test_dense_release_compiles(chip, numeric_mode):
     _fits(compiled)
     text = compiled.as_text()
     assert text.count(" sort(") >= 3  # bounding, reduce, compaction
-    assert "f64" not in text
+    # An f64 ARRAY, not the bare letters: a cached trace's stack-frame
+    # names (tests/test_numeric_armor.py's "..._f64_oracle_...") ride along
+    # in the text when xdist hands both files to one worker.
+    assert " f64[" not in text
+
+
+def test_dense_release_compiles_with_five_value_columns(chip):
+    """TPC-H Q1's job (perfbench/configs/q1-fewgroups.json): five value
+    columns with their own clamps through ONE bounding sort and ONE reduce
+    sort, P = 6 public partitions, rows reduced to 2^12. Full size, 2^26
+    rows (SF10's 59,986,052), compiled for the described v5e in this
+    sandbox (PR 30): see PERF.md section 4 for the seconds and the bytes."""
+    import pipelinedp_tpu as pdp
+    from pipelinedp_tpu import combiners
+
+    M = pdp.Metrics
+    params = pdp.AggregateParams(
+        metrics=[M.COUNT], max_partitions_contributed=6,
+        max_contributions_per_partition=16,
+        value_columns=[
+            pdp.ValueColumn("quantity", 1, 50, [M.SUM, M.MEAN]),
+            pdp.ValueColumn("extendedprice", 0, 70000, [M.SUM, M.MEAN]),
+            pdp.ValueColumn("disc_price", 0, 70000, [M.SUM]),
+            pdp.ValueColumn("charge", 0, 70000, [M.SUM]),
+            pdp.ValueColumn("discount", 0, 0.10, [M.MEAN])])
+    accountant = pdp.NaiveBudgetAccountant(total_epsilon=1.0,
+                                           total_delta=0.0)
+    with accountant.scope(weight=1):
+        compound = combiners.create_compound_combiner(params, accountant)
+    accountant.compute_budgets()
+    cfg = executor.make_kernel_config(params, compound, 6, False, None)
+    n_stds = len(executor.compute_noise_stds(compound, params))
+
+    def lower():
+        per_column, scalar = chip((5,), F32), chip((), F32)
+        return executor.aggregate_release_kernel.lower(
+            chip((ROWS,), I32), chip((ROWS,), I32), chip((ROWS, 5), F32),
+            chip((ROWS,), np.bool_), per_column, per_column, scalar, scalar,
+            per_column, chip((n_stds,), F32), chip((2,), U32), cfg)
+
+    compiled = _x32(lower).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert sum("bound_sort" in line for line in sorts) == 1, sorts
+    assert sum("segment_reduce" in line for line in sorts) == 1, sorts
+    assert " f64[" not in text
 
 
 def test_blocked_block_kernel_compiles(chip):

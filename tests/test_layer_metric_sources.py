@@ -4,9 +4,11 @@ A per-layer metric of the benchmark is a data file
 (perfbench/layer_metrics/<metric>.json) that names rt_trace spans
 (reader ``span_ms_per_job``) or a telemetry counter (``counter_per_job``).
 Nothing ties those names to the program but this test: each span a listed
-metric names must be recorded by one of two tiny runs — a dense
+metric names must be recorded by one of three tiny runs — a dense
 ChunkSource job through the engine, a blocked job run once with pass 1
-host-staged and once device-resident — and each counter must be declared
+host-staged and once device-resident, a dense job given a host
+EncodedData of two value columns whose row count is no power of two (the
+dense staging: pad, upload) — and each counter must be declared
 in telemetry.REGISTRY and, where those runs can reach it, counted by
 them. A rename in the program then fails here instead of leaving a null
 in the ledger.
@@ -67,6 +69,32 @@ def _dense_chunk_run():
     assert dict(result)
 
 
+def _dense_encoded_run():
+    """A host EncodedData, 2 value columns, 3,000 rows (bucket 4,096): the
+    dense branch pads it (dense.pad), uploads it (dense.upload, h2d_bytes)
+    and counts its columns (value_columns)."""
+    from pipelinedp_tpu import columnar
+
+    rng = np.random.default_rng(1)
+    n = 3000
+    encoded = columnar.encode_columns(
+        rng.integers(0, 400, n), rng.integers(0, 5, n),
+        rng.uniform(0, 5, (n, 2)), public_partitions=list(range(6)))
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT],
+        max_partitions_contributed=3,
+        max_contributions_per_partition=2,
+        value_columns=[
+            pdp.ValueColumn("a", 0.0, 4.0, [pdp.Metrics.SUM]),
+            pdp.ValueColumn("b", 1.0, 5.0, [pdp.Metrics.MEAN])])
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0, total_delta=0.0)
+    engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=3))
+    result = engine.aggregate(encoded, params, pdp.DataExtractors(),
+                              public_partitions=list(range(6)))
+    acc.compute_budgets()
+    assert len(dict(result)) == 6
+
+
 def _blocked_run(row_chunk):
     """row_chunk below the 3,000 rows: the host-staged pass 1; None: the
     device's own budget, which holds them."""
@@ -116,6 +144,7 @@ def recorded():
     trace.enable()
     try:
         _dense_chunk_run()
+        _dense_encoded_run()
         _blocked_run(row_chunk=1000)
         _blocked_run(row_chunk=None)
         counters = {name for name, n in telemetry.snapshot().items() if n}
