@@ -1672,11 +1672,6 @@ def kernel_scalars(params: AggregateParams):
     return min_v, max_v, min_s, max_s, mid
 
 
-def _nbytes(*arrays) -> int:
-    """Bytes of the arrays as they cross the link."""
-    return sum(int(a.nbytes) for a in arrays)
-
-
 def _round_up_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
@@ -1702,7 +1697,14 @@ def pad_rows(encoded: columnar.EncodedData):
     Device-resident encodings (ingest.stream_encode_columns) pad with jnp
     on device — a host round-trip here would undo the streamed upload.
     Pipelined encodings arrive already padded to exactly this bucket
-    (DeviceRowAccumulator.finalize), so this is a no-op for them."""
+    (DeviceRowAccumulator.finalize), so this is a no-op for them.
+
+    Host columns are copied to the bucket on the host, for the callers
+    that hand padded HOST arrays on: a launch offered to the service's
+    interceptor, the meshed dense route and one-chip select_partitions.
+    The one-chip dense aggregation does not come here with host columns:
+    rt_pipeline.stage_host_rows sends them up as they are and the pad
+    exists on the device only — these are the pad values it writes."""
     n = encoded.n_rows
     n_pad = row_bucket(n)
     if n_pad == n:
@@ -1877,17 +1879,26 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                                                   encoded.partition_vocab,
                                                   compound)
             return
-        pid, pk, values, valid = pad_rows(encoded)
         fused = bool(getattr(backend, "fused_release", True))
         aot_flag = getattr(backend, "aot", None)
+        interceptor = _active_launch_interceptor()
+        # Several value columns carry array scalars, which a batch's
+        # launch fingerprint cannot hold: such a job runs solo.
+        offer = (_offerable(interceptor, fused, encoded.pid, backend)
+                 and not cfg.value_columns)
+        host = isinstance(encoded.pid, np.ndarray)
+        if host and backend.mesh is None and not offer:
+            # Host columns bound for one chip go up as they are and are
+            # padded there (rt_pipeline.stage_host_rows). An offered
+            # launch and the mesh's staging take padded HOST arrays.
+            pid, pk, values, valid = (encoded.pid, encoded.pk,
+                                      encoded.values, None)
+        else:
+            pid, pk, values, valid = pad_rows(encoded)
         with budget_accountant.no_new_mechanisms(
                 "fused aggregation execution"), rt_aot.activate(aot_flag):
             batched = None
-            interceptor = _active_launch_interceptor()
-            # Several value columns carry array scalars, which a batch's
-            # launch fingerprint cannot hold: such a job runs solo.
-            if _offerable(interceptor, fused, pid, backend) and \
-                    not cfg.value_columns:
+            if offer:
                 batched = interceptor(ReleaseLaunch(
                     kind="aggregate", mesh=backend.mesh,
                     reshard=getattr(backend, "reshard", "auto"),
@@ -1908,17 +1919,15 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                                             "sharded_aggregate_arrays"))
             else:
                 columns = (pid, pk, values, valid)
-                if isinstance(pid, np.ndarray):
-                    # Host columns (a pre-encoded EncodedData, or rows
-                    # encoded here): narrowed to the device dtypes and sent
-                    # up; the span closes when they ARE up (the kernel
-                    # cannot start before). Device-resident inputs were
-                    # counted where they went up
+                if host:
+                    # Host columns (a pre-encoded EncodedData, rows
+                    # encoded here, or the padded copies of a launch the
+                    # interceptor declined): staged slab by slab; the
+                    # call returns when they ARE up (the kernel cannot
+                    # start before). Device-resident inputs were counted
+                    # where they went up
                     # (DeviceRowAccumulator._append_now).
-                    with rt_trace.span("dense.upload"):
-                        columns = jax.block_until_ready(
-                            tuple(jnp.asarray(c) for c in columns))
-                        rt_telemetry.record("h2d_bytes", _nbytes(*columns))
+                    columns = rt_pipeline.stage_host_rows(*columns)
                 with rt_trace.span("dispatch"):
                     kernel = (aggregate_release_kernel
                               if fused else aggregate_kernel)

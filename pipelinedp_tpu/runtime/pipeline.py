@@ -26,6 +26,13 @@ device-resident pipeline instead of one serial batch call:
     to ``executor.pad_rows`` over the concatenated rows — pipelined and
     serial execution therefore feed the fused kernel the exact same
     arrays and release the exact same noise.
+  * **Dense staging** (``stage_host_rows``) — a host ``EncodedData``
+    reaches the device in slabs of ``DENSE_SLAB_BYTES`` through two
+    reused host scratch buffers, appended (the accumulator's donated
+    append) into device buffers that were built at the row bucket's
+    length with the pad values: the pad exists on the device only, no
+    host array of a column's length is made in a job, and slab *k+1* is
+    narrowed on the host while slab *k* crosses the link.
   * **ChunkSource** — the engine-level chunked entry: wrap an iterable of
     ``(pid_raw, pk_raw, values)`` column chunks and hand it to
     ``DPEngine.aggregate`` / ``select_partitions`` in place of a row
@@ -54,8 +61,10 @@ performs none (chunks flow host->device only, drains happen in the
 executor at the final barrier).
 """
 
+import contextlib
 import functools
 import logging
+import math
 import queue
 import threading
 from concurrent import futures as _futures
@@ -65,6 +74,7 @@ from pipelinedp_tpu.runtime import faults as rt_faults
 from pipelinedp_tpu.runtime import telemetry as rt_telemetry
 from pipelinedp_tpu.runtime import trace as rt_trace
 from pipelinedp_tpu.runtime import watchdog as rt_watchdog
+from pipelinedp_tpu.runtime.concurrency import guarded_by
 
 # One shared depth for every async pipeline in the package: the blocked
 # drivers keep at most this many block kernels in flight and this many
@@ -84,6 +94,15 @@ PIPELINE_DEPTH = 8
 # reproduces executor.pad_rows either way). 0 disables batching (the
 # per-chunk comparison baseline).
 APPEND_BATCH_ROWS = 1 << 16
+
+# Slab size of the dense route's host -> device staging
+# (stage_host_rows): bytes of one slab in the device dtypes over all four
+# row columns (pid, pk, values, valid), converted to rows from the
+# columns' widths. A job smaller than one slab goes up as one slab. Large
+# enough that a job is a handful of dispatches, small enough that the two
+# host scratch buffers a process keeps and the two slabs in flight on the
+# device stay a small share of either memory.
+DENSE_SLAB_BYTES = 128 << 20
 
 _POLL_S = 0.05
 
@@ -309,14 +328,24 @@ def _donation_supported() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def _append_fn():
-    """Jitted chunk append: writes one bucket-padded chunk into the
-    persistent buffers at a traced row offset. The previous buffers are
-    donated to XLA, so the append updates device memory in place
-    instead of allocating a fresh copy per chunk."""
+    """Jitted chunk append: writes one chunk into the persistent buffers
+    at a traced row offset, column by column, however many columns the
+    caller carries (the accumulator's three bucket-padded ones, the
+    dense staging's four slab columns with ``valid``). The previous
+    buffers are donated to XLA, so the append updates device memory in
+    place instead of allocating a fresh copy per chunk."""
     import jax
 
     def _append_impl(bufs, chunk, offset):
         def upd(buf, part):
+            # A part that came up flat (the dense staging's [m, d]
+            # columns, see stage_host_rows) takes its rows' shape here,
+            # on the device. For d under the chip's 128 lanes the
+            # compiler pads the reshaped rows to 128 lanes on the way
+            # (512 B a slab row of f32, whatever d is: 2.4 GB for a
+            # 128 MiB slab of five f32 columns), as a temporary of this
+            # program and beside nothing but the job's buffers.
+            part = part.reshape((-1,) + buf.shape[1:])
             start = (offset,) + (0,) * (buf.ndim - 1)
             return jax.lax.dynamic_update_slice(buf, part, start)
 
@@ -334,7 +363,10 @@ def _grow_fn(fills: tuple = (0, -1, 0)):
     hash-device route) so the tail is indistinguishable from a fresh
     pad. Not donating: a larger output can never alias the smaller
     input (the chip's compiler reports such donated buffers unusable),
-    and the old buffers are released when the accumulator rebinds."""
+    and the old buffers are released when the accumulator rebinds.
+    Grown from zero-row buffers it builds the dense staging's
+    bucket-length buffers of pad values alone (stage_host_rows, fills
+    (0, -1, 0, False) for the fourth column, ``valid``)."""
     import jax
     import jax.numpy as jnp
 
@@ -376,6 +408,16 @@ class DeviceRowAccumulator:
     power-of-two capacity (``executor.row_bucket``), same pad values —
     so the fused kernel compiled for the serial path is hit, not
     retraced, and pipelined noise is the serial noise.
+
+    Who pads where: a streamed input is padded HERE, chunk by chunk on
+    the host (each chunk to its own bucket) and by the buffers' tail on
+    the device; a host ``EncodedData`` on the one-chip dense route is
+    padded on the device by ``stage_host_rows`` below, which shares this
+    class's append and grow programs and carries ``valid`` as a fourth
+    column; ``executor.pad_rows`` pads on the host only for the callers
+    that hand padded host arrays on (an offered batched launch, the
+    meshed dense route, one-chip select_partitions) and on the device
+    for a device-resident input that is no power of two long.
     """
 
     def __init__(self, donate: Optional[bool] = None,
@@ -556,3 +598,134 @@ class DeviceRowAccumulator:
                 jnp.full((pad,) + vals[0].shape[1:], f2, vals[0].dtype))
         return (jnp.concatenate(pids), jnp.concatenate(pks),
                 jnp.concatenate(vals))
+
+
+# --- Dense staging: a host EncodedData to the device, slab by slab ---------
+
+# executor.pad_rows' pad values for (pid, pk, values, valid).
+_PAD_ROW_FILLS = (0, -1, 0, False)
+
+# Host scratch of the dense staging: (shape, dtype) -> two rotating
+# buffers, kept for the life of the process so that a job maps and
+# first-touches no new host pages. Keyed by shape and dtype alone: what a
+# job leaves in them means nothing to the next one. One job stages at a
+# time (the lock is held from its first slab to its last) — service/ runs
+# jobs on threads, and they share the one link anyway.
+_scratch_lock = threading.Lock()
+_scratch: dict = {}
+_GUARDED_BY = guarded_by("_scratch_lock", "_scratch")
+
+
+def _slab_scratch(shape, dtype):  # staticcheck: disable=lock-discipline — caller holds _scratch_lock
+    import numpy as np
+    key = (shape, np.dtype(dtype).str)
+    pair = _scratch.get(key)
+    if pair is None:
+        pair = _scratch[key] = (np.empty(shape, dtype),
+                                np.empty(shape, dtype))
+    return pair
+
+
+def stage_host_rows(pid, pk, values, valid=None):
+    """Host row columns -> ``(pid, pk, values, valid)`` device buffers of
+    ``executor.row_bucket(n)`` rows: bit-identical to ``jnp.asarray`` of
+    ``executor.pad_rows``' host copies, without making them.
+
+    The device buffers are built at the bucket's length holding the
+    pad_rows pad values (span ``dense.pad``, when n is no power of two);
+    the real rows are written into them slab by slab (span
+    ``dense.upload``) with the accumulator's donated append at a traced
+    offset, so the tail IS the pad and no pad row crosses the link. A
+    column already in its device dtype goes up as views of the caller's
+    array; one that is not (float64 values with x64 off) is narrowed a
+    slab at a time, by numpy's rounding as ``jnp.asarray`` applies it,
+    into two rotating scratch buffers the process keeps. ``valid=None``
+    derives each slab's flags from ``pk`` as ``EncodedData.valid``
+    defines them (pk >= 0), into scratch too.
+
+    Slab k's transfer and append run while the host narrows slab k+1; a
+    scratch buffer is written again only after the append that read it
+    is done. ``dense.upload`` closes when the rows ARE on the device.
+    Every program's shape follows (bucket, slab rows, column widths)
+    alone, the last, shorter slab being one more; nothing made from the
+    rows outlives the call but the returned buffers."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from pipelinedp_tpu import executor
+
+    n = pid.shape[0]
+    cap = executor.row_bucket(n)
+    sources = [pid, pk, values, pk if valid is None else valid]
+    widths = [c.shape[1:] for c in sources]
+    dtypes = [jax.dtypes.canonicalize_dtype(c.dtype) for c in sources]
+
+    def narrow(out, part):
+        np.copyto(out, part, casting="same_kind")
+
+    def flags(out, part):
+        np.greater_equal(part, 0, out=out)
+
+    # How a column's slab is made ready on the host: None sends views of
+    # the caller's array, anything else fills scratch first.
+    fill = [narrow if c.dtype != dt else None
+            for c, dt in zip(sources, dtypes)]
+    if valid is None:
+        dtypes[3], fill[3] = np.dtype(bool), flags
+    row_bytes = sum(dt.itemsize * math.prod(w)
+                    for dt, w in zip(dtypes, widths))
+    slab_rows = max(1, DENSE_SLAB_BYTES // row_bytes)
+    n_slabs = -(-n // slab_rows)
+
+    def host_slab(k, scratch):
+        a = k * slab_rows
+        b = min(n, a + slab_rows)
+        with rt_trace.span("dense.narrow", slab=k, rows=b - a):
+            slab = [c[a:b] for c in sources]
+            for i, pair in enumerate(scratch):
+                if pair is not None:
+                    out = pair[k % 2][:b - a]
+                    fill[i](out, slab[i])
+                    slab[i] = out
+            # An [m, d] column goes up flat and takes its shape in the
+            # append. The chip keeps the long dimension of a narrow
+            # [n, d] array minor, and the transfer transposes a
+            # two-dimensional host array into that layout on the HOST, a
+            # few rows at a time (4.2 M pieces a 60 M-row job of five
+            # columns, each an event while a profiler is on: 2.3 s and
+            # 6 GB of host memory a traced job). A flat array is copied
+            # as it is, and the device lays the rows out instead.
+            slab = [c.reshape(-1) for c in slab]
+        return a, slab
+
+    with _scratch_lock:
+        # Scratch rows stop at the bucket, so that small jobs keep small
+        # scratch and the keys stay few.
+        scratch = [
+            _slab_scratch((min(slab_rows, cap),) + w, dt) if f else None
+            for f, w, dt in zip(fill, widths, dtypes)]
+        empty = tuple(jnp.asarray(np.empty((0,) + w, dt))
+                      for w, dt in zip(widths, dtypes))
+        with (rt_trace.span("dense.pad", rows=n, padded=cap) if cap != n
+              else contextlib.nullcontext()):
+            bufs = _grow_fn(_PAD_ROW_FILLS)(empty, new_cap=cap)
+        with rt_trace.span("dense.upload", rows=n, slabs=n_slabs):
+            nxt = host_slab(0, scratch) if n_slabs else None
+            for k in range(n_slabs):
+                offset, slab = nxt
+                slab = tuple(jnp.asarray(c) for c in slab)
+                if k:
+                    # Append k-1 read the scratch slab k+1 is narrowed
+                    # into; its output is still ours to wait on (append k
+                    # donates it next). Transfer k is already queued.
+                    with rt_trace.span("dense.wait", slab=k - 1):
+                        jax.block_until_ready(bufs)
+                bufs = _append_fn()(bufs, slab, offset)
+                rt_telemetry.record("dense_stage_slabs")
+                rt_telemetry.record("h2d_bytes",
+                                    sum(int(c.nbytes) for c in slab))
+                if k + 1 < n_slabs:
+                    nxt = host_slab(k + 1, scratch)
+            with rt_trace.span("dense.wait", slab=n_slabs - 1):
+                bufs = jax.block_until_ready(bufs)
+    return bufs

@@ -127,7 +127,15 @@ REGISTRY: Dict[str, Metric] = {
                  "path, from nbytes where they cross: the ingest "
                  "accumulator's appends, blocked pass 1's padded rows or "
                  "chunk inputs and its re-upload of the merged survivors, "
-                 "the dense route's upload of host columns (dense.upload)"),
+                 "the dense route's slabs of host columns (dense.upload, "
+                 "pipeline.stage_host_rows: the real rows only, the pad is "
+                 "written on the device)"),
+        _counter("dense_stage_slabs",
+                 "slabs of host row columns the dense route sent up and "
+                 "appended into its bucket-length device buffers "
+                 "(pipeline.stage_host_rows): ceil(rows / slab rows) a "
+                 "job, the slab rows being pipeline.DENSE_SLAB_BYTES over "
+                 "the bytes of a row in the device dtypes"),
         _counter("d2h_bytes",
                  "bytes copied device->host on the release path, from "
                  "nbytes where they cross: pass 1's survivor fetches, "

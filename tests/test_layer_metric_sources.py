@@ -8,7 +8,7 @@ metric names must be recorded by one of three tiny runs — a dense
 ChunkSource job through the engine, a blocked job run once with pass 1
 host-staged and once device-resident, a dense job given a host
 EncodedData of two value columns whose row count is no power of two (the
-dense staging: pad, upload) — and each counter must be declared
+dense staging: pad, upload, its slab count) — and each counter must be declared
 in telemetry.REGISTRY and, where those runs can reach it, counted by
 them. A rename in the program then fails here instead of leaving a null
 in the ledger.
@@ -71,8 +71,9 @@ def _dense_chunk_run():
 
 def _dense_encoded_run():
     """A host EncodedData, 2 value columns, 3,000 rows (bucket 4,096): the
-    dense branch pads it (dense.pad), uploads it (dense.upload, h2d_bytes)
-    and counts its columns (value_columns)."""
+    dense branch stages it (pipeline.stage_host_rows: dense.pad around the
+    bucket-length device buffers, dense.upload around its one slab,
+    h2d_bytes, dense_stage_slabs) and counts its columns (value_columns)."""
     from pipelinedp_tpu import columnar
 
     rng = np.random.default_rng(1)
