@@ -1,5 +1,4 @@
-"""Benchmark and profiling scripts (see README.md in this directory).
-
-Importable as a package so bench.py at the repo root can share the spec
-and data construction in benchmarks._common with the standalone scripts.
+"""Spec, data and compile-cache helpers shared by chip_smoke.py and
+tests/test_chip_compile.py (see README.md in this directory). The
+benchmark itself is perfbench/.
 """

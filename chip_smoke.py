@@ -45,7 +45,7 @@ SIZES = types.SimpleNamespace(
     # Netflix Prize (ROADMAP R1): 100,480,507 ratings, 480,189 users,
     # 17,770 movies, ratings 1-5.
     netflix_rows=100_480_507, users=480_189, movies=17_770,
-    dense_rows=1 << 24,  # one launch bucket (bench.py's per-launch size)
+    dense_rows=1 << 24,  # one launch bucket
     chunk_rows=1 << 20,
     file_rows=(1 << 23) + (1 << 17),  # lands in the dense phase's bucket
     parity_rows=200_000, parity_users=20_000, parity_movies=2_000,
@@ -154,7 +154,7 @@ def group_by(pid, pk, values):
 
 
 def count_sum_params(pdp, l0=4, linf=8, max_value=5.0):
-    """COUNT+SUM, Laplace, l0=4, linf=8: the spec of bench.py's headline
+    """COUNT+SUM, Laplace, l0=4, linf=8: the spec of the seed's headline
     and BASELINE.json configs 1/3."""
     return pdp.AggregateParams(
         metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],

@@ -7,16 +7,19 @@ ReportGenerator, over the generic PipelineBackend op vocabulary.
 
 TPU fast path: when the backend is a TPUBackend (and standard combiners are
 used), aggregate() lowers the whole graph to the fused columnar executor
-(pipelinedp_tpu/executor.py) — one jit-compiled XLA program. Laziness is
+(pipelinedp_tpu/executor.py) — one jit-compiled XLA program
+(executor.aggregate_release_kernel; select_partitions():
+executor.select_partitions_release_kernel). Laziness is
 preserved: the device program runs when the returned collection is first
 iterated, which must happen after BudgetAccountant.compute_budgets() (noise
 scales enter the compiled program as traced inputs).
 
 Routing within the TPU path is owned by the backend's knobs, not this
 module: TPUBackend(mesh=...) sends the program through the meshed kernels
-(parallel/sharded.py, or parallel/large_p.py above
-large_partition_threshold), and TPUBackend(reshard=...) picks how each
-privacy id's rows are co-located on one shard — device-resident
+(parallel/sharded.py's shard_map wrappers of the same traced release
+body, or parallel/large_p.py above large_partition_threshold), and
+TPUBackend(reshard=...) picks how each privacy id's rows are
+co-located on one shard — device-resident
 streamed-ingest columns take the on-device all_to_all reshard
 (parallel/reshard.py) and never revisit the host between ingest and
 dispatch; host rows take the exact load-balanced host permutation.
@@ -238,7 +241,7 @@ class DPEngine:
                                     params: agg_params.SelectPartitionsParams,
                                     data_extractors: DataExtractors):
         """Lowers standalone partition selection to one device program
-        (executor.select_partitions_kernel): sort-based pair dedupe + L0
+        (executor.select_partitions_release_kernel): pair dedupe + L0
         sampling, per-partition privacy-id counts via segment ops, and the
         vectorized selection strategies — the TPU counterpart of the
         reference's shuffle pipeline (dp_engine.py:224-278)."""

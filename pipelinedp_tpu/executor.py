@@ -964,14 +964,36 @@ def quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
 
 def _aggregate_trace(pid, pk, values, valid, min_v, max_v, min_s, max_s,
                      mid, stds, rng_key, cfg: KernelConfig,
-                     secure_tables=None):
-    """Traceable fused-aggregation body shared by aggregate_kernel and
-    the compacting aggregate_release_kernel — ONE copy of the op order
-    and key derivation, so the two entry points cannot release
-    different noise."""
+                     secure_tables=None, psum_axis: Optional[str] = None,
+                     combine=None):
+    """THE dense aggregation body: bound and reduce the rows to
+    per-partition partial columns, select, noise. Every dense entry
+    point — one chip or a shard of a mesh, solo or a lane of a batch,
+    compacted or the reference form — wraps it, so no two of them can
+    release different noise.
+
+    Under shard_map psum_axis names the mesh axis (None on one chip),
+    and a shard differs in three places: the sampling key is folded
+    with the shard's index (one privacy id's rows all live on one
+    shard; the finalize key is NOT folded, so selection and noise run
+    replicated), the partial columns go through `combine(cols, cfg)` —
+    handed in by the mesh wrapper, not imported here — and
+    quantile_outputs psums its histograms over the axis.
+    large_p._block_trace(psum_axis=) is the same pattern.
+
+    Returns (outputs, keep bool[P], row_count)."""
+    meshed = psum_axis is not None
+    if meshed:
+        # Read before the split: the op order the mesh's programs have
+        # always had (their compile-cache key is the program text).
+        shard_idx = jax.lax.axis_index(psum_axis)
     rows_key, final_key = jax.random.split(rng_key, 2)
+    if meshed:
+        rows_key = jax.random.fold_in(rows_key, shard_idx)
     cols, qrows = partial_columns(pid, pk, values, valid, min_v, max_v, min_s,
                                   max_s, mid, rows_key, cfg)
+    if meshed:
+        cols = combine(cols, cfg)
     with jax.named_scope("select_noise"):
         outputs, keep, row_count = finalize(cols, min_v, mid, stds,
                                             final_key, cfg, secure_tables)
@@ -979,16 +1001,9 @@ def _aggregate_trace(pid, pk, values, valid, min_v, max_v, min_s, max_s,
         qkey = jax.random.fold_in(rng_key, 7919)
         outputs.update(
             quantile_outputs(qrows, min_v, max_v, stds, qkey, cfg,
+                             psum_axis=psum_axis,
                              secure_tables=secure_tables))
     return outputs, keep, row_count
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def aggregate_kernel(pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-                     stds, rng_key, cfg: KernelConfig, secure_tables=None):
-    """Single-device fused program: partial_columns + finalize."""
-    return _aggregate_trace(pid, pk, values, valid, min_v, max_v, min_s,
-                            max_s, mid, stds, rng_key, cfg, secure_tables)
 
 
 def compact_release(outputs, keep):
@@ -997,7 +1012,7 @@ def compact_release(outputs, keep):
     ascending id order — exactly np.nonzero(keep) — so the host fetches
     one scalar gate plus O(kept) values instead of the dense bool[P] +
     [P] columns. The blocked block body (parallel/large_p._block_trace)
-    has always compacted this way; this is the dense route catching up.
+    compacts the same way.
 
     Returns (n_kept, ids_sorted int32[P], outputs_sorted)."""
     with jax.named_scope("compact"):
@@ -1006,24 +1021,49 @@ def compact_release(outputs, keep):
         return keep.sum(), order, outputs_sorted
 
 
+def aggregate_release_trace(pid, pk, values, valid, min_v, max_v, min_s,
+                            max_s, mid, stds, rng_key, cfg: KernelConfig,
+                            secure_tables=None,
+                            psum_axis: Optional[str] = None, combine=None):
+    """The traced dense RELEASE: _aggregate_trace, then the kept-first
+    compaction. The served entry points here and in parallel/sharded.py
+    are jit / vmap / shard_map around this one call: a change to the
+    release is made here, once.
+
+    Returns (n_kept, ids_sorted int32[P], outputs_sorted, row_count)."""
+    outputs, keep, row_count = _aggregate_trace(
+        pid, pk, values, valid, min_v, max_v, min_s, max_s, mid, stds,
+        rng_key, cfg, secure_tables, psum_axis, combine)
+    n_kept, order, outputs_sorted = compact_release(outputs, keep)
+    return n_kept, order, outputs_sorted, row_count
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def aggregate_kernel(pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
+                     stds, rng_key, cfg: KernelConfig, secure_tables=None):
+    """Reference form, not served: the body without the compaction —
+    dense [P] columns plus the keep mask, which the tests compare the
+    blocked and meshed routes against. Nothing under pipelinedp_tpu/
+    calls it.
+
+    Returns (outputs, keep bool[P], row_count)."""
+    return _aggregate_trace(pid, pk, values, valid, min_v, max_v, min_s,
+                            max_s, mid, stds, rng_key, cfg, secure_tables)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def aggregate_release_kernel(pid, pk, values, valid, min_v, max_v, min_s,
                              max_s, mid, stds, rng_key, cfg: KernelConfig,
                              secure_tables=None):
-    """The fused RELEASE program of the dense route: the whole
+    """The release program of the dense route on one chip: the whole
     post-encode chain — contribution bounding, per-partition stats, DP
     selection, noise, kept-first compaction — as ONE device program
-    (one launch, no intermediate host syncs; XLA reuses the stage
-    buffers in place inside the program, the donation the unfused
-    chain's separate dispatches could never express). Bit-identical to
-    aggregate_kernel + host-side np.nonzero decoding: the body is
-    _aggregate_trace verbatim, and compact_release orders kept
-    partitions exactly as nonzero would."""
-    outputs, keep, row_count = _aggregate_trace(
-        pid, pk, values, valid, min_v, max_v, min_s, max_s, mid, stds,
-        rng_key, cfg, secure_tables)
-    n_kept, order, outputs_sorted = compact_release(outputs, keep)
-    return n_kept, order, outputs_sorted, row_count
+    (one launch, no intermediate host syncs). Equal to aggregate_kernel
+    + np.nonzero(keep) bit for bit: the same body, and compact_release
+    orders kept partitions exactly as nonzero would."""
+    return aggregate_release_trace(pid, pk, values, valid, min_v, max_v,
+                                   min_s, max_s, mid, stds, rng_key, cfg,
+                                   secure_tables)
 
 
 # Compile/dispatch attribution + AOT executable routing (runtime/aot.py
@@ -1048,18 +1088,17 @@ def batched_aggregate_release_kernel(pid, pk, values, valid, min_v, max_v,
     Row arrays carry a leading job-lane axis ([L, n] / [L, n, V]) and
     rng_keys is the [L, 2] stack of each job's own base key; scalars,
     stds and cfg are shared (lanes coalesce only on an identical launch
-    fingerprint — see service/batching.py). The body is _aggregate_trace
-    + compact_release vmapped over the lane axis, and threefry keys are
-    counter-based and elementwise, so lane l's outputs are bit-identical
-    to aggregate_release_kernel on that lane's arrays and key alone —
-    the megabatching guarantee the batching tier asserts per lane."""
+    fingerprint — see service/batching.py). The body is
+    aggregate_release_trace vmapped over the lane axis, and threefry
+    keys are counter-based and elementwise, so lane l's outputs are
+    bit-identical to aggregate_release_kernel on that lane's arrays and
+    key alone — the megabatching guarantee the batching tier asserts
+    per lane."""
 
     def lane(pid_l, pk_l, values_l, valid_l, key_l):
-        outputs, keep, row_count = _aggregate_trace(
-            pid_l, pk_l, values_l, valid_l, min_v, max_v, min_s, max_s,
-            mid, stds, key_l, cfg, secure_tables)
-        n_kept, order, outputs_sorted = compact_release(outputs, keep)
-        return n_kept, order, outputs_sorted, row_count
+        return aggregate_release_trace(pid_l, pk_l, values_l, valid_l,
+                                       min_v, max_v, min_s, max_s, mid,
+                                       stds, key_l, cfg, secure_tables)
 
     return jax.vmap(lane)(pid, pk, values, valid, rng_keys)
 
@@ -1149,14 +1188,58 @@ select_kept_pair_stream = rt_aot.aot_probe(
     static_argnames=("l0", "n_partitions"))
 
 
+def _select_partitions_trace(pid, pk, valid, rng_key, l0: int,
+                             n_partitions: int,
+                             selection: selection_ops.SelectionParams,
+                             psum_axis: Optional[str] = None):
+    """THE standalone-selection body: count each partition's privacy
+    ids after pair dedupe + L0 sampling, draw the keep decisions. Under
+    shard_map psum_axis names the mesh axis (as in _aggregate_trace):
+    the sampling key is folded with the shard's index, the int32[P]
+    counts are psum'd, and the selection key is NOT folded, so every
+    shard holds the same keep mask.
+
+    Returns keep: bool[n_partitions]."""
+    meshed = psum_axis is not None
+    if meshed:
+        shard_idx = jax.lax.axis_index(psum_axis)  # before the split
+    key_l0, key_sel = jax.random.split(rng_key)
+    if meshed:
+        key_l0 = jax.random.fold_in(key_l0, shard_idx)
+    counts = select_partition_counts(pid, pk, valid, key_l0, l0,
+                                     n_partitions)
+    if meshed:
+        counts = jax.lax.psum(counts, psum_axis)
+    return selection_ops.sample_keep_decisions(key_sel, counts, selection)
+
+
+def select_release_trace(pid, pk, valid, rng_key, l0: int,
+                         n_partitions: int,
+                         selection: selection_ops.SelectionParams,
+                         psum_axis: Optional[str] = None):
+    """The traced selection RELEASE: _select_partitions_trace, then the
+    kept-first compaction in compact_release's order (np.nonzero's), so
+    the host fetches one scalar and O(kept) ids. Every served selection
+    entry point, here and in parallel/sharded.py, is jit / vmap /
+    shard_map around this one call.
+
+    Returns (n_kept, ids_sorted int32[n_partitions])."""
+    keep = _select_partitions_trace(pid, pk, valid, rng_key, l0,
+                                    n_partitions, selection, psum_axis)
+    order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
+    return keep.sum(), order
+
+
 @functools.partial(jax.jit,
                    static_argnames=("l0", "n_partitions", "selection"))
 def select_partitions_kernel(pid, pk, valid, rng_key, l0: int,
                              n_partitions: int,
                              selection: selection_ops.SelectionParams):
-    """Standalone DP partition selection as ONE device program:
-    select_partition_counts + the vectorized selection closed forms
-    (ops/selection_ops.py). Returns keep: bool[n_partitions]."""
+    """Reference form, not served: the selection body without the
+    compaction — the dense keep mask the tests compare the blocked and
+    meshed routes against. Nothing under pipelinedp_tpu/ calls it.
+
+    Returns keep: bool[n_partitions]."""
     return _select_partitions_trace(pid, pk, valid, rng_key, l0,
                                     n_partitions, selection)
 
@@ -1167,24 +1250,12 @@ def select_partitions_release_kernel(pid, pk, valid, rng_key, l0: int,
                                      n_partitions: int,
                                      selection:
                                      selection_ops.SelectionParams):
-    """select_partitions_kernel + fused kept-first compaction: the host
-    fetches one scalar and O(kept) ids instead of the dense bool[P]
-    keep vector (compact_release ordering == np.nonzero(keep)).
+    """Standalone DP partition selection on one chip as ONE device
+    program: select_partition_counts + the vectorized selection closed
+    forms (ops/selection_ops.py) + the kept-first compaction.
     Returns (n_kept, ids_sorted int32[n_partitions])."""
-    keep = _select_partitions_trace(pid, pk, valid, rng_key, l0,
-                                    n_partitions, selection)
-    order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-    return keep.sum(), order
-
-
-def _select_partitions_trace(pid, pk, valid, rng_key, l0, n_partitions,
-                             selection):
-    """Shared traced body of the two standalone-selection entry points
-    (same split, same counting core — one copy of the release math)."""
-    key_l0, key_sel = jax.random.split(rng_key)
-    counts = select_partition_counts(pid, pk, valid, key_l0, l0,
-                                     n_partitions)
-    return selection_ops.sample_keep_decisions(key_sel, counts, selection)
+    return select_release_trace(pid, pk, valid, rng_key, l0, n_partitions,
+                                selection)
 
 
 select_partitions_kernel = rt_aot.aot_probe(
@@ -1206,10 +1277,8 @@ def batched_select_partitions_release_kernel(
     (same vmap/threefry argument as batched_aggregate_release_kernel)."""
 
     def lane(pid_l, pk_l, valid_l, key_l):
-        keep = _select_partitions_trace(pid_l, pk_l, valid_l, key_l, l0,
-                                        n_partitions, selection)
-        order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-        return keep.sum(), order
+        return select_release_trace(pid_l, pk_l, valid_l, key_l, l0,
+                                    n_partitions, selection)
 
     return jax.vmap(lane)(pid, pk, valid, rng_keys)
 
@@ -1231,65 +1300,64 @@ def blocked_job_id(kind: str, static_config, noise_seed) -> str:
     return f"{kind}-{digest}"
 
 
+def _shared_runtime_kwargs(backend) -> dict:
+    """The entries every meshed or blocked driver takes alike from
+    TPUBackend: retry, the watchdog deadline knobs and, on a mesh, the
+    elastic device-loss tolerance (the unsharded drivers already run at
+    the one-device floor)."""
+    kwargs = dict(retry=backend.retry)
+    if backend.timeout_s is not None:
+        kwargs["timeout_s"] = backend.timeout_s
+    if backend.watchdog is not None:
+        kwargs["watchdog"] = backend.watchdog
+    if backend.mesh is not None:
+        if backend.elastic:
+            kwargs["elastic"] = True
+        if backend.elastic_grow:
+            kwargs["elastic_grow"] = True
+        if backend.min_devices != 1:
+            kwargs["min_devices"] = backend.min_devices
+    return kwargs
+
+
 def _blocked_runtime_kwargs(backend, kind: str, static_config) -> dict:
     """The failure-semantics kwargs (retry/journal/job_id, the watchdog
     deadline knobs, plus the block_partitions failure-domain size when
     set) threaded from TPUBackend into the blocked drivers."""
-    journal = getattr(backend, "journal", None)
-    job_id = getattr(backend, "job_id", None)
-    noise_seed = getattr(backend, "noise_seed", None)
-    if journal is not None and noise_seed is None:
+    journal = backend.journal
+    job_id = backend.job_id
+    if journal is not None and backend.noise_seed is None:
         logging.warning(
             "journaled blocked execution without a fixed noise_seed: a "
             "resumed run derives a fresh base key, so only journaled "
             "blocks keep their original results — set "
             "TPUBackend(noise_seed=...) for a deterministic resume.")
     if journal is not None and job_id is None:
-        job_id = blocked_job_id(kind, static_config, noise_seed)
-    kwargs = dict(retry=getattr(backend, "retry", None),
-                  journal=journal,
-                  job_id=job_id)
+        job_id = blocked_job_id(kind, static_config, backend.noise_seed)
+    kwargs = _shared_runtime_kwargs(backend)
+    kwargs.update(journal=journal, job_id=job_id)
     # Compute/drain overlap (the drainer-thread mode of
     # _dispatch_blocks): opt-in via TPUBackend(overlap_drain=True) —
     # drain deadlines then include dispatch-side compile contention,
     # so the default stays the serial consume loop.
-    if getattr(backend, "overlap_drain", False):
+    if backend.overlap_drain:
         kwargs["overlap"] = True
-    block_partitions = getattr(backend, "block_partitions", None)
-    if block_partitions is not None:
-        kwargs["block_partitions"] = block_partitions
-    timeout_s = getattr(backend, "timeout_s", None)
-    if timeout_s is not None:
-        kwargs["timeout_s"] = timeout_s
-    wd = getattr(backend, "watchdog", None)
-    if wd is not None:
-        kwargs["watchdog"] = wd
-    # Elastic device-loss tolerance only means something on a mesh; the
-    # unsharded drivers already run at the one-device floor.
-    if getattr(backend, "mesh", None) is not None:
-        if getattr(backend, "elastic", False):
-            kwargs["elastic"] = True
-        if getattr(backend, "elastic_grow", False):
-            kwargs["elastic_grow"] = True
-        min_devices = getattr(backend, "min_devices", 1)
-        if min_devices != 1:
-            kwargs["min_devices"] = min_devices
+    if backend.block_partitions is not None:
+        kwargs["block_partitions"] = backend.block_partitions
     # Attribute the job's health record to this backend so
     # TPUBackend.health() can answer for the aggregations it actually
     # ran. Without an explicit/derived job_id the drivers fall back to
     # their own function name as the job key.
-    health_jobs = getattr(backend, "_health_jobs", None)
-    if health_jobs is not None:
-        if job_id is not None:
-            health_jobs.add(job_id)
-        else:
-            meshed = getattr(backend, "mesh", None) is not None
-            health_jobs.add({
-                "aggregate": "aggregate_blocked_sharded"
-                             if meshed else "aggregate_blocked",
-                "select": "select_partitions_blocked_sharded"
-                          if meshed else "select_partitions_blocked",
-            }.get(kind, kind))
+    if job_id is not None:
+        backend._health_jobs.add(job_id)
+    else:
+        meshed = backend.mesh is not None
+        backend._health_jobs.add({
+            "aggregate": "aggregate_blocked_sharded"
+                         if meshed else "aggregate_blocked",
+            "select": "select_partitions_blocked_sharded"
+                      if meshed else "select_partitions_blocked",
+        }.get(kind, kind))
     return kwargs
 
 
@@ -1300,26 +1368,10 @@ def _dense_runtime_kwargs(backend, kind: str) -> dict:
     sharded_select_partitions), which share the blocked drivers' runtime
     entry but have no journal — the whole run is one program, so a
     resume IS a re-run under the same key."""
-    kwargs = dict(retry=getattr(backend, "retry", None))
-    timeout_s = getattr(backend, "timeout_s", None)
-    if timeout_s is not None:
-        kwargs["timeout_s"] = timeout_s
-    wd = getattr(backend, "watchdog", None)
-    if wd is not None:
-        kwargs["watchdog"] = wd
-    job_id = getattr(backend, "job_id", None)
-    if job_id is not None:
-        kwargs["job_id"] = job_id
-    if getattr(backend, "elastic", False):
-        kwargs["elastic"] = True
-    if getattr(backend, "elastic_grow", False):
-        kwargs["elastic_grow"] = True
-    min_devices = getattr(backend, "min_devices", 1)
-    if min_devices != 1:
-        kwargs["min_devices"] = min_devices
-    health_jobs = getattr(backend, "_health_jobs", None)
-    if health_jobs is not None:
-        health_jobs.add(job_id or kind)
+    kwargs = _shared_runtime_kwargs(backend)
+    if backend.job_id is not None:
+        kwargs["job_id"] = backend.job_id
+    backend._health_jobs.add(backend.job_id or kind)
     return kwargs
 
 
@@ -1345,16 +1397,15 @@ def stream_chunk_source(backend, source, public_list=None):
     bucket — bit-identical kernel inputs to the serial encode of the
     same chunks, so pipelined and serial runs release the same noise.
     """
-    wd = getattr(backend, "watchdog", None)
-    timeout_s = getattr(backend, "timeout_s", None)
-    if wd is None and timeout_s is not None:
-        wd = rt_watchdog.Watchdog(timeout_s=timeout_s)
-    threads = getattr(backend, "encode_threads", None)
+    wd = backend.watchdog
+    if wd is None and backend.timeout_s is not None:
+        wd = rt_watchdog.Watchdog(timeout_s=backend.timeout_s)
+    threads = backend.encode_threads
     if threads is None:
         threads = rt_pipeline.default_encode_threads()
-    encode_mode = getattr(source, "encode_mode", None)
+    encode_mode = source.encode_mode
     if encode_mode is None:
-        encode_mode = getattr(backend, "encode_mode", "host")
+        encode_mode = backend.encode_mode
     from pipelinedp_tpu import ingest
     with rt_watchdog.activate(wd):
         return ingest.stream_encode_columns(
@@ -1362,7 +1413,7 @@ def stream_chunk_source(backend, source, public_list=None):
             public_partitions=public_list,
             nonfinite=source.nonfinite,
             encode_threads=threads,
-            pipeline_depth=getattr(backend, "pipeline_depth", None),
+            pipeline_depth=backend.pipeline_depth,
             encode_mode=encode_mode)
 
 
@@ -1433,17 +1484,15 @@ def launch_interceptor(fn):
         _LAUNCH_INTERCEPTOR.fn = prev
 
 
-def _offerable(interceptor, fused: bool, arr, backend) -> bool:
+def _offerable(interceptor, arr, backend) -> bool:
     """A launch can join a batch only when an interceptor is active,
-    the fused release is on, rows are host numpy (streamed/device-
-    resident encodings keep their solo device path), and a meshed
-    backend is not forced onto the collective reshard (the batched
-    meshed dispatcher stages lanes through the host LPT permutation —
-    the same path solo host-numpy staging takes)."""
-    return (interceptor is not None and fused
-            and isinstance(arr, np.ndarray)
-            and (backend.mesh is None
-                 or getattr(backend, "reshard", "auto") != "device"))
+    rows are host numpy (streamed/device-resident encodings keep their
+    solo device path), and a meshed backend is not forced onto the
+    collective reshard (the batched meshed dispatcher stages lanes
+    through the host LPT permutation — the same path solo host-numpy
+    staging takes)."""
+    return (interceptor is not None and isinstance(arr, np.ndarray)
+            and (backend.mesh is None or backend.reshard != "device"))
 
 
 def lazy_select_partitions(backend, col, params, data_extractors,
@@ -1474,8 +1523,8 @@ def lazy_select_partitions(backend, col, params, data_extractors,
             strategy, budget.eps, budget.delta,
             params.max_partitions_contributed, params.pre_threshold)
         n_partitions = resolve_n_partitions(backend, encoded.n_partitions)
-        key = noise_ops.make_noise_key(getattr(backend, "noise_seed", None))
-        threshold = getattr(backend, "large_partition_threshold", None)
+        key = noise_ops.make_noise_key(backend.noise_seed)
+        threshold = backend.large_partition_threshold
         if threshold is not None and n_partitions > threshold:
             # Huge partition spaces: neither the dense count vector nor
             # the bool[P] keep vector is ever materialized — the
@@ -1488,13 +1537,12 @@ def lazy_select_partitions(backend, col, params, data_extractors,
                 (n_partitions, params.max_partitions_contributed, selection))
             with budget_accountant.no_new_mechanisms(
                     "blocked partition selection execution"), \
-                    rt_aot.activate(getattr(backend, "aot", None)):
+                    rt_aot.activate(backend.aot):
                 if backend.mesh is not None:
                     kept_ids = large_p.select_partitions_blocked_sharded(
                         backend.mesh, encoded.pid, encoded.pk, encoded.valid,
                         key, params.max_partitions_contributed, n_partitions,
-                        selection,
-                        reshard=getattr(backend, "reshard", "auto"),
+                        selection, reshard=backend.reshard,
                         **runtime_kwargs)
                 else:
                     kept_ids = large_p.select_partitions_blocked(
@@ -1510,19 +1558,17 @@ def lazy_select_partitions(backend, col, params, data_extractors,
                     # staticcheck: disable=release-taint — sanctioned release: partition keys are decoded ONLY at indices the DP selection kernel kept (noise + threshold); the selection mechanism registered with the ledger is the sanitizer
                     yield vocab[idx]
             return
-        fused = bool(getattr(backend, "fused_release", True))
-        aot_flag = getattr(backend, "aot", None)
         interceptor = _active_launch_interceptor()
         if backend.mesh is not None:
             from pipelinedp_tpu.parallel import sharded
             with budget_accountant.no_new_mechanisms(
                     "sharded partition selection execution"), \
-                    rt_aot.activate(aot_flag):
+                    rt_aot.activate(backend.aot):
                 result = None
-                if _offerable(interceptor, fused, encoded.pid, backend):
+                if _offerable(interceptor, encoded.pid, backend):
                     result = interceptor(ReleaseLaunch(
                         kind="select", mesh=backend.mesh,
-                        reshard=getattr(backend, "reshard", "auto"),
+                        reshard=backend.reshard,
                         pid=encoded.pid, pk=encoded.pk,
                         valid=encoded.valid, key=key,
                         l0=params.max_partitions_contributed,
@@ -1532,8 +1578,7 @@ def lazy_select_partitions(backend, col, params, data_extractors,
                         backend.mesh, encoded.pid, encoded.pk,
                         encoded.valid, key,
                         params.max_partitions_contributed, n_partitions,
-                        selection, fused=fused,
-                        reshard=getattr(backend, "reshard", "auto"),
+                        selection, reshard=backend.reshard,
                         **_dense_runtime_kwargs(
                             backend, "sharded_select_partitions"))
                 rt_telemetry.record("release_dispatches")
@@ -1544,18 +1589,16 @@ def lazy_select_partitions(backend, col, params, data_extractors,
             slim = dataclasses.replace(
                 encoded, values=np.zeros((encoded.n_rows, 0), np.float64))
             pid, pk, _, valid = pad_rows(slim)
-            with rt_trace.span("dispatch"), rt_aot.activate(aot_flag):
+            with rt_trace.span("dispatch"), rt_aot.activate(backend.aot):
                 result = None
-                if _offerable(interceptor, fused, pid, backend):
+                if _offerable(interceptor, pid, backend):
                     result = interceptor(ReleaseLaunch(
                         kind="select", mesh=None, reshard="auto",
                         pid=pid, pk=pk, valid=valid, key=key,
                         l0=params.max_partitions_contributed,
                         n_partitions=n_partitions, selection=selection))
                 if result is None:
-                    kernel = (select_partitions_release_kernel
-                              if fused else select_partitions_kernel)
-                    result = kernel(
+                    result = select_partitions_release_kernel(
                         jnp.asarray(pid), jnp.asarray(pk),
                         jnp.asarray(valid), key,
                         params.max_partitions_contributed, n_partitions,
@@ -1564,19 +1607,15 @@ def lazy_select_partitions(backend, col, params, data_extractors,
         vocab = encoded.partition_vocab
         n_real = len(vocab)
         with rt_trace.span("drain"):
-            if fused:
-                # Fused compaction: one scalar gate, then exactly
-                # O(kept) ids cross the link (same ascending order as
-                # np.nonzero over the dense keep vector).
-                n_kept, order = result
-                k = int(n_kept)
-                ids = order[:k]
-                rt_pipeline.copy_to_host_async(ids)
-                kept_idx = np.asarray(ids)
-                rt_telemetry.record("release_dispatches", 2)
-            else:
-                kept_idx = np.nonzero(np.asarray(result))[0]
-                rt_telemetry.record("release_dispatches")
+            # One scalar gate, then exactly O(kept) ids cross the link
+            # (same ascending order as np.nonzero over the dense keep
+            # vector).
+            n_kept, order = result
+            k = int(n_kept)
+            ids = order[:k]
+            rt_pipeline.copy_to_host_async(ids)
+            kept_idx = np.asarray(ids)
+            rt_telemetry.record("release_dispatches", 2)
         with rt_trace.span("post_process"):
             if hasattr(vocab, "prefetch"):
                 vocab.prefetch(idx for idx in kept_idx if idx < n_real)
@@ -1816,20 +1855,20 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                 selection_budget.delta, params.max_partitions_contributed,
                 params.pre_threshold)
         n_partitions = resolve_n_partitions(backend, encoded.n_partitions)
-        threshold = getattr(backend, "large_partition_threshold", None)
+        threshold = backend.large_partition_threshold
         blocked = threshold is not None and n_partitions > threshold
         root.set(rows=encoded.n_rows, n_partitions=n_partitions,
                  route=("mesh" if backend.mesh is not None else
                         "blocked" if blocked else "dense"))
-        secure = bool(getattr(backend, "secure_noise", False))
-        numeric_mode = str(getattr(backend, "numeric_mode", "fast"))
+        secure = bool(backend.secure_noise)
+        numeric_mode = backend.numeric_mode
         cfg = make_kernel_config(params, compound, n_partitions, private,
                                  selection_params, secure=secure,
                                  numeric_mode=numeric_mode)
         stds = compute_noise_stds(compound, params)
         secure_tables = None
         if secure:
-            snap_bits = getattr(backend, "snap_grid_bits", None)
+            snap_bits = backend.snap_grid_bits
             thr_hi, thr_lo, gran = secure_noise.build_tables(
                 stds, params.noise_kind,
                 sensitivities=compute_noise_sensitivities(compound, params),
@@ -1837,7 +1876,7 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                             else 2.0 ** int(snap_bits)))
             secure_tables = (jnp.asarray(thr_hi), jnp.asarray(thr_lo),
                              jnp.asarray(gran, dtype=_ftype()))
-        key = noise_ops.make_noise_key(getattr(backend, "noise_seed", None))
+        key = noise_ops.make_noise_key(backend.noise_seed)
         min_v, max_v, min_s, max_s, mid = kernel_scalars(params)
         if blocked:
             # Very large partition spaces: never materialize dense [0, P)
@@ -1857,7 +1896,7 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
             # here would double-spend the budget.
             with budget_accountant.no_new_mechanisms(
                     "blocked aggregation execution"), \
-                    rt_aot.activate(getattr(backend, "aot", None)):
+                    rt_aot.activate(backend.aot):
                 if backend.mesh is not None:
                     kept_ids, blocked_outputs = \
                         large_p.aggregate_blocked_sharded(
@@ -1865,8 +1904,7 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                             encoded.values, encoded.valid, min_v, max_v,
                             min_s, max_s, mid, np.asarray(stds), key, cfg,
                             secure_tables=secure_tables,
-                            reshard=getattr(backend, "reshard", "auto"),
-                            **runtime_kwargs)
+                            reshard=backend.reshard, **runtime_kwargs)
                 else:
                     kept_ids, blocked_outputs = large_p.aggregate_blocked(
                         encoded.pid, encoded.pk, encoded.values,
@@ -1879,12 +1917,10 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                                                   encoded.partition_vocab,
                                                   compound)
             return
-        fused = bool(getattr(backend, "fused_release", True))
-        aot_flag = getattr(backend, "aot", None)
         interceptor = _active_launch_interceptor()
         # Several value columns carry array scalars, which a batch's
         # launch fingerprint cannot hold: such a job runs solo.
-        offer = (_offerable(interceptor, fused, encoded.pid, backend)
+        offer = (_offerable(interceptor, encoded.pid, backend)
                  and not cfg.value_columns)
         host = isinstance(encoded.pid, np.ndarray)
         if host and backend.mesh is None and not offer:
@@ -1896,13 +1932,13 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
         else:
             pid, pk, values, valid = pad_rows(encoded)
         with budget_accountant.no_new_mechanisms(
-                "fused aggregation execution"), rt_aot.activate(aot_flag):
+                "fused aggregation execution"), rt_aot.activate(backend.aot):
             batched = None
             if offer:
                 batched = interceptor(ReleaseLaunch(
                     kind="aggregate", mesh=backend.mesh,
-                    reshard=getattr(backend, "reshard", "auto"),
-                    pid=pid, pk=pk, values=values, valid=valid, key=key,
+                    reshard=backend.reshard, pid=pid, pk=pk,
+                    values=values, valid=valid, key=key,
                     scalars=(min_v, max_v, min_s, max_s, mid),
                     stds=np.asarray(stds), cfg=cfg,
                     secure_tables=secure_tables))
@@ -1913,8 +1949,7 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                 result = sharded.sharded_aggregate_arrays(
                     backend.mesh, pid, pk, values, valid, min_v, max_v,
                     min_s, max_s, mid, stds, key, cfg, secure_tables,
-                    fused=fused,
-                    reshard=getattr(backend, "reshard", "auto"),
+                    reshard=backend.reshard,
                     **_dense_runtime_kwargs(backend,
                                             "sharded_aggregate_arrays"))
             else:
@@ -1929,37 +1964,24 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                     # (DeviceRowAccumulator._append_now).
                     columns = rt_pipeline.stage_host_rows(*columns)
                 with rt_trace.span("dispatch"):
-                    kernel = (aggregate_release_kernel
-                              if fused else aggregate_kernel)
-                    result = kernel(*columns, min_v, max_v, min_s, max_s,
-                                    mid, jnp.asarray(stds), key, cfg,
-                                    secure_tables)
+                    result = aggregate_release_kernel(
+                        *columns, min_v, max_v, min_s, max_s, mid,
+                        jnp.asarray(stds), key, cfg, secure_tables)
             rt_telemetry.record("release_dispatches")
         with rt_trace.span("post_process"):
-            if fused:
-                n_kept, order, outputs, _ = result
-                # Fail-closed numeric sentinel: one scalar reduction over
-                # the kept released columns BEFORE any value is decoded.
-                # Its scalar fetch is the first barrier after the launch:
-                # the host's wait for the release kernel is here.
-                with rt_trace.span("release_wait", what="sentinel"):
-                    rt_numeric.check_release(outputs, n_kept=n_kept,
-                                             numeric_mode=numeric_mode,
-                                             context="dense release")
-                # staticcheck: disable=release-taint — sanctioned release: the compacted ids/columns are the fused kernel's DP-selected partitions and its noised outputs, reordered kept-first inside the program
-                yield from decode_release_results(n_kept, order, outputs,
-                                                  encoded.partition_vocab,
-                                                  compound)
-            else:
-                outputs, keep, _ = result
-                with rt_trace.span("release_wait", what="sentinel"):
-                    rt_numeric.check_release(
-                        outputs, keep=keep, numeric_mode=numeric_mode,
-                        context="dense release (unfused)")
-                # staticcheck: disable=release-taint — sanctioned release: decode_results emits only partitions the fused kernel's DP selection kept, and the output columns carry the kernel's noise
-                yield from decode_results(outputs, keep,
-                                          encoded.partition_vocab,
-                                          compound)
+            n_kept, order, outputs, _ = result
+            # Fail-closed numeric sentinel: one scalar reduction over
+            # the kept released columns BEFORE any value is decoded.
+            # Its scalar fetch is the first barrier after the launch:
+            # the host's wait for the release kernel is here.
+            with rt_trace.span("release_wait", what="sentinel"):
+                rt_numeric.check_release(outputs, n_kept=n_kept,
+                                         numeric_mode=numeric_mode,
+                                         context="dense release")
+            # staticcheck: disable=release-taint — sanctioned release: the compacted ids/columns are the fused kernel's DP-selected partitions and its noised outputs, reordered kept-first inside the program
+            yield from decode_release_results(n_kept, order, outputs,
+                                              encoded.partition_vocab,
+                                              compound)
 
     def generator():
         # The root span of one materialised aggregation: its `agg`
@@ -2028,15 +2050,6 @@ def decode_blocked_results(kept_ids, outputs, partition_vocab: Sequence[Any],
                         partition_vocab, compound)
 
 
-def decode_results(outputs, keep, partition_vocab: Sequence[Any],
-                   compound: dp_combiners.CompoundCombiner):
-    """Device arrays -> [(partition_key, MetricsTuple)], matching the generic
-    path's namedtuple field order (per-child compute_metrics dict order)."""
-    kept = np.nonzero(np.asarray(keep))[0]
-    rt_telemetry.record("release_dispatches")
-    return _decode_rows(outputs, zip(kept, kept), partition_vocab, compound)
-
-
 # Partition buckets at or under this row count decode through the
 # whole-column host-slice fast path in decode_release_results; larger
 # releases keep the O(kept) device-side slicing.
@@ -2046,12 +2059,11 @@ _HOST_SLICE_MAX_ROWS = 4096
 def decode_release_results(n_kept, order, outputs,
                            partition_vocab: Sequence[Any],
                            compound: dp_combiners.CompoundCombiner):
-    """Compacted fused-release output (aggregate_release_kernel /
-    sharded fused route) -> results. One scalar sync gates the O(kept)
-    slices; every slice's host copy starts before the single barrier in
-    _decode_rows (the same overlapped-drain discipline as the blocked
-    drivers' staged drains). Emits the exact stream decode_results
-    yields for the unfused (outputs, keep) pair."""
+    """Compacted dense release (aggregate_release_kernel / the meshed
+    route) -> results. One scalar sync gates the O(kept) slices; every
+    slice's host copy starts before the single barrier in _decode_rows
+    (the same overlapped-drain discipline as the blocked drivers'
+    staged drains)."""
     with rt_trace.span("release_wait", what="n_kept"):
         k = int(n_kept)  # the one sync; gates O(kept) transfers
     rt_telemetry.record("release_dispatches")

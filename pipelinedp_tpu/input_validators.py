@@ -527,22 +527,6 @@ def validate_aot(aot, obj_name: str) -> None:
             f".lower().compile() executable cache, runtime/aot.py).")
 
 
-def validate_fused_release(fused_release, obj_name: str) -> None:
-    """Validates the fused-release-kernel switch: a plain bool.
-
-    Raises:
-        ValueError: fused_release is not a bool (a truthy non-bool would
-        silently flip the dense routes between the one-program
-        compacting release and the unfused kernel + host nonzero chain).
-    """
-    if not isinstance(fused_release, bool):
-        raise ValueError(
-            f"{obj_name}: fused_release must be a bool, but "
-            f"{fused_release!r} given (True fuses DP selection, noise "
-            f"and kept-first compaction into one device program with an "
-            f"O(kept) drain; outputs are bit-identical either way).")
-
-
 def validate_overlap_drain(overlap_drain, obj_name: str) -> None:
     """Validates the compute/drain-overlap switch: a plain bool.
 

@@ -90,31 +90,20 @@ def _column_flags(col, gate):
             | jnp.where(jnp.any(sat), jnp.uint32(_FLAG_SAT), z))
 
 
-def _gather_flags(cols, gate):
+@jax.jit
+def _flags_from_kept(cols, n_kept):
+    """Sentinel flags for kept-first compacted columns ([:n_kept] live)."""
+    p = next(iter(cols.values())).shape[0]
+    gate = jnp.arange(p, dtype=jnp.int32) < n_kept.astype(jnp.int32)
     flags = jnp.uint32(0)
     for name in sorted(cols):
         flags = flags | _column_flags(cols[name], gate)
     return flags
 
 
-@jax.jit
-def _flags_from_kept(cols, n_kept):
-    """Sentinel flags for kept-first compacted columns ([:n_kept] live)."""
-    p = next(iter(cols.values())).shape[0]
-    gate = jnp.arange(p, dtype=jnp.int32) < n_kept.astype(jnp.int32)
-    return _gather_flags(cols, gate)
-
-
-@jax.jit
-def _flags_from_mask(cols, keep):
-    """Sentinel flags for dense columns under a bool keep mask."""
-    return _gather_flags(cols, keep.astype(bool))
-
-
 # Compile/dispatch attribution: the sentinel reductions are tiny, but a
 # retrace storm here would still be invisible without the probes.
 _flags_from_kept = rt_trace.probe_jit("_flags_from_kept", _flags_from_kept)
-_flags_from_mask = rt_trace.probe_jit("_flags_from_mask", _flags_from_mask)
 
 
 def release_flag_bits(flags: int):
@@ -129,15 +118,14 @@ def release_flag_bits(flags: int):
     return names
 
 
-def check_release(outputs, *, n_kept=None, keep=None,
-                  numeric_mode: str = "fast",
+def check_release(outputs, *, n_kept, numeric_mode: str = "fast",
                   context: str = "release") -> None:
     """Fail-closed sentinel over released columns; raises typed on trip.
 
-    Exactly one of `n_kept` (kept-first compacted columns, fused/blocked
-    drivers) or `keep` (dense bool mask, unfused driver) selects the
-    gate. The device program reduces every floating column to one uint32
-    flag word; the single scalar fetch here is the only host transfer.
+    The columns are kept-first compacted ([:n_kept] live), as every
+    driver releases them. The device program reduces every floating
+    column to one uint32 flag word; the single scalar fetch here is the
+    only host transfer.
     """
     cols = {
         name: col
@@ -147,12 +135,7 @@ def check_release(outputs, *, n_kept=None, keep=None,
     if not cols:
         return
     cols = {name: jnp.asarray(col) for name, col in cols.items()}
-    if keep is not None:
-        flags = int(_flags_from_mask(cols, jnp.asarray(keep)))
-    elif n_kept is not None:
-        flags = int(_flags_from_kept(cols, jnp.asarray(n_kept)))
-    else:
-        raise ValueError("check_release needs n_kept= or keep=")
+    flags = int(_flags_from_kept(cols, jnp.asarray(n_kept)))
     if not flags:
         return
     overflow = bool(flags & (_FLAG_INF | _FLAG_SAT))

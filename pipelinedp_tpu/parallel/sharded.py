@@ -171,70 +171,24 @@ def _combine_partials(cols, cfg):
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh"))
-def _sharded_kernel(pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-                    stds, rng_key, cfg: executor.KernelConfig, mesh: Mesh,
-                    secure_tables=None):
-
-    def per_shard(pid_s, pk_s, values_s, valid_s, stds_r, key_r, tables_r):
-        shard_idx = jax.lax.axis_index(SHARD_AXIS)
-        rows_key, final_key = jax.random.split(key_r, 2)
-        # Distinct sampling randomness per shard; identical finalize key.
-        shard_rows_key = jax.random.fold_in(rows_key, shard_idx)
-        cols, qrows = executor.partial_columns(pid_s, pk_s, values_s, valid_s,
-                                               min_v, max_v, min_s, max_s,
-                                               mid, shard_rows_key, cfg)
-        cols = _combine_partials(cols, cfg)
-        outputs, keep, row_count = executor.finalize(cols, min_v, mid, stds_r,
-                                                     final_key, cfg, tables_r)
-        if cfg.quantiles:
-            # Chunk histograms are psum'd inside quantile_outputs (tree
-            # merge over the mesh); noise + descent replicated via key_r.
-            qkey = jax.random.fold_in(key_r, 7919)
-            outputs.update(
-                executor.quantile_outputs(qrows, min_v, max_v, stds_r, qkey,
-                                          cfg, psum_axis=SHARD_AXIS,
-                                          secure_tables=tables_r))
-        return outputs, keep, row_count
-
-    fn = shard_map(per_shard,
-                   mesh=mesh,
-                   in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS),
-                             P(SHARD_AXIS), P(), P(), P()),
-                   out_specs=P())
-    return fn(pid, pk, values, valid, stds, rng_key, secure_tables)
-
-
-@partial(jax.jit, static_argnames=("cfg", "mesh"))
 def _sharded_release_kernel(pid, pk, values, valid, min_v, max_v, min_s,
                             max_s, mid, stds, rng_key,
                             cfg: executor.KernelConfig, mesh: Mesh,
                             secure_tables=None):
-    """The fused-release form of _sharded_kernel: the same per-shard
-    body, then kept-first compaction (executor.compact_release) fused
-    into the SAME program — selection/noise/compaction run replicated
-    over already-psum'd columns, so every device holds identical
-    O(kept)-transferable results and the driver fetches one scalar gate
-    instead of the dense bool[P] + [P] columns."""
+    """The dense release over the mesh: executor.aggregate_release_trace
+    as each shard's body. Rows arrive sharded by privacy id; the body
+    folds the shard index into the sampling key, combines the shards'
+    partial columns through _combine_partials (looked up here at trace
+    time and handed to the body), and runs selection, noise and the
+    kept-first compaction replicated over the combined columns — every
+    device holds the identical O(kept)-transferable release and the
+    driver fetches one scalar gate."""
 
     def per_shard(pid_s, pk_s, values_s, valid_s, stds_r, key_r, tables_r):
-        shard_idx = jax.lax.axis_index(SHARD_AXIS)
-        rows_key, final_key = jax.random.split(key_r, 2)
-        shard_rows_key = jax.random.fold_in(rows_key, shard_idx)
-        cols, qrows = executor.partial_columns(pid_s, pk_s, values_s, valid_s,
-                                               min_v, max_v, min_s, max_s,
-                                               mid, shard_rows_key, cfg)
-        cols = _combine_partials(cols, cfg)
-        outputs, keep, row_count = executor.finalize(cols, min_v, mid, stds_r,
-                                                     final_key, cfg, tables_r)
-        if cfg.quantiles:
-            qkey = jax.random.fold_in(key_r, 7919)
-            outputs.update(
-                executor.quantile_outputs(qrows, min_v, max_v, stds_r, qkey,
-                                          cfg, psum_axis=SHARD_AXIS,
-                                          secure_tables=tables_r))
-        n_kept, order, outputs_sorted = executor.compact_release(
-            outputs, keep)
-        return n_kept, order, outputs_sorted, row_count
+        return executor.aggregate_release_trace(
+            pid_s, pk_s, values_s, valid_s, min_v, max_v, min_s, max_s, mid,
+            stds_r, key_r, cfg, tables_r, psum_axis=SHARD_AXIS,
+            combine=_combine_partials)
 
     fn = shard_map(per_shard,
                    mesh=mesh,
@@ -242,40 +196,6 @@ def _sharded_release_kernel(pid, pk, values, valid, min_v, max_v, min_s,
                              P(SHARD_AXIS), P(), P(), P()),
                    out_specs=P())
     return fn(pid, pk, values, valid, stds, rng_key, secure_tables)
-
-
-def _select_per_shard_trace(pid_s, pk_s, valid_s, key_r, l0, n_partitions,
-                            selection):
-    """Shared per-shard selection body of the two meshed entry points."""
-    shard_idx = jax.lax.axis_index(SHARD_AXIS)
-    key_l0, key_sel = jax.random.split(key_r)
-    # Distinct pair-sampling randomness per shard (rows of one privacy
-    # id all live on one shard, so L0 sampling stays shard-local);
-    # identical selection key, so every shard holds the same keep mask.
-    counts = executor.select_partition_counts(
-        pid_s, pk_s, valid_s, jax.random.fold_in(key_l0, shard_idx), l0,
-        n_partitions)
-    counts = jax.lax.psum(counts, SHARD_AXIS)
-    return selection_ops.sample_keep_decisions(key_sel, counts, selection)
-
-
-@partial(jax.jit,
-         static_argnames=("l0", "n_partitions", "selection", "mesh"))
-def _sharded_select_kernel(pid, pk, valid, rng_key, l0: int,
-                           n_partitions: int,
-                           selection: selection_ops.SelectionParams,
-                           mesh: Mesh):
-
-    def per_shard(pid_s, pk_s, valid_s, key_r):
-        return _select_per_shard_trace(pid_s, pk_s, valid_s, key_r, l0,
-                                       n_partitions, selection)
-
-    fn = shard_map(per_shard,
-                   mesh=mesh,
-                   in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS),
-                             P()),
-                   out_specs=P())
-    return fn(pid, pk, valid, rng_key)
 
 
 @partial(jax.jit,
@@ -284,14 +204,14 @@ def _sharded_select_release_kernel(pid, pk, valid, rng_key, l0: int,
                                    n_partitions: int,
                                    selection: selection_ops.SelectionParams,
                                    mesh: Mesh):
-    """_sharded_select_kernel + fused kept-first compaction (replicated;
-    same ordering as np.nonzero over the dense keep vector)."""
+    """Standalone selection over the mesh: executor.select_release_trace
+    as each shard's body (shard-local counts, one psum of int32[P],
+    decisions and compaction replicated)."""
 
     def per_shard(pid_s, pk_s, valid_s, key_r):
-        keep = _select_per_shard_trace(pid_s, pk_s, valid_s, key_r, l0,
-                                       n_partitions, selection)
-        order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-        return keep.sum(), order
+        return executor.select_release_trace(pid_s, pk_s, valid_s, key_r,
+                                             l0, n_partitions, selection,
+                                             psum_axis=SHARD_AXIS)
 
     fn = shard_map(per_shard,
                    mesh=mesh,
@@ -310,35 +230,20 @@ def _sharded_batched_release_kernel(pid, pk, values, valid, min_v, max_v,
     over the mesh. Row arrays carry a leading job-lane axis over the
     per-shard blocked layout ([L, D*cap] / [L, D*cap, V], every lane
     staged by the SAME host LPT permutation its solo run would take) and
-    rng_keys is the [L, 2] stack of the jobs' own base keys. The
-    per-shard body is _sharded_release_kernel's verbatim, vmapped over
-    the lane axis — fold_in(shard_idx), the psum of partial columns and
-    the replicated finalize/compaction all batch elementwise, so lane
-    l's release is bit-identical to its solo meshed run."""
+    rng_keys is the [L, 2] stack of the jobs' own base keys. The same
+    body, vmapped over the lane axis inside each shard — fold_in of the
+    shard index, the combine of partial columns and the replicated
+    finalize/compaction all batch elementwise, so lane l's release is
+    bit-identical to its solo meshed run."""
 
     def per_shard(pid_s, pk_s, values_s, valid_s, stds_r, keys_r,
                   tables_r):
 
         def lane(pid_l, pk_l, values_l, valid_l, key_l):
-            shard_idx = jax.lax.axis_index(SHARD_AXIS)
-            rows_key, final_key = jax.random.split(key_l, 2)
-            shard_rows_key = jax.random.fold_in(rows_key, shard_idx)
-            cols, qrows = executor.partial_columns(
+            return executor.aggregate_release_trace(
                 pid_l, pk_l, values_l, valid_l, min_v, max_v, min_s,
-                max_s, mid, shard_rows_key, cfg)
-            cols = _combine_partials(cols, cfg)
-            outputs, keep, row_count = executor.finalize(
-                cols, min_v, mid, stds_r, final_key, cfg, tables_r)
-            if cfg.quantiles:
-                qkey = jax.random.fold_in(key_l, 7919)
-                outputs.update(
-                    executor.quantile_outputs(qrows, min_v, max_v, stds_r,
-                                              qkey, cfg,
-                                              psum_axis=SHARD_AXIS,
-                                              secure_tables=tables_r))
-            n_kept, order, outputs_sorted = executor.compact_release(
-                outputs, keep)
-            return n_kept, order, outputs_sorted, row_count
+                max_s, mid, stds_r, key_l, cfg, tables_r,
+                psum_axis=SHARD_AXIS, combine=_combine_partials)
 
         return jax.vmap(lane)(pid_s, pk_s, values_s, valid_s, keys_r)
 
@@ -362,10 +267,10 @@ def _sharded_batched_select_release_kernel(
     def per_shard(pid_s, pk_s, valid_s, keys_r):
 
         def lane(pid_l, pk_l, valid_l, key_l):
-            keep = _select_per_shard_trace(pid_l, pk_l, valid_l, key_l,
-                                           l0, n_partitions, selection)
-            order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-            return keep.sum(), order
+            return executor.select_release_trace(pid_l, pk_l, valid_l,
+                                                 key_l, l0, n_partitions,
+                                                 selection,
+                                                 psum_axis=SHARD_AXIS)
 
         return jax.vmap(lane)(pid_s, pk_s, valid_s, keys_r)
 
@@ -379,8 +284,6 @@ def _sharded_batched_select_release_kernel(
 
 # Compile/dispatch attribution + AOT executable routing for the dense
 # meshed entry points (runtime/aot.py wraps runtime/trace.probe_jit).
-_sharded_kernel = rt_aot.aot_probe("sharded_kernel", _sharded_kernel,
-                                   static_argnames=("cfg", "mesh"))
 _sharded_release_kernel = rt_aot.aot_probe(
     "sharded_release_kernel", _sharded_release_kernel,
     static_argnames=("cfg", "mesh"))
@@ -391,9 +294,6 @@ _sharded_batched_select_release_kernel = rt_aot.aot_probe(
     "sharded_batched_select_release_kernel",
     _sharded_batched_select_release_kernel,
     static_argnames=("l0", "n_partitions", "selection", "mesh"))
-_sharded_select_kernel = rt_aot.aot_probe(
-    "sharded_select_kernel", _sharded_select_kernel,
-    static_argnames=("l0", "n_partitions", "selection", "mesh"))
 _sharded_select_release_kernel = rt_aot.aot_probe(
     "sharded_select_release_kernel", _sharded_select_release_kernel,
     static_argnames=("l0", "n_partitions", "selection", "mesh"))
@@ -401,19 +301,17 @@ _sharded_select_release_kernel = rt_aot.aot_probe(
 
 def _fallback_select_partitions(args, kwargs, job):
     """Elastic floor of sharded_select_partitions: the single-device
-    selection kernel on the surviving device. The selection key
+    selection release kernel on the surviving device. The selection key
     (key_sel half of the split) is replicated on the mesh, so the
     single-device decisions are the same release."""
 
     def go(mesh, pid, pk, valid, rng_key, l0, n_partitions, selection,
-           fused=False, reshard="auto", retry=None, job_id=None):
+           reshard="auto", retry=None, job_id=None):
         del mesh, reshard, job_id
         from pipelinedp_tpu.parallel.large_p import _pad_to
         cap = round_capacity(len(pid))
-        kernel = (executor.select_partitions_release_kernel
-                  if fused else executor.select_partitions_kernel)
         return rt_retry.retry_call(
-            lambda: kernel(
+            lambda: executor.select_partitions_release_kernel(
                 jnp.asarray(_pad_to(pid, cap, 0)),
                 jnp.asarray(_pad_to(pk, cap, 0)),
                 jnp.asarray(_pad_to(valid, cap, False)), rng_key, l0,
@@ -425,13 +323,13 @@ def _fallback_select_partitions(args, kwargs, job):
 
 def _fallback_aggregate_arrays(args, kwargs, job):
     """Elastic floor of sharded_aggregate_arrays: the single-device
-    fused kernel (identical output contract; the finalize/noise key is
+    release kernel (identical output contract; the finalize/noise key is
     the replicated half of the same split, so released noise is the
     same release)."""
 
     def go(mesh, pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-           stds, rng_key, cfg, secure_tables=None, fused=False,
-           reshard="auto", retry=None, job_id=None):
+           stds, rng_key, cfg, secure_tables=None, reshard="auto",
+           retry=None, job_id=None):
         del mesh, reshard, job_id
         from pipelinedp_tpu.parallel.large_p import _pad_to
         if isinstance(values, jax.Array):
@@ -439,10 +337,8 @@ def _fallback_aggregate_arrays(args, kwargs, job):
         else:
             values = np.asarray(values, dtype=np.dtype(executor._ftype()))
         cap = round_capacity(len(pid))
-        kernel = (executor.aggregate_release_kernel
-                  if fused else executor.aggregate_kernel)
         return rt_retry.retry_call(
-            lambda: kernel(
+            lambda: executor.aggregate_release_kernel(
                 jnp.asarray(_pad_to(pid, cap, 0)),
                 jnp.asarray(_pad_to(pk, cap, 0)),
                 jnp.asarray(_pad_to(values, cap, 0)),
@@ -459,7 +355,6 @@ def _fallback_aggregate_arrays(args, kwargs, job):
 def sharded_select_partitions(mesh: Mesh, pid, pk, valid, rng_key, l0: int,
                               n_partitions: int,
                               selection: selection_ops.SelectionParams,
-                              fused: bool = False,
                               reshard: str = "auto",
                               retry: rt_retry.RetryPolicy = None,
                               job_id: Optional[str] = None):
@@ -472,13 +367,12 @@ def sharded_select_partitions(mesh: Mesh, pid, pk, valid, rng_key, l0: int,
     Runtime knobs (shared entry, runtime/entry.py): timeout_s=/watchdog=
     deadlines, job_id= health attribution, elastic=/min_devices=
     device-loss tolerance (the one-device floor runs the single-device
-    selection kernel — the selection key is replicated, so decisions
-    are the same release).
+    selection release kernel — the selection key is replicated, so
+    decisions are the same release).
 
-    Returns keep: bool[n_partitions], replicated across the mesh — or,
-    with fused=True, (n_kept, ids_sorted) with kept ids compacted to
-    the front inside the same program (the O(kept) fused-release
-    drain).
+    Returns (n_kept, ids_sorted int32[n_partitions]), replicated across
+    the mesh: kept ids compacted to the front inside the same program,
+    in np.nonzero's order (executor.select_release_trace).
     """
     # Zero-width values column: selection never reads values, and a real
     # column would cost an O(rows) gather/scatter (or exchange) in the
@@ -491,12 +385,10 @@ def sharded_select_partitions(mesh: Mesh, pid, pk, valid, rng_key, l0: int,
                                            valid, reshard)
     # Retried dispatches reuse the identical rng_key: a retry is a replay
     # of the same selection decisions, never a second draw.
-    kernel = (_sharded_select_release_kernel
-              if fused else _sharded_select_kernel)
     with rt_trace.span("dispatch"):
         return _collective_launch(lambda: rt_retry.retry_call(
-            lambda: kernel(pid, pk, valid, rng_key, l0,
-                           n_partitions, selection, mesh),
+            lambda: _sharded_select_release_kernel(
+                pid, pk, valid, rng_key, l0, n_partitions, selection, mesh),
             retry, what="sharded select_partitions dispatch"))
 
 
@@ -505,27 +397,24 @@ def sharded_select_partitions(mesh: Mesh, pid, pk, valid, rng_key, l0: int,
 def sharded_aggregate_arrays(mesh: Mesh, pid, pk, values, valid, min_v, max_v,
                              min_s, max_s, mid, stds, rng_key,
                              cfg: executor.KernelConfig, secure_tables=None,
-                             fused: bool = False,
                              reshard: str = "auto",
                              retry: rt_retry.RetryPolicy = None,
                              job_id: Optional[str] = None):
-    """Shards rows by pid over `mesh` and runs the two-phase fused program.
+    """Shards rows by pid over `mesh` and runs the two-phase release program.
 
     Accepts host numpy arrays or device-resident jax arrays (any length);
     device-resident columns reshard over ICI without touching the host
-    (stage_rows_to_mesh). Returns the same (outputs, keep, row_count)
-    triple as executor.aggregate_kernel, with results replicated across
-    the mesh — or, with fused=True, the compacted
+    (stage_rows_to_mesh). Returns the compacted
     (n_kept, ids_sorted, outputs_sorted, row_count) release of
-    executor.aggregate_release_kernel (kept-first ordering fused into
-    the one program, so the caller fetches a scalar gate + O(kept)
-    columns).
+    executor.aggregate_release_kernel, replicated across the mesh
+    (kept-first ordering inside the one program, so the caller fetches a
+    scalar gate + O(kept) columns).
 
     Runtime knobs (shared entry, runtime/entry.py): timeout_s=/watchdog=
     deadlines, job_id= health attribution, and elastic=/min_devices=
     device-loss tolerance — a device-fatal failure rebuilds a smaller
     mesh from the survivors and re-enters; the one-device floor runs the
-    single-device fused kernel (the finalize/noise key is replicated, so
+    single-device release kernel (the finalize/noise key is replicated, so
     every geometry releases the same noise).
     """
     # Chaos ingest seam (no-op without an active extreme_values fault).
@@ -537,10 +426,9 @@ def sharded_aggregate_arrays(mesh: Mesh, pid, pk, values, valid, min_v, max_v,
         values_dtype=np.dtype(executor._ftype()))
     # Retried dispatches reuse the identical rng_key, so the redrawn noise
     # is bit-identical — a retry replays the same release.
-    kernel = _sharded_release_kernel if fused else _sharded_kernel
     with rt_trace.span("dispatch"):
         return _collective_launch(lambda: rt_retry.retry_call(
-            lambda: kernel(pid, pk, values, valid, min_v, max_v,
-                           min_s, max_s, mid, jnp.asarray(stds),
-                           rng_key, cfg, mesh, secure_tables),
+            lambda: _sharded_release_kernel(
+                pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
+                jnp.asarray(stds), rng_key, cfg, mesh, secure_tables),
             retry, what="sharded aggregation dispatch"))

@@ -305,8 +305,9 @@ class TPUBackend(LocalBackend):
     program doing contribution bounding + per-partition combine + partition
     selection + noise on device. Standalone select_partitions() lowers to
     its own single-program device kernel
-    (executor.select_partitions_kernel): pair dedupe + L0 sampling via one
-    payload-carrying sort, privacy-id counts via segment ops, vectorized
+    (executor.select_partitions_release_kernel): pair dedupe + L0
+    sampling via one payload-carrying sort, privacy-id counts via
+    segment ops, vectorized
     selection — O(rows) memory, no dense per-partition columns.
 
     The generic op vocabulary is inherited from LocalBackend so that
@@ -459,14 +460,6 @@ class TPUBackend(LocalBackend):
             (aot_cache_hits), with zero Python retraces. Results are
             bit-identical; any entry that cannot lower falls back to
             the traced jit path with one warning. Off by default.
-        fused_release: run the dense routes through the fused RELEASE
-            kernels (default True): contribution bounding, group
-            stats, DP selection, noise and kept-first compaction as
-            ONE device program, so the host fetches a scalar gate plus
-            O(kept) columns instead of the dense bool[P] keep vector
-            and [P] outputs. Bit-identical to False (the unfused
-            kernel + host-side np.nonzero decode — kept as the
-            comparison baseline).
         overlap_drain: compute/drain overlap on the blocked drivers
             (opt-in, default False): block b's drain sync, journal
             fsync and staged transfers run on a dedicated drainer
@@ -535,7 +528,6 @@ class TPUBackend(LocalBackend):
                  min_devices: int = 1,
                  trace: bool = False,
                  aot: bool = False,
-                 fused_release: bool = True,
                  overlap_drain: bool = False,
                  pipeline_depth: Optional[int] = None,
                  encode_threads: Optional[int] = None,
@@ -568,7 +560,6 @@ class TPUBackend(LocalBackend):
         input_validators.validate_min_devices(min_devices, "TPUBackend")
         input_validators.validate_trace(trace, "TPUBackend")
         input_validators.validate_aot(aot, "TPUBackend")
-        input_validators.validate_fused_release(fused_release, "TPUBackend")
         input_validators.validate_overlap_drain(overlap_drain, "TPUBackend")
         if pipeline_depth is not None:
             input_validators.validate_pipeline_depth(
@@ -623,7 +614,6 @@ class TPUBackend(LocalBackend):
         self.min_devices = min_devices
         self.trace = trace
         self.aot = aot
-        self.fused_release = fused_release
         self.overlap_drain = overlap_drain
         self.pipeline_depth = pipeline_depth
         self.encode_threads = encode_threads
@@ -697,7 +687,6 @@ class TPUBackend(LocalBackend):
             elastic_grow=self.elastic_grow,
             min_devices=self.min_devices,
             aot=self.aot,
-            fused_release=self.fused_release,
             overlap_drain=self.overlap_drain,
             pipeline_depth=self.pipeline_depth,
             encode_threads=self.encode_threads,

@@ -96,9 +96,6 @@ def runtime_entry(kind: str, fallback: Optional[Callable] = None):
             if "overlap" in kwargs:
                 input_validators.validate_overlap_drain(
                     kwargs["overlap"], kind)
-            if "fused" in kwargs:
-                input_validators.validate_fused_release(
-                    kwargs["fused"], kind)
             input_validators.validate_elastic(elastic, kind)
             input_validators.validate_elastic_grow(elastic_grow, kind)
             input_validators.validate_min_devices(min_devices, kind)

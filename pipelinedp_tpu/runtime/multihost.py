@@ -183,6 +183,19 @@ def _stage_global_rows(mesh, pid, pk, values, valid):
             to_global(valid, False, bool))
 
 
+def _kept_release(n_kept, ids, outputs) -> Dict[str, np.ndarray]:
+    """The kept prefix of a compacted dense release (replicated on the
+    mesh, so every controller reads its local replica), keyed for the
+    cross-topology bit-compare."""
+    from pipelinedp_tpu.parallel.mesh import host_fetch
+    k = int(host_fetch(n_kept))
+    return {
+        "dense_ids": host_fetch(ids)[:k],
+        "dense_count": host_fetch(outputs["count"])[:k],
+        "dense_sum": host_fetch(outputs["sum"])[:k],
+    }
+
+
 def run_pod_workload(mesh, journal_dir: Optional[str] = None,  # staticcheck: disable=key-hygiene — fixed literal harness keys: the bit-identity proof REQUIRES every controller and the reference to derive from the same key; noise stds are zeroed, nothing here is a product release
                      elastic: bool = False) -> Dict[str, np.ndarray]:
     """The four meshed drivers over `mesh`, device-resident inputs,
@@ -206,10 +219,10 @@ def run_pod_workload(mesh, journal_dir: Optional[str] = None,  # staticcheck: di
     runtime_kwargs = dict(elastic=elastic) if elastic else {}
 
     cols = _stage_global_rows(mesh, pid, pk, values, valid)
-    outputs, keep, _ = sharded.sharded_aggregate_arrays(
+    n_kept, ids, outputs, _ = sharded.sharded_aggregate_arrays(
         mesh, *cols, min_v, max_v, min_s, max_s, mid, stds, key, cfg,
         **runtime_kwargs)
-    sel = sharded.sharded_select_partitions(
+    sel_n, sel_ids = sharded.sharded_select_partitions(
         mesh, cols[0], cols[1], cols[3], jax.random.PRNGKey(5), 2,
         P_dense, selection, **runtime_kwargs)
 
@@ -224,10 +237,8 @@ def run_pod_workload(mesh, journal_dir: Optional[str] = None,  # staticcheck: di
         **runtime_kwargs)
 
     return {
-        "dense_count": host_fetch(outputs["count"]),
-        "dense_sum": host_fetch(outputs["sum"]),
-        "dense_keep": host_fetch(keep),
-        "dense_sel": host_fetch(sel),
+        **_kept_release(n_kept, ids, outputs),
+        "dense_sel": host_fetch(sel_ids)[:int(host_fetch(sel_n))],
         "blk_ids": np.asarray(blk_ids),
         "blk_count": np.asarray(blk_out["count"]),
         "blk_sum": np.asarray(blk_out["sum"]),
@@ -579,20 +590,15 @@ def _drill_dense_outputs(mesh) -> Dict[str, np.ndarray]:  # staticcheck: disable
     import jax
 
     from pipelinedp_tpu.parallel import sharded
-    from pipelinedp_tpu.parallel.mesh import host_fetch
 
     P_dense = 48
     cfg, _, stds, (min_v, max_v, min_s, max_s, mid) = _pod_spec(P_dense)
     pid, pk, values, valid = _pod_rows(P_dense)
     cols = _stage_global_rows(mesh, pid, pk, values, valid)
-    outputs, keep, _ = sharded.sharded_aggregate_arrays(
+    n_kept, ids, outputs, _ = sharded.sharded_aggregate_arrays(
         mesh, *cols, min_v, max_v, min_s, max_s, mid, stds,
         jax.random.PRNGKey(3), cfg)
-    return {
-        "dense_count": host_fetch(outputs["count"]),
-        "dense_sum": host_fetch(outputs["sum"]),
-        "dense_keep": host_fetch(keep),
-    }
+    return _kept_release(n_kept, ids, outputs)
 
 
 def reference_drill_outputs() -> Dict[str, np.ndarray]:
@@ -900,7 +906,7 @@ def check_identity_results(results: List[Tuple[dict, dict]],
     assert len(mech) == 1, (
         f"budget-ledger mechanism counts diverged across topologies: "
         f"{mech}")
-    kept = int(np.asarray(reference["dense_keep"]).sum())
+    kept = len(reference["dense_ids"])
     return (f"{POD_PROCESSES} processes x {POD_DEVICES_PER_PROCESS} "
             f"devices == 1 process x "
             f"{POD_PROCESSES * POD_DEVICES_PER_PROCESS} devices "
@@ -1162,19 +1168,20 @@ def check_pod_observability(out_dir: str,
 
 
 # ---------------------------------------------------------------------------
-# Bench receipt
+# Topology report
 # ---------------------------------------------------------------------------
 
 
 def multihost_receipt(mesh=None) -> Dict[str, object]:
-    """The multihost_* bench-receipt keys: process topology, per-process
+    """The multihost_* topology keys (read by tests/test_multihost.py
+    only since bench.py went — ROADMAP D10): process topology, per-process
     ingest overlap (each controller parses/encodes only its shard — the
     overlap factor is the process count on an evenly-sharded stream),
     the cross-host share of the collective-reshard exchange volume
     (geometry fraction x the traced exchange bytes), and
     ``multihost_trace_merged`` — this run's trace pushed through the
     export→aggregate→merge path (the machinery the 2-process dryrun
-    proves end to end; a single-controller bench truthfully reports one
+    proves end to end; a single controller truthfully reports one
     track)."""
     import tempfile
 

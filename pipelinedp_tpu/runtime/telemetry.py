@@ -2,8 +2,9 @@
 
 A flat Counter rather than per-run stats objects: the drivers that
 increment these live several layers below the entry points that want to
-report them (bench.py receipts, the dryrun), and threading a stats dict
-through every signature would couple all of them to the runtime. Counters
+report them (the benchmark's per-layer lines, the dryrun), and
+threading a stats dict through every signature would couple all of them
+to the runtime. Counters
 are monotonically increasing per process; callers that want per-run deltas
 snapshot() before and after.
 
@@ -21,7 +22,7 @@ table is rendered in README "Observability".
 
 Timings (record_duration) aggregate per-phase wall time as
 (count, min, max, sum); the watchdog and the blocked drivers feed them
-so bench receipts can show where a job's wall clock went. Every counter
+so a report can show where a job's wall clock went. Every counter
 increment and duration is also forwarded to the current job's health
 state machine (runtime/health.py) when one is tracked, and durations
 are ADDITIONALLY aggregated under the current job's id — the same
@@ -198,8 +199,7 @@ REGISTRY: Dict[str, Metric] = {
         _counter("service_batch_launches",
                  "megabatched release launches dispatched by the "
                  "service's coalescing tier (one vmapped device program "
-                 "per >= 2-lane batch; the N-jobs-per-launch collapse "
-                 "the bench's dispatch-count receipt measures)"),
+                 "per >= 2-lane batch: N jobs per launch)"),
         _counter("service_jobs_batched",
                  "jobs whose release executed as one lane of a "
                  "megabatched launch (increments by the lane count per "

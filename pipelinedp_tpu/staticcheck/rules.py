@@ -919,14 +919,12 @@ KNOB_VALIDATORS: Dict[str, str] = {
     "coordinator_address": "validate_coordinator_address",
     "metrics_port": "validate_metrics_port",
     "metrics_path": "validate_metrics_path",
-    # Warm-path knobs (PR 14): the AOT executable cache, the fused
-    # release kernels and the compute/drain overlap. The driver-level
-    # `fused`/`overlap` route selectors share the backend validators
-    # (validated in runtime/entry.py's wrapper).
+    # Warm-path knobs (PR 14): the AOT executable cache and the
+    # compute/drain overlap. The driver-level `overlap` route selector
+    # shares the backend validator (validated in runtime/entry.py's
+    # wrapper).
     "aot": "validate_aot",
-    "fused_release": "validate_fused_release",
     "overlap_drain": "validate_overlap_drain",
-    "fused": "validate_fused_release",
     "overlap": "validate_overlap_drain",
     # Multi-tenant service knobs (validated in
     # DPAggregationService.__init__ — the service API boundary).
@@ -1256,8 +1254,6 @@ TAINT_SANITIZERS: Set[Tuple[str, str]] = {
     ("pipelinedp_tpu/parallel/large_p.py", "_sharded_block_kernel"),
     ("pipelinedp_tpu/parallel/large_p.py", "_sharded_selection_block"),
     ("pipelinedp_tpu/parallel/large_p.py", "_sharded_select_compact"),
-    ("pipelinedp_tpu/parallel/sharded.py", "_sharded_kernel"),
-    ("pipelinedp_tpu/parallel/sharded.py", "_sharded_select_kernel"),
     ("pipelinedp_tpu/ops/selection_ops.py", "sample_keep_decisions"),
     ("pipelinedp_tpu/ops/noise.py", "laplace_noise"),
     ("pipelinedp_tpu/ops/noise.py", "gaussian_noise"),

@@ -2,13 +2,13 @@
 
 The contracts under test:
 
-  * **Bit-identity** — the fused release kernels (one program: bounding
-    → stats → selection → noise → kept-first compaction), the
-    compute/drain overlap (drainer-thread consume) and the AOT
-    executable cache are OPTIMIZATIONS: every knob combination releases
-    exactly the bytes the unfused / serial / traced path releases,
-    across the dense, meshed (1/4/8 devices) and blocked routes, with
-    equal budget-ledger mechanism counts.
+  * **Bit-identity** — the compute/drain overlap (drainer-thread
+    consume) and the AOT executable cache are OPTIMIZATIONS: every knob
+    combination releases exactly the bytes the default unmeshed serial
+    traced run releases, across the dense, meshed (1/4/8 devices) and
+    blocked routes, with equal budget-ledger mechanism counts. (That
+    the release kernels equal the dense reference forms + np.nonzero is
+    tests/test_release_body.py's.)
   * **AOT cache keying** — a distinct spec or row bucket is a miss; an
     identical (spec, shape) is a hit; values never enter the key. A
     second identical-spec service job records 0 aot_cache_misses on
@@ -116,39 +116,33 @@ def _run_select(rows, **backend_kwargs):
 
 
 class TestBitIdentity:
-    """Fused/unfused, overlapped/serial and AOT/traced release the same
-    bytes on every route."""
+    """Meshed/unmeshed, overlapped/serial and AOT/traced release the
+    same bytes on every route."""
 
     def test_dense_engine(self):
         rows = _rows()
-        base, n_base = _run_engine(rows, fused_release=False)
+        base, n_base = _run_engine(rows)
         assert base  # a vacuous comparison proves nothing
-        for kwargs in (dict(fused_release=True),
-                       dict(fused_release=True, aot=True),
-                       dict(fused_release=False, aot=True)):
-            got, n = _run_engine(rows, **kwargs)
-            assert got == base, kwargs
-            assert n == n_base
+        got, n = _run_engine(rows, aot=True)
+        assert got == base
+        assert n == n_base
 
     @pytest.mark.parametrize("n_devices", [1, 4, 8])
     def test_meshed_engine(self, n_devices):
-        # Exactly-met bounds: the UNMESHED unfused run is the bitwise
+        # Exactly-met bounds: the UNMESHED traced run is the bitwise
         # baseline for every geometry (computed once, shared across
-        # the mesh params) — the fused meshed release must equal it at
-        # 1, 4 AND 8 devices, which asserts both fused-vs-unfused and
-        # cross-geometry identity in one run per mesh.
+        # the mesh params) — the meshed release must equal it at 1, 4
+        # AND 8 devices: cross-geometry identity in one run per mesh.
         rows = _exact_rows()
-        base, n_base = _cached(
-            "meshed_base", lambda: _run_engine(rows, fused_release=False))
+        base, n_base = _cached("meshed_base", lambda: _run_engine(rows))
         assert base
         mesh = make_mesh(n_devices=n_devices)
         # AOT executes the same executable jit would dispatch; the
         # 8-device point covers the AOT meshed route.
         kwargs = dict(aot=True) if n_devices == 8 else {}
-        fused, n_f = _run_engine(rows, mesh=mesh, fused_release=True,
-                                 **kwargs)
-        assert fused == base
-        assert n_base == n_f
+        meshed, n_m = _run_engine(rows, mesh=mesh, **kwargs)
+        assert meshed == base
+        assert n_base == n_m
 
     @pytest.mark.parametrize("mesh_devices", [None, 4])
     def test_blocked_overlap_vs_serial(self, mesh_devices):
@@ -176,15 +170,12 @@ class TestBitIdentity:
     def test_select_routes(self, n_devices):
         # Exact bounds: L0 sampling drops no pairs, counts are integer
         # psums — selection decisions are geometry-independent, so the
-        # unmeshed unfused run baselines the mesh-8 routes too.
+        # unmeshed traced run baselines the mesh-8 routes too.
         rows = _exact_rows()
         mesh = make_mesh(n_devices=n_devices) if n_devices else None
-        base, _ = _cached(
-            "select_base",
-            lambda: _run_select(rows, fused_release=False))
+        base, _ = _cached("select_base", lambda: _run_select(rows))
         assert base
-        fused, _ = _run_select(rows, mesh=mesh, fused_release=True,
-                               aot=True)
+        dense, _ = _run_select(rows, mesh=mesh, aot=True)
         blocked, _ = _run_select(rows, mesh=mesh,
                                  large_partition_threshold=4,
                                  block_partitions=3,
@@ -197,16 +188,15 @@ class TestBitIdentity:
                                             block_partitions=3,
                                             overlap_drain=False)
             assert blocked_serial == blocked
-        assert fused == base
+        assert dense == base
         assert blocked == base
 
     def test_chunk_source_depths(self):
         """The streamed (batched-append) route at pipeline depths 1/8
-        equals the serial row run — the append batching and the fused
-        release change dispatch counts, never bytes."""
+        equals the serial row run — the append batching changes
+        dispatch counts, never bytes."""
         rows = _rows(n=2500)
-        base, n_base = _run_engine(rows, fused_release=False,
-                                   overlap_drain=False)
+        base, n_base = _run_engine(rows, overlap_drain=False)
 
         def chunks():
             for i in range(0, len(rows), 300):

@@ -28,6 +28,7 @@ from pipelinedp_tpu.runtime import faults
 from pipelinedp_tpu.runtime import health as health_lib
 from pipelinedp_tpu.runtime import retry as retry_lib
 from pipelinedp_tpu.runtime import telemetry
+from tests.test_release_body import kept_release
 
 pytestmark = pytest.mark.faults
 
@@ -170,15 +171,14 @@ class TestBlockKeyGeometryInvariance:
         key = jax.random.PRNGKey(11)
         ref = None
         for d in (1, 2, 4):
-            out, keep, _ = sharded.sharded_aggregate_arrays(
+            kept, out = kept_release(sharded.sharded_aggregate_arrays(
                 make_mesh(n_devices=d), pid, pk, values, valid, min_v,
-                max_v, min_s, max_s, mid, stds, key, cfg)
-            got = (np.asarray(keep), np.asarray(out["count"]),
-                   np.asarray(out["sum"]))
+                max_v, min_s, max_s, mid, stds, key, cfg))
+            got = (kept, out["count"], out["sum"])
             if ref is None:
                 ref = got
                 continue
-            assert np.array_equal(ref[0], got[0]), f"keep differs at D={d}"
+            assert np.array_equal(ref[0], got[0]), f"kept ids differ at D={d}"
             assert np.array_equal(ref[1], got[1]), f"count differs at D={d}"
             assert np.array_equal(ref[2], got[2]), f"sum differs at D={d}"
 
@@ -205,19 +205,20 @@ def _dense_agg_runner(mesh, key, journal=None, **kwargs):
     assert journal is None
     cfg, stds, (min_v, max_v, min_s, max_s, mid), _ = _spec()
     pid, pk, values, valid, _ = _data()
-    out, keep, _ = sharded.sharded_aggregate_arrays(
+    kept, out = kept_release(sharded.sharded_aggregate_arrays(
         mesh, pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-        stds, key, cfg, **kwargs)
-    return np.asarray(keep), np.asarray(out["sum"])
+        stds, key, cfg, **kwargs))
+    return kept, out["sum"]
 
 
 def _dense_select_runner(mesh, key, journal=None, **kwargs):
     assert journal is None
     _, _, _, selection = _spec()
     pid, pk, values, valid, _ = _data()
-    keep = sharded.sharded_select_partitions(mesh, pid, pk, valid, key, L0,
-                                             P, selection, **kwargs)
-    return np.asarray(keep), np.asarray(keep)
+    n_kept, ids = sharded.sharded_select_partitions(
+        mesh, pid, pk, valid, key, L0, P, selection, **kwargs)
+    kept = np.asarray(ids)[:int(n_kept)]
+    return kept, kept
 
 
 # (runner, supports_journal) for each of the four meshed drivers.

@@ -11,6 +11,7 @@ import pytest
 import pipelinedp_tpu as pdp
 from pipelinedp_tpu.parallel import make_mesh
 from pipelinedp_tpu.parallel import reshard
+from tests.test_release_body import kept_release
 
 
 def _data(n=10_000, n_ids=700, n_pk=50, seed=0, invalid_frac=0.1):
@@ -180,10 +181,10 @@ class TestTransferGuard:
         cols = _device(pid, pk, values, valid)
         key = jax.random.PRNGKey(0)
         with reshard.forbid_row_fetches():
-            outputs, keep, _ = sharded.sharded_aggregate_arrays(
+            n_kept, ids, outputs, _ = sharded.sharded_aggregate_arrays(
                 mesh, *cols, min_v, max_v, min_s, max_s, mid, stds, key,
                 cfg)
-        assert np.asarray(keep).shape == (P,)
+        assert np.asarray(ids).shape == (P,)
 
     def test_host_inputs_would_fail_the_guard(self):
         # Sanity that the guard scope is meaningful: forcing the HOST
@@ -211,19 +212,19 @@ class TestMeshedRouteParity:
                                                                eps=1e7)
         pid, pk, values, valid = _data()
         key = jax.random.PRNGKey(0)
-        out_h, keep_h, _ = sharded.sharded_aggregate_arrays(
+        kept_h, out_h = kept_release(sharded.sharded_aggregate_arrays(
             mesh, pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-            stds, key, cfg)
+            stds, key, cfg))
         with reshard.forbid_row_fetches():
-            out_d, keep_d, _ = sharded.sharded_aggregate_arrays(
+            release_d = sharded.sharded_aggregate_arrays(
                 mesh, *_device(pid, pk, values, valid), min_v, max_v,
                 min_s, max_s, mid, stds, key, cfg)
-        assert np.array_equal(np.asarray(keep_h), np.asarray(keep_d))
-        assert np.asarray(keep_h).sum() > 0
-        np.testing.assert_allclose(np.asarray(out_h["count"]),
-                                   np.asarray(out_d["count"]), atol=1e-3)
-        np.testing.assert_allclose(np.asarray(out_h["sum"]),
-                                   np.asarray(out_d["sum"]), rtol=1e-4,
+        kept_d, out_d = kept_release(release_d)
+        assert np.array_equal(kept_h, kept_d)
+        assert len(kept_h) > 0
+        np.testing.assert_allclose(out_h["count"], out_d["count"],
+                                   atol=1e-3)
+        np.testing.assert_allclose(out_h["sum"], out_d["sum"], rtol=1e-4,
                                    atol=1e-3)
 
     def test_reshard_mode_escape_hatches(self):
@@ -234,18 +235,18 @@ class TestMeshedRouteParity:
         cfg, _, stds, (min_v, max_v, min_s, max_s, mid) = _spec(P)
         pid, pk, values, valid = _data()
         key = jax.random.PRNGKey(0)
-        ref, keep_ref, _ = sharded.sharded_aggregate_arrays(
+        kept_ref, _ = kept_release(sharded.sharded_aggregate_arrays(
             mesh, pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-            stds, key, cfg)
+            stds, key, cfg))
         # host mode on device inputs, device mode on host inputs.
-        _, keep_h, _ = sharded.sharded_aggregate_arrays(
+        kept_h, _ = kept_release(sharded.sharded_aggregate_arrays(
             mesh, *_device(pid, pk, values, valid), min_v, max_v, min_s,
-            max_s, mid, stds, key, cfg, reshard="host")
-        _, keep_d, _ = sharded.sharded_aggregate_arrays(
+            max_s, mid, stds, key, cfg, reshard="host"))
+        kept_d, _ = kept_release(sharded.sharded_aggregate_arrays(
             mesh, pid, pk, values, valid, min_v, max_v, min_s, max_s, mid,
-            stds, key, cfg, reshard="device")
-        assert np.array_equal(np.asarray(keep_ref), np.asarray(keep_h))
-        assert np.array_equal(np.asarray(keep_ref), np.asarray(keep_d))
+            stds, key, cfg, reshard="device"))
+        assert np.array_equal(kept_ref, kept_h)
+        assert np.array_equal(kept_ref, kept_d)
 
     def test_sharded_select_partitions(self):
         import jax
@@ -255,15 +256,14 @@ class TestMeshedRouteParity:
         _, selection, _, _ = _spec(P, eps=1e7)
         pid, pk, _, valid = _data()
         key = jax.random.PRNGKey(1)
-        keep_h = np.asarray(
-            sharded.sharded_select_partitions(mesh, pid, pk, valid, key,
-                                              50, P, selection))
+        n_h, ids_h = sharded.sharded_select_partitions(
+            mesh, pid, pk, valid, key, 50, P, selection)
         with reshard.forbid_row_fetches():
-            keep_d = np.asarray(
-                sharded.sharded_select_partitions(
-                    mesh, *_device(pid, pk, valid), key, 50, P, selection))
-        assert np.array_equal(keep_h, keep_d)
-        assert keep_h.sum() > 0
+            n_d, ids_d = sharded.sharded_select_partitions(
+                mesh, *_device(pid, pk, valid), key, 50, P, selection)
+        assert int(n_h) == int(n_d) > 0
+        assert np.array_equal(np.asarray(ids_h)[:int(n_h)],
+                              np.asarray(ids_d)[:int(n_d)])
 
     def test_blocked_aggregate(self):
         import jax
