@@ -1607,14 +1607,12 @@ def lazy_select_partitions(backend, col, params, data_extractors,
         vocab = encoded.partition_vocab
         n_real = len(vocab)
         with rt_trace.span("drain"):
-            # One scalar gate, then exactly O(kept) ids cross the link
-            # (same ascending order as np.nonzero over the dense keep
-            # vector).
+            # One scalar gate, then O(kept) ids cross the link: a
+            # bucket-length prefix, cut to k on the host
+            # (rt_pipeline.KeptPrefix; same ascending order as np.nonzero
+            # over the dense keep vector).
             n_kept, order = result
-            k = int(n_kept)
-            ids = order[:k]
-            rt_pipeline.copy_to_host_async(ids)
-            kept_idx = np.asarray(ids)
+            (kept_idx,) = rt_pipeline.fetch_kept((order,), int(n_kept))
             rt_telemetry.record("release_dispatches", 2)
         with rt_trace.span("post_process"):
             if hasattr(vocab, "prefetch"):
@@ -1999,25 +1997,15 @@ def _decode_rows(outputs, row_idx_pairs, partition_vocab: Sequence[Any],
                  compound: dp_combiners.CompoundCombiner):
     """Shared emit loop: (output row, partition id) pairs -> results.
 
+    `outputs` are HOST columns holding the released rows and nothing
+    else: the blocked drivers' concatenated drains, or a dense release's
+    columns cut to its kept count (decode_release_results).
+
     Field order = concatenated plan-entry outputs, which build_plan stores
     in each child's true compute_metrics insertion order — identical to
     CompoundCombiner.compute_metrics on the generic path.
     """
-    with rt_trace.span("drain"):
-        # Start every output column's device->host copy before the first
-        # blocking materialization: the transfers overlap each other (and
-        # any remaining device execution), and the np.asarray barrier
-        # below then waits once for the batch instead of paying one
-        # serial round trip per column. On the async dense path that one
-        # wait IS the device execution + transfer time.
-        d2h = 0
-        for col in outputs.values():
-            if isinstance(col, jax.Array):
-                rt_pipeline.copy_to_host_async(col)
-                d2h += int(col.nbytes)
-        outputs_np = {name: np.asarray(col) for name, col in outputs.items()}
-        rt_telemetry.record("release_dispatches")
-        rt_telemetry.record("d2h_bytes", d2h)
+    rt_telemetry.record("release_dispatches")  # the drain these rows took
     field_order: List[str] = [
         name for entry in build_plan(compound) for name in entry.released
     ]
@@ -2035,8 +2023,8 @@ def _decode_rows(outputs, row_idx_pairs, partition_vocab: Sequence[Any],
         values = tuple(
             # Vector-valued columns (e.g. vector_sum) decode to ndarrays,
             # scalars to floats — matching the generic combiner outputs.
-            (np.asarray(outputs_np[name][row], dtype=np.float64)
-             if outputs_np[name].ndim > 1 else float(outputs_np[name][row]))
+            (np.asarray(outputs[name][row], dtype=np.float64)
+             if outputs[name].ndim > 1 else float(outputs[name][row]))
             for name in field_order)
         yield (partition_vocab[idx],
                dp_combiners._create_named_tuple_instance(
@@ -2050,43 +2038,21 @@ def decode_blocked_results(kept_ids, outputs, partition_vocab: Sequence[Any],
                         partition_vocab, compound)
 
 
-# Partition buckets at or under this row count decode through the
-# whole-column host-slice fast path in decode_release_results; larger
-# releases keep the O(kept) device-side slicing.
-_HOST_SLICE_MAX_ROWS = 4096
-
-
 def decode_release_results(n_kept, order, outputs,
                            partition_vocab: Sequence[Any],
                            compound: dp_combiners.CompoundCombiner):
     """Compacted dense release (aggregate_release_kernel / the meshed
-    route) -> results. One scalar sync gates the O(kept) slices; every
-    slice's host copy starts before the single barrier in _decode_rows
-    (the same overlapped-drain discipline as the blocked drivers'
-    staged drains)."""
+    route, whose arrays are replicated; a batched lane's host copies) ->
+    results. One scalar sync gates the drain: the kept ids and every
+    column cross as one bucket-length prefix each, all copies in flight
+    before the single barrier, and are cut to the kept count on the host
+    (rt_pipeline.KeptPrefix — no device program depends on the count, and
+    no row the selection dropped gets past it). Pure indexing: the
+    emitted stream is np.asarray(col)[:k]'s."""
     with rt_trace.span("release_wait", what="n_kept"):
         k = int(n_kept)  # the one sync; gates O(kept) transfers
     rt_telemetry.record("release_dispatches")
-    if np.shape(order)[0] <= _HOST_SLICE_MAX_ROWS:
-        # Micro-release fast path: at small partition buckets the
-        # device-side slice programs (one per column plus the ids) cost
-        # more dispatch overhead than the padding bytes they avoid
-        # transferring — fetch each column whole and slice on the host.
-        # Pure indexing either way: the emitted stream is bit-identical.
-        ids = np.asarray(order)[:k]
-        sliced = {name: np.asarray(col)[:k]
-                  for name, col in outputs.items()}
-        if isinstance(order, jax.Array):
-            d2h = int(order.nbytes)
-            for col in outputs.values():
-                d2h += int(col.nbytes)
-            rt_telemetry.record("d2h_bytes", d2h)
-        return _decode_rows(sliced, enumerate(ids), partition_vocab,
-                            compound)
-    ids = order[:k]
-    sliced = {name: col[:k] for name, col in outputs.items()}
-    if isinstance(ids, jax.Array):
-        rt_pipeline.copy_to_host_async(ids)
-        rt_telemetry.record("d2h_bytes", int(ids.nbytes))
-    return _decode_rows(sliced, enumerate(np.asarray(ids)),
+    with rt_trace.span("drain"):
+        ids, *columns = rt_pipeline.fetch_kept((order, *outputs.values()), k)
+    return _decode_rows(dict(zip(outputs, columns)), enumerate(ids),
                         partition_vocab, compound)

@@ -569,25 +569,18 @@ _copy_to_host_async = rt_pipeline.copy_to_host_async
 
 def _materialize_block_record(ids_sorted, outputs_sorted, k: int,
                               b_base: int) -> rt_journal.BlockRecord:
-    """O(kept) journal-record materialization with overlapped copies.
-
-    Every output slice's device->host copy starts BEFORE the first
-    blocking np.asarray — the same discipline as the dense executor's
-    _decode_rows drain. The journaled consume paths used to materialize
-    ids + each column serially (one blocking round trip per array,
-    the async-drain asymmetry); now the transfers overlap each other
-    and the still-running block compute, and the np.asarray barrier
-    waits once for the batch."""
-    ids = ids_sorted[:k]
-    cols = {name: col[:k] for name, col in outputs_sorted.items()}
-    _copy_to_host_async(ids)
-    for col in cols.values():
-        _copy_to_host_async(col)
+    """Journal-record materialization with overlapped copies: the kept ids
+    and every column cross as one bucket-length prefix each
+    (rt_pipeline.KeptPrefix), all copies started before the one barrier,
+    overlapping each other and the still-running block compute. The
+    record holds exactly the k kept rows: the prefix's leftover rows are
+    cut on the host before the record exists."""
+    ids, *columns = rt_pipeline.fetch_kept(
+        (ids_sorted, *outputs_sorted.values()), k)
     rt_telemetry.record("release_dispatches")
     return rt_journal.BlockRecord(
-        ids=np.asarray(ids).astype(np.int64) + b_base,  # staticcheck: disable=host-transfer — O(kept) journal materialization gated by the n_kept sync; the copy was started async above
-        outputs={name: np.asarray(col)  # staticcheck: disable=host-transfer — O(kept) journal materialization; all column copies started async above, this barrier waits for the batch
-                 for name, col in cols.items()})
+        ids=ids.astype(np.int64) + b_base,
+        outputs=dict(zip(outputs_sorted, columns)))
 
 
 class _StagedDrain:
@@ -596,16 +589,18 @@ class _StagedDrain:
     consume() used to np.asarray each kept slice as its block was
     consumed — one blocking device->host round trip per array, so a
     10-block run with 3 output columns paid ~30 serial round trips.
-    Staging instead starts an async host copy per
-    slice and defers the blocking np.asarray: transfers overlap each
-    other and the remaining block compute. Order is preserved per
-    target list (blocks are consumed ascending), so the concatenation
-    contracts of the drivers are unchanged.
+    Staging instead starts the host copies of a block's kept prefix
+    (rt_pipeline.KeptPrefix: a bucket-length slice of the ids and of each
+    column) and defers the barrier: transfers overlap each other and the
+    remaining block compute, and the cut to the kept count happens on the
+    host at drain time. Order is preserved per target list (blocks are
+    consumed ascending), so the concatenation contracts of the drivers
+    are unchanged.
 
     Residency stays bounded: staged device buffers would otherwise
     accumulate O(total kept) in HBM — the exact footprint the bounded
     dispatch window exists to avoid. end_block() (called once per
-    consumed block) materializes and frees block groups older than
+    consumed block) materializes and frees blocks older than
     `max_staged_blocks`; those blocks finished computing a full window
     ago, so draining them rarely blocks and still overlaps the
     in-flight compute."""
@@ -616,11 +611,13 @@ class _StagedDrain:
         self._open = 0  # entries staged since the last end_block()
         self._max = max_staged_blocks
 
-    def stage(self, target: list, arr, transform=None) -> None:
-        """Append np.asarray(arr) (through transform, if given) to
-        target at drain time; starts the host copy now."""
-        _copy_to_host_async(arr)
-        self._staged.append((target, arr, transform))
+    def stage(self, targets, arrays, k: int, id_base: int) -> None:
+        """At drain time, append the first k rows of arrays[i] to
+        targets[i]; arrays[0] are the block's kept ids, relative to its
+        first partition `id_base`, and land as global int64 ids. The host
+        copies start now."""
+        self._staged.append(
+            (targets, rt_pipeline.KeptPrefix(arrays, k), id_base))
         self._open += 1
 
     def end_block(self) -> None:
@@ -638,14 +635,12 @@ class _StagedDrain:
         self._drain_n(len(self._staged))
 
     def _drain_n(self, n: int) -> None:
-        nbytes = 0
-        for target, arr, transform in self._staged[:n]:
-            host = np.asarray(arr)
-            nbytes += int(host.nbytes)
-            target.append(transform(host) if transform else host)
+        for targets, prefix, id_base in self._staged[:n]:
+            ids, *columns = prefix.host()
+            for target, host in zip(
+                    targets, (ids.astype(np.int64) + id_base, *columns)):
+                target.append(host)
         del self._staged[:n]
-        if n:
-            rt_telemetry.record("d2h_bytes", nbytes)
 
 
 def _seed_pass1(seconds: float) -> None:
@@ -763,17 +758,17 @@ def _bound_and_compact_host_staged(pid, pk, values, valid, min_v, max_v,
         with rt_trace.span("p1.chunk_wait", chunk=ci):
             k = int(n_kept)  # the only per-chunk sync; bounds the d2h volume
         with rt_trace.span("p1.fetch", chunk=ci, rows=k) as sp:
-            b_pk.append(np.asarray(spk[:k]))
-            b_pair.append(np.asarray(pair[:k]))
+            # The survivors cross as one bucket-length prefix per column
+            # (no program per survivor count) and are cut to k here.
+            targets = [b_pk, b_pair, *(b_cols[name] for name in cols)]
+            arrays = [spk, pair, *cols.values()]
             if cfg.quantiles:
-                b_leaf.append(np.asarray(leaf[:k]))
-            for name, col in cols.items():
-                b_cols[name].append(np.asarray(col[:k]))
-            nbytes = _nbytes(b_pk[-1], b_pair[-1],
-                             *(chunks[-1] for chunks in b_cols.values()),
-                             *b_leaf[-1:])
-            sp.set(bytes=nbytes)
-            rt_telemetry.record("d2h_bytes", nbytes)
+                targets.append(b_leaf)
+                arrays.append(leaf)
+            fetch = rt_pipeline.KeptPrefix(arrays, k)
+            sp.set(bytes=fetch.nbytes)
+            for target, host in zip(targets, fetch.host()):
+                target.append(host)
         start = end
 
     with rt_trace.span("p1.merge", chunks=len(b_pk)):
@@ -1080,11 +1075,10 @@ def aggregate_blocked_sharded(mesh,
                 journal.put(job, rt_journal.block_key(b_base, C), record)
                 append_record(record)
             elif k:
-                drain.stage(kept_ids, ids_sorted[:k],
-                            lambda h, base_=b_base: h.astype(np.int64) +
-                            base_)
-                for name, col in outputs_sorted.items():
-                    drain.stage(kept_outputs.setdefault(name, []), col[:k])
+                drain.stage(
+                    [kept_ids, *(kept_outputs.setdefault(name, [])
+                                 for name in outputs_sorted)],
+                    [ids_sorted, *outputs_sorted.values()], k, b_base)
             drain.end_block()
 
         def block_iter():
@@ -1324,20 +1318,17 @@ def select_partitions_blocked_sharded(mesh,
             n_kept, order = result
             k = int(n_kept)  # sync; gates the O(kept) transfer
             if journal is not None:
-                kept = order[:k]
-                # Async-copy before the blocking materialization (the
-                # dense _decode_rows discipline, shared via
-                # _materialize_block_record on the aggregate routes).
-                _copy_to_host_async(kept)
-                ids = np.asarray(kept).astype(np.int64) + b_base  # staticcheck: disable=host-transfer — O(kept) journal materialization; the copy was started async on the line above
+                # Journaled runs materialize per block, as the aggregate
+                # routes' _materialize_block_record does: the record holds
+                # exactly the k kept ids.
+                (ids,) = rt_pipeline.fetch_kept((order,), k)
+                ids = ids.astype(np.int64) + b_base
                 journal.put(job, rt_journal.block_key(b_base, C),
                             rt_journal.BlockRecord(ids=ids, outputs={}))
                 if k:
                     kept_ids.append(ids)
             elif k:
-                drain.stage(kept_ids, order[:k],
-                            lambda h, base_=b_base: h.astype(np.int64) +
-                            base_)
+                drain.stage([kept_ids], [order], k, b_base)
             drain.end_block()
 
         def block_iter():
@@ -1440,20 +1431,17 @@ def select_partitions_blocked(pid,
             n_kept, order = result
             k = int(n_kept)  # sync; gates the O(kept) transfer
             if journal is not None:
-                kept = order[:k]
-                # Async-copy before the blocking materialization (the
-                # dense _decode_rows discipline, shared via
-                # _materialize_block_record on the aggregate routes).
-                _copy_to_host_async(kept)
-                ids = np.asarray(kept).astype(np.int64) + b_base  # staticcheck: disable=host-transfer — O(kept) journal materialization; the copy was started async on the line above
+                # Journaled runs materialize per block, as the aggregate
+                # routes' _materialize_block_record does: the record holds
+                # exactly the k kept ids.
+                (ids,) = rt_pipeline.fetch_kept((order,), k)
+                ids = ids.astype(np.int64) + b_base
                 journal.put(job, rt_journal.block_key(b_base, C),
                             rt_journal.BlockRecord(ids=ids, outputs={}))
                 if k:
                     kept_ids.append(ids)
             elif k:
-                drain.stage(kept_ids, order[:k],
-                            lambda h, base_=b_base: h.astype(np.int64) +
-                            base_)
+                drain.stage([kept_ids], [order], k, b_base)
             drain.end_block()
 
         def block_iter():
@@ -1681,11 +1669,10 @@ def aggregate_blocked(pid,
                 journal.put(job, rt_journal.block_key(b_base, C), record)
                 append_record(record)
             elif k:
-                drain.stage(kept_ids, ids_sorted[:k],
-                            lambda h, base_=b_base: h.astype(np.int64) +
-                            base_)
-                for name, col in outputs_sorted.items():
-                    drain.stage(kept_outputs.setdefault(name, []), col[:k])
+                drain.stage(
+                    [kept_ids, *(kept_outputs.setdefault(name, [])
+                                 for name in outputs_sorted)],
+                    [ids_sorted, *outputs_sorted.values()], k, b_base)
             drain.end_block()
 
         def block_iter():

@@ -44,6 +44,11 @@ device-resident pipeline instead of one serial batch call:
     executor's dense drain can use it without an import cycle): result
     columns start their device->host copies together and block only at
     the final materialization barrier.
+  * **Kept-prefix drain** (``KeptPrefix``, ``drain_bucket``) — what every
+    ``[:k]`` of a kept-first compacted release goes through, on every
+    route: the device slice's length comes from a short ladder of buckets,
+    never from the kept count, so no job builds a program for a count the
+    process has not seen; the cut to k happens on the host copy.
 
 Failure semantics compose with the rest of the runtime: encode-worker
 exceptions re-raise in the consumer (the original exception, so
@@ -57,8 +62,8 @@ seed, never from execution history.
 Static discipline: this module is covered by staticcheck's host-transfer
 rule (like parallel/ and ops/) — staging-queue consumers must route any
 device->host fetch through ``mesh.host_fetch``; the module itself
-performs none (chunks flow host->device only, drains happen in the
-executor at the final barrier).
+performs one, suppressed with its reason: ``KeptPrefix.host``, the
+O(kept) barrier of a release drain (chunks flow host->device only).
 """
 
 import contextlib
@@ -313,6 +318,107 @@ def copy_to_host_async(arr) -> None:
             "copy_to_host_async is unsupported on this platform (%s: %s); "
             "device->host drains will block at materialization instead of "
             "overlapping. Warning once.", type(e).__name__, e)
+
+
+# The ladder of device-prefix lengths a drain slices a kept-first
+# compacted release to. A slice on the device is a program per (column
+# shape, dtype, length): cut to the kept count itself, every job whose
+# count the process had not seen built one per dtype (34-50 ms each on a
+# TPU host, and a stalled build failed jobs: PERF.md §6, PR 34). Cut to
+# a power of two at or above the kept count, a process builds one per
+# dtype and BUCKET — the first job does, every later one dispatches.
+# The shortest bucket is 4,096 rows, and it is also the length at or
+# under which a column goes whole: below it a slice's dispatch costs more
+# than the padding bytes it keeps off the link, and a finer ladder only
+# adds boundaries for a kept count to straddle (keys-1e7's fullest block
+# keeps 495-525 partitions, either side of 512: with a floor of 8 rows a
+# window of its jobs still built six programs; my chip run, PR 34). What
+# crosses stays small: at most twice the kept rows or 4,096 of them,
+# tens of KB a drain.
+DRAIN_MIN_ROWS = 4096
+
+
+def drain_bucket(k: int, length: int) -> int:
+    """Rows of a kept-first compacted column of `length` rows that cross
+    to the host for its `k` kept ones: none for none, else the next power
+    of two at or above max(k, DRAIN_MIN_ROWS), capped at the column —
+    where that is the whole column no program is dispatched at all. Its
+    only inputs are k and the length, so every route and every cell takes
+    the same rule."""
+    if k <= 0:
+        return 0
+    return min(length, max(DRAIN_MIN_ROWS, _pow2_at_least(k)))
+
+
+class KeptPrefix:
+    """The first `k` rows of a compacted release's arrays (the kept ids
+    and each released column, kept-first, behind an n_kept sync) on their
+    way to the host — what every `[:k]` of such an array goes through.
+
+    Constructing it slices each device array to `drain_bucket(k, length)`
+    rows and starts every host copy, so the transfers overlap each other
+    and whatever the device still runs; `host()` is the one barrier and
+    hands back host arrays of exactly k rows, in the arrays' order. A host
+    array (a batched lane's copy) is only cut.
+
+    Release discipline: rows k..bucket of a prefix are compaction
+    leftovers — noised values and ids of partitions the selection
+    DROPPED. They cross the link and end here: `host()` copies the k kept
+    rows out of the fetched buffer and lets go of it, so nothing it
+    returns holds a row at or beyond k, and no caller may read a prefix
+    any other way. They must never reach executor._decode_rows' loop, a
+    journal record or a log (the drains' release-taint notes rest on
+    this).
+
+    `rows` / `nbytes`: rows of the device prefix fetched (bucket length,
+    or the column's where it went whole) and the bytes that cross.
+    Telemetry: `drain_bucket_rows` += rows once a fetch, `d2h_bytes` +=
+    nbytes at the barrier."""
+
+    def __init__(self, arrays, k: int):
+        import jax
+        import numpy as np
+        self.k = max(int(k), 0)
+        self.rows = 0
+        self.nbytes = 0
+        self._prefixes = []
+        for arr in arrays:
+            if not isinstance(arr, jax.Array):
+                self._prefixes.append(arr)
+                continue
+            rows = drain_bucket(self.k, arr.shape[0])
+            if not rows:  # nothing kept: nothing is dispatched or fetched
+                self._prefixes.append(
+                    np.empty((0,) + tuple(arr.shape[1:]), arr.dtype))
+                continue
+            prefix = (arr if rows == arr.shape[0] else
+                      jax.lax.slice_in_dim(arr, 0, rows))
+            copy_to_host_async(prefix)
+            self._prefixes.append(prefix)
+            self.rows = max(self.rows, rows)
+            self.nbytes += int(prefix.nbytes)
+        if self.rows:
+            rt_telemetry.record("drain_bucket_rows", self.rows)
+
+    def host(self) -> list:
+        """Blocks until the prefixes are on the host; each array's k kept
+        rows, and nothing else of it."""
+        import numpy as np
+        kept = []
+        for prefix in self._prefixes:
+            fetched = np.asarray(prefix)  # staticcheck: disable=host-transfer — the O(kept) drain barrier itself: at most max(2k, DRAIN_MIN_ROWS) rows per array, gated by the caller's n_kept sync; every copy was started async in __init__
+            kept.append(fetched if fetched.shape[0] == self.k else
+                        fetched[:self.k].copy())
+        self._prefixes = []
+        if self.nbytes:
+            rt_telemetry.record("d2h_bytes", self.nbytes)
+        return kept
+
+
+def fetch_kept(arrays, k: int) -> list:
+    """Host copies of the first k rows of each kept-first compacted
+    array, all in flight before the one barrier (KeptPrefix)."""
+    return KeptPrefix(arrays, k).host()
 
 
 # --- Device-resident chunk accumulation ------------------------------------

@@ -139,9 +139,22 @@ REGISTRY: Dict[str, Metric] = {
                  "the bytes of a row in the device dtypes"),
         _counter("d2h_bytes",
                  "bytes copied device->host on the release path, from "
-                 "nbytes where they cross: pass 1's survivor fetches, "
-                 "control-table host_fetch, the blocked staged drains and "
-                 "the decode barrier's released columns"),
+                 "nbytes where they cross: control-table host_fetch, and "
+                 "every kept prefix a drain fetched (pipeline.KeptPrefix: "
+                 "pass 1's survivors, the blocked staged drains and "
+                 "journal records, the dense release's ids and columns) "
+                 "at the length that crossed, the bucket's, not the kept "
+                 "count's"),
+        _counter("drain_bucket_rows",
+                 "rows of the device prefix each drain of a kept-first "
+                 "compacted release fetched (pipeline.KeptPrefix), once a "
+                 "fetch whatever its number of columns: the bucket's "
+                 "length (pipeline.drain_bucket: the next power of two at "
+                 "or above the kept count, 4,096 rows at least), or the "
+                 "column's where it went whole; a fetch of nothing kept "
+                 "adds nothing. Beside the "
+                 "kept count it says how often the ladder engaged and "
+                 "what it over-fetched"),
         _counter("pass1_device_resident",
                  "aggregate_blocked calls whose pass 1 stayed "
                  "device-resident: the rows fit the device's row budget "

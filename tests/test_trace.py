@@ -776,15 +776,21 @@ class TestUntracedCounters:
         before = telemetry.snapshot()
         released, n, n_columns = _chunk_job(9)
         moved = telemetry.delta(before)
+        from pipelinedp_tpu.runtime import pipeline
         f = np.dtype(executor._ftype()).itemsize
         assert moved["h2d_bytes"] == n * (4 + 4 + f)
-        # More than _HOST_SLICE_MAX_ROWS partitions: the decode slices to
-        # the kept count on the device and fetches exactly that.
-        assert moved["d2h_bytes"] == len(released) * (4 + n_columns * f)
+        # More than DRAIN_MIN_ROWS partitions: the decode slices
+        # to the kept count's bucket on the device and fetches exactly
+        # that; the counter holds what crossed.
+        bucket = pipeline.drain_bucket(len(released), 5900)
+        assert len(released) < bucket < 5900  # of some 6,000 partitions
+        assert moved["drain_bucket_rows"] == bucket
+        assert moved["d2h_bytes"] == bucket * (4 + n_columns * f)
 
     def test_blocked_host_staged_bytes_follow_its_shapes(self):
         from pipelinedp_tpu import executor
         from pipelinedp_tpu.parallel import large_p
+        from pipelinedp_tpu.runtime import pipeline
         args = _blocked_args(epsilon=200.0)
         run = functools.partial(large_p.aggregate_blocked, *args,
                                 block_partitions=1 << 10, row_chunk=1000)
@@ -797,16 +803,27 @@ class TestUntracedCounters:
         f = np.dtype(executor._ftype()).itemsize
         events = _span_events()
         caps = [e["args"]["cap"] for e in events if e["name"] == "p1.chunk"]
-        survivors = sum(e["args"]["rows"] for e in events
-                        if e["name"] == "p1.fetch")
+        fetches = [e["args"] for e in events if e["name"] == "p1.fetch"]
+        survivors = sum(a["rows"] for a in fetches)
         survivor_bytes = survivors * (4 + 1 + f)  # spk, pair flag, sum
+        # What a fetch brings down is its survivor count's bucket of the
+        # chunk's capacity, not the count.
+        fetched_bytes = sum(a["bytes"] for a in fetches)
+        assert fetched_bytes == sum(
+            pipeline.drain_bucket(a["rows"], cap) * (4 + 1 + f)
+            for a, cap in zip(fetches, caps))
         control = sum(e["args"]["bytes"] for e in events
                       if e["name"] == "host_fetch")
         # Up: each chunk padded to its capacity (pid, pk, value, valid),
         # then the merged survivors once more.
         assert moved["h2d_bytes"] == sum(caps) * (4 + 4 + f + 1) + \
             survivor_bytes
-        # Down: the survivors, the block-offset table, and per kept
-        # partition its id and released columns.
-        assert moved["d2h_bytes"] == survivor_bytes + control + \
-            len(kept) * (4 + len(outputs) * f)
+        # Down: the survivors' buckets, the block-offset table, and per
+        # block its kept partitions' bucket of ids and released columns
+        # (blocks of 1 << 10 partitions: such a column crosses whole).
+        drained = moved["drain_bucket_rows"] - sum(
+            pipeline.drain_bucket(a["rows"], cap)
+            for a, cap in zip(fetches, caps))
+        assert len(kept) <= drained and drained % (1 << 10) == 0
+        assert moved["d2h_bytes"] == fetched_bytes + control + \
+            drained * (4 + len(outputs) * f)
