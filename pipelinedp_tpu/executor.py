@@ -879,6 +879,26 @@ def _lazy_quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
     }
 
 
+def _lazy_quantiles(cfg: KernelConfig) -> bool:
+    """Whether the trees of cfg.n_partitions partitions exceed one dense
+    histogram chunk, so quantile_outputs descends lazily."""
+    return -(-cfg.n_partitions // max(cfg.quantile_chunk, 1)) > 1
+
+
+def quantile_row_passes(cfg: KernelConfig) -> int:
+    """Passes over the bounded row stream that quantile_outputs takes in
+    one launch, from the static config alone: one scatter-add per quantile
+    and tree level on the lazy path (every descent step re-reads the rows),
+    one for the whole histogram on the one-chunk dense path, none without
+    percentiles. The telemetry counter `quantile_row_passes` has no other
+    source, and shares _lazy_quantiles with the dispatch below."""
+    if not cfg.quantiles:
+        return 0
+    if _lazy_quantiles(cfg):
+        return len(cfg.quantiles) * cfg.tree_height
+    return 1
+
+
 def quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
                      cfg: KernelConfig, psum_axis: Optional[str] = None,
                      secure_tables=None):
@@ -897,7 +917,7 @@ def quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
     (_lazy_quantile_outputs): O(n_q * height) row passes total instead of
     one per chunk, with [P, branching] peak memory.
     """
-    if -(-cfg.n_partitions // max(cfg.quantile_chunk, 1)) > 1:
+    if _lazy_quantiles(cfg):
         return _lazy_quantile_outputs(qrows, min_v, max_v, stds, key, cfg,
                                       psum_axis, secure_tables)
     row_pk, row_leaf, row_keep = qrows
@@ -998,11 +1018,12 @@ def _aggregate_trace(pid, pk, values, valid, min_v, max_v, min_s, max_s,
         outputs, keep, row_count = finalize(cols, min_v, mid, stds,
                                             final_key, cfg, secure_tables)
     if cfg.quantiles:
-        qkey = jax.random.fold_in(rng_key, 7919)
-        outputs.update(
-            quantile_outputs(qrows, min_v, max_v, stds, qkey, cfg,
-                             psum_axis=psum_axis,
-                             secure_tables=secure_tables))
+        with jax.named_scope("quantile_tree"):
+            qkey = jax.random.fold_in(rng_key, 7919)
+            outputs.update(
+                quantile_outputs(qrows, min_v, max_v, stds, qkey, cfg,
+                                 psum_axis=psum_axis,
+                                 secure_tables=secure_tables))
     return outputs, keep, row_count
 
 
@@ -1863,6 +1884,13 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
         cfg = make_kernel_config(params, compound, n_partitions, private,
                                  selection_params, secure=secure,
                                  numeric_mode=numeric_mode)
+        if cfg.quantiles:
+            # Once per materialised aggregation with percentiles: the row
+            # passes its trees take (on the blocked route each block's
+            # program takes them over its own rows) and the trees built.
+            rt_telemetry.record("quantile_row_passes",
+                                quantile_row_passes(cfg))
+            rt_telemetry.record("quantile_trees", n_partitions)
         stds = compute_noise_stds(compound, params)
         secure_tables = None
         if secure:

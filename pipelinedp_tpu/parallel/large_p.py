@@ -207,12 +207,13 @@ def _block_trace(spk_s, pair_s, cols_s, leaf_s, lo, length, base, min_v,
         # partition ids index trees [0, C); quantile_outputs picks the lazy
         # descent whenever the block exceeds one dense histogram chunk, so
         # peak memory stays O(C * branching), never O(C * leaves).
-        qkey = jax.random.fold_in(key, 7919)
-        outputs.update(
-            executor.quantile_outputs((spk_rel, take(leaf_s), valid), min_v,
-                                      max_v, stds, qkey, cfg,
-                                      psum_axis=psum_axis,
-                                      secure_tables=secure_tables))
+        with jax.named_scope("quantile_tree"):
+            qkey = jax.random.fold_in(key, 7919)
+            outputs.update(
+                executor.quantile_outputs((spk_rel, take(leaf_s), valid),
+                                          min_v, max_v, stds, qkey, cfg,
+                                          psum_axis=psum_axis,
+                                          secure_tables=secure_tables))
     order = jnp.argsort(~keep, stable=True)  # kept partitions first
     ids_sorted = order.astype(jnp.int32)
     outputs_sorted = {name: col[order] for name, col in outputs.items()}

@@ -165,6 +165,16 @@ REGISTRY: Dict[str, Metric] = {
                  "scalar value columns bounded in one pass, added once per "
                  "materialised aggregation: len(AggregateParams."
                  "value_columns), 1 for a one-column job"),
+        _counter("quantile_row_passes",
+                 "passes over the bounded row stream that the quantile "
+                 "trees of one launch take (executor.quantile_row_passes, "
+                 "from the static config: quantiles x tree height on the "
+                 "lazy descent, 1 on the one-chunk dense histogram), added "
+                 "once per materialised aggregation that has percentiles"),
+        _counter("quantile_trees",
+                 "partitions a materialised aggregation with percentiles "
+                 "built quantile trees for (the launch's n_partitions), "
+                 "added once per such aggregation"),
         _counter("aot_cache_hits",
                  "warm-path dispatches served by an ahead-of-time "
                  "compiled executable from the process-wide "

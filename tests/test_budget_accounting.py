@@ -8,6 +8,7 @@ import math
 
 import numpy as np
 import pytest
+import threadpoolctl
 
 import pipelinedp_tpu as pdp
 from pipelinedp_tpu.accounting import pld as pldlib
@@ -370,8 +371,13 @@ class TestPldIndependentCrossChecks:
         grid = 1e-4
         losses, pmf = self._laplace_loss_pmf(b, grid)
         composed = pmf
-        for _ in range(k - 1):
-            composed = np.convolve(composed, pmf)
+        # np.convolve is one BLAS dot per output element, 60,000 and more of
+        # them: with BLAS threads on, each wakes a pool that has to share the
+        # cores with five other xdist workers' XLA threads, and the 3 s this
+        # takes alone became 238-623 s under the driver's command (PR 35).
+        with threadpoolctl.threadpool_limits(limits=1, user_api="blas"):
+            for _ in range(k - 1):
+                composed = np.convolve(composed, pmf)
         n = (len(losses) - 1) // 2
         composed_losses = np.arange(-k * n, k * n + 1) * grid
         # Hockey-stick divergence at eps from the composed PMF.

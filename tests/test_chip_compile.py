@@ -176,6 +176,37 @@ def test_dense_release_compiles_with_five_value_columns(chip):
     assert " f64[" not in text
 
 
+def test_dense_release_compiles_with_percentiles(chip):
+    """The upstream movie-ratings job with its PERCENTILEs
+    (perfbench/configs/netflix-percentiles.json): 17,770 quantile trees
+    on the lazy descent, rows reduced to 2^12. The trees' ops carry the
+    `quantile_tree` scope a trace attributes them by; the two quantiles'
+    root-level passes have identical inputs, and the compiler merges them:
+    7 scatters for 2 quantiles x 4 levels. Full size, 2^24 rows, compiled
+    for the described v5e in this sandbox (PR 35): PERF.md section 4."""
+    import pipelinedp_tpu as pdp
+
+    M = pdp.Metrics
+    _, cfg, stds, _ = _common.build_spec(
+        MOVIES, metrics=[M.COUNT, M.SUM, M.PRIVACY_ID_COUNT,
+                         M.PERCENTILE(50), M.PERCENTILE(90)], l0=2, linf=1)
+    assert executor.quantile_row_passes(cfg) == 8
+
+    def lower():
+        scalars = [chip((), F32)] * 5
+        return executor.aggregate_release_kernel.lower(
+            *_rows(chip, ROWS), *scalars, chip((len(stds),), F32),
+            chip((2,), U32), cfg)
+
+    compiled = _x32(lower).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert len(scatters) == 7, len(scatters)
+    assert all("quantile_tree" in line for line in scatters), scatters
+    assert " f64[" not in text
+
+
 def test_blocked_block_kernel_compiles(chip):
     """One partition block at the real C = 2^20 (large_p), its row
     gather capacity reduced."""

@@ -10,8 +10,10 @@ host-staged and once device-resident, a dense job given a host
 EncodedData of two value columns whose row count is no power of two (the
 dense staging: pad, upload, its slab count) — and each counter must be declared
 in telemetry.REGISTRY and, where those runs can reach it, counted by
-them. A rename in the program then fails here instead of leaving a null
-in the ledger.
+them. A fourth tiny run, a dense job with two PERCENTILEs over more
+partitions than one histogram chunk holds (the lazy descent), counts the
+quantile trees and their row passes. A rename in the program then fails
+here instead of leaving a null in the ledger.
 """
 
 import json
@@ -96,6 +98,38 @@ def _dense_encoded_run():
     assert len(dict(result)) == 6
 
 
+def _dense_percentile_run():
+    """Two PERCENTILEs over 600 partitions, more than the 512 whose leaves
+    one dense histogram chunk holds: the lazy descent, so
+    quantile_row_passes counts 2 quantiles x 4 levels and quantile_trees
+    the 600."""
+    from pipelinedp_tpu import columnar
+
+    rng = np.random.default_rng(4)
+    n = 4000
+    encoded = columnar.encode_columns(
+        rng.integers(0, 500, n), rng.integers(0, 600, n),
+        rng.integers(1, 6, n).astype(np.float64))
+    assert encoded.n_partitions > 512
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.PERCENTILE(50),
+                 pdp.Metrics.PERCENTILE(90)],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=2,
+        max_contributions_per_partition=1,
+        min_value=1.0,
+        max_value=5.0)
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0, total_delta=1e-3)
+    engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=5))
+    before = telemetry.snapshot()
+    result = engine.aggregate(encoded, params, pdp.DataExtractors())
+    acc.compute_budgets()
+    assert dict(result)
+    counted = telemetry.delta(before)
+    assert counted["quantile_row_passes"] == 8
+    assert counted["quantile_trees"] == encoded.n_partitions
+
+
 def _blocked_run(row_chunk):
     """row_chunk below the 3,000 rows: the host-staged pass 1; None: the
     device's own budget, which holds them."""
@@ -146,6 +180,7 @@ def recorded():
     try:
         _dense_chunk_run()
         _dense_encoded_run()
+        _dense_percentile_run()
         _blocked_run(row_chunk=1000)
         _blocked_run(row_chunk=None)
         counters = {name for name, n in telemetry.snapshot().items() if n}
