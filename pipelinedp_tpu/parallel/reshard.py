@@ -21,13 +21,30 @@ This module keeps the rows in HBM end to end:
      are bit-identical everywhere, every controller derives the SAME
      static capacities and compiles the SAME exchange program (divergent
      capacities would deadlock the collective).
-  3. **Padded all_to_all**: each shard packs its rows into [D, cap_send]
-     invalid-padded buckets and ONE jax.lax.all_to_all per column moves
-     them over the SHARD_AXIS mesh axis (ICI within a host, DCN across
-     hosts on a pod).
-  4. **Compaction**: each shard sorts its received rows valid-first and
-     slices to the host-known output capacity, restoring the dense
-     leading-axis layout every meshed kernel consumes.
+  3. **Pack and padded all_to_all**: each shard sorts its rows ONCE by
+     destination with every column riding the key as a sort payload
+     (jax.lax.sort, stable, invalid rows last), so bucket d is the
+     contiguous run of the sorted columns that holds destination d: a
+     dynamic_slice of cap_send rows, masked past the bucket's count
+     with the invalid fills. ONE jax.lax.all_to_all per column moves
+     the [D, cap_send] buckets over the SHARD_AXIS mesh axis (ICI
+     within a host, DCN across hosts on a pod), and one more moves the
+     [D] send counts — the received counts stand in for a plane of
+     valid flags.
+  4. **Compaction**: received bucket d is valid in its first
+     r_counts[d] rows, so each shard copies the buckets in order
+     d = 0..D-1 into one buffer at offs[d] = the rows received before
+     bucket d (dynamic_update_slice: each bucket's invalid tail is
+     overwritten by the next bucket's head) and slices to the host-known
+     output capacity — the order a stable valid-first sort of the
+     received slots gives, and the dense leading-axis layout every
+     meshed kernel consumes; valid = arange < rows received.
+
+No row is addressed one at a time in either program: a per-row gather or
+scatter runs at 9-26 ns a row on a TPU v5e where a sort payload or a
+contiguous copy streams (PERF.md, PR 36: nine such gathers were 3.0 s of
+a 3.4 s device job at 2^24 rows a shard), so the send counts are D
+masked sums and rows move only as sort payloads and slices.
 
 Capacity caching: the rounded (cap_send, out_cap) pair is cached per
 exchange geometry (mesh devices, padded per-shard capacity, salt, value
@@ -101,6 +118,20 @@ def _dest_shard(pid, n_shards: int, salt: int):
     return (h % jnp.uint32(n_shards)).astype(jnp.int32)
 
 
+def _send_dest(pid, valid, n_shards: int, salt: int):
+    """Destination shard of each row, n_shards for an invalid one (so a
+    sort by it puts the invalid rows last)."""
+    return jnp.where(valid, _dest_shard(pid, n_shards, salt), n_shards)
+
+
+def _send_counts(dest, n_shards: int):
+    """int32[D]: this shard's rows per destination, as D masked sums —
+    streaming passes, where a scatter-add into D bins visits the rows one
+    at a time."""
+    return jnp.stack([jnp.sum(dest == d, dtype=jnp.int32)
+                      for d in range(n_shards)])
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n_shards", "salt", "mesh"))
 def _count_stats_kernel(pid, valid, n_shards: int, salt: int, mesh: Mesh):
@@ -113,10 +144,8 @@ def _count_stats_kernel(pid, valid, n_shards: int, salt: int, mesh: Mesh):
     host's table shard)."""
 
     def per_shard(pid_s, valid_s):
-        dest = _dest_shard(pid_s, n_shards, salt)
-        idx = jnp.where(valid_s, dest, n_shards)
-        counts = jnp.zeros((n_shards + 1,), jnp.int32).at[idx].add(
-            1)[:n_shards]
+        counts = _send_counts(_send_dest(pid_s, valid_s, n_shards, salt),
+                              n_shards)
         recv = jax.lax.psum(counts, SHARD_AXIS)
         max_send = jax.lax.pmax(counts.max(), SHARD_AXIS)
         return jnp.stack([max_send, recv.max(), recv.sum()])
@@ -132,47 +161,84 @@ def _count_stats_kernel(pid, valid, n_shards: int, salt: int, mesh: Mesh):
                                     "salt", "mesh"))
 def _exchange_kernel(pid, pk, values, valid, cap_send: int, out_cap: int,
                      n_shards: int, salt: int, mesh: Mesh):
-    """Pack -> all_to_all -> compact, one jit program, zero host traffic.
+    """Pack -> all_to_all -> compact, one jit program, zero host traffic
+    and no per-row random access: the rows travel as the payloads of one
+    sort and as contiguous slices.
 
-    Each shard sorts its rows by destination, gathers them into invalid-
-    padded [D, cap_send] buckets, exchanges bucket d with shard d over the
-    mesh axis, then sorts the received [D * cap_send] rows valid-first and
-    slices to the host-known out_cap — the dense leading-axis layout the
-    meshed kernels consume.
+    Each shard sorts its rows by destination with every column riding the
+    key, cuts bucket d as the contiguous run of the sorted columns that
+    holds destination d (invalid-padded to cap_send), exchanges bucket d
+    with shard d over the mesh axis together with the [D] send counts,
+    then copies the received buckets' valid heads back to back and slices
+    to the host-known out_cap — the dense leading-axis layout the meshed
+    kernels consume. A bucket longer than cap_send, or more received rows
+    than out_cap, is truncated: the caller's stats fetch sees that the
+    capacities did not fit and dispatches again.
     """
+    # Rows out a shard: out_cap of the D * cap_send received slots.
+    length = min(out_cap, n_shards * cap_send)
 
     def per_shard(pid_s, pk_s, values_s, valid_s):
-        n_local = pid_s.shape[0]
-        dest = jnp.where(valid_s, _dest_shard(pid_s, n_shards, salt),
-                         n_shards)
-        order = jnp.argsort(dest, stable=True)
-        starts = jnp.searchsorted(dest[order],
-                                  jnp.arange(n_shards + 1, dtype=jnp.int32))
-        j = jnp.arange(cap_send, dtype=jnp.int32)
-        slot = starts[:-1, None] + j[None, :]  # [D, cap_send] row ranks
-        slot_valid = slot < starts[1:, None]
-        take = order[jnp.minimum(slot, n_local - 1)]
+        if values_s.ndim == 1:
+            value_cols = [values_s]
+        else:
+            value_cols = [values_s[:, c] for c in range(values_s.shape[1])]
+        fills = [0, -1] + [0] * len(value_cols)
 
-        def exchange(col, fill):
-            bucket = jnp.where(
-                slot_valid.reshape(slot_valid.shape + (1,) *
-                                   (col.ndim - 1)), col[take],
-                jnp.asarray(fill, col.dtype))
-            return jax.lax.all_to_all(bucket, SHARD_AXIS, 0, 0, tiled=True)
+        with jax.named_scope("exchange_pack"):
+            dest = _send_dest(pid_s, valid_s, n_shards, salt)
+            _, *sorted_cols = jax.lax.sort(
+                (dest, pid_s, pk_s, *value_cols), num_keys=1,
+                is_stable=True)
+            held = _send_counts(dest, n_shards)
+            starts = jnp.cumsum(held, dtype=jnp.int32) - held
+            counts = jnp.minimum(held, cap_send)
+            slot_valid = (jnp.arange(cap_send, dtype=jnp.int32)[None, :] <
+                          counts[:, None])
 
-        r_valid = jax.lax.all_to_all(slot_valid, SHARD_AXIS, 0, 0,
-                                     tiled=True)
-        r_pid = exchange(pid_s, 0)
-        r_pk = exchange(pk_s, -1)
-        r_val = exchange(values_s, 0)
+            def buckets(col, fill):
+                padded = jnp.concatenate(
+                    [col, jnp.full((cap_send,), fill, col.dtype)])
+                runs = jnp.stack([
+                    jax.lax.dynamic_slice_in_dim(padded, starts[d],
+                                                 cap_send)
+                    for d in range(n_shards)
+                ])
+                return jnp.where(slot_valid, runs,
+                                 jnp.asarray(fill, col.dtype))
 
-        def flat(x):
-            return x.reshape((n_shards * cap_send,) + x.shape[2:])
+            packed = [buckets(c, f) for c, f in zip(sorted_cols, fills)]
 
-        fvalid = flat(r_valid)
-        keep_first = jnp.argsort(~fvalid, stable=True)[:out_cap]
-        return (flat(r_pid)[keep_first], flat(r_pk)[keep_first],
-                flat(r_val)[keep_first], fvalid[keep_first])
+        def exchange(x):
+            return jax.lax.all_to_all(x, SHARD_AXIS, 0, 0, tiled=True)
+
+        r_counts = exchange(counts)
+        received = [exchange(b) for b in packed]
+
+        with jax.named_scope("exchange_compact"):
+            offs = jnp.cumsum(r_counts, dtype=jnp.int32) - r_counts
+
+            def compact(r_col, fill):
+                # Bucket d's invalid tail is overwritten by bucket d+1's
+                # head; the last tail is fill like the buffer's own rows.
+                out = jnp.full((length + cap_send,), fill, r_col.dtype)
+                for d in range(n_shards):
+                    out = jax.lax.dynamic_update_slice_in_dim(
+                        out, r_col[d], offs[d], axis=0)
+                return out[:length]
+
+            o_pid, o_pk, *o_cols = [
+                compact(c, f) for c, f in zip(received, fills)
+            ]
+            if values_s.ndim == 1:
+                o_val = o_cols[0]
+            elif o_cols:
+                o_val = jnp.stack(o_cols, axis=1)
+            else:
+                o_val = jnp.zeros((length, 0), values_s.dtype)
+            o_valid = (jnp.arange(length, dtype=jnp.int32) <
+                       r_counts.sum())
+            return o_pid, o_pk, o_val, o_valid
 
     fn = shard_map(per_shard, mesh=mesh, in_specs=(P(SHARD_AXIS),) * 4,
                    out_specs=(P(SHARD_AXIS),) * 4)

@@ -12,8 +12,11 @@ dense staging: pad, upload, its slab count) — and each counter must be declare
 in telemetry.REGISTRY and, where those runs can reach it, counted by
 them. A fourth tiny run, a dense job with two PERCENTILEs over more
 partitions than one histogram chunk holds (the lazy descent), counts the
-quantile trees and their row passes. A rename in the program then fails
-here instead of leaving a null in the ledger.
+quantile trees and their row passes. A fifth, the dense ChunkSource job
+again on a four-device mesh, once sound (the collective reshard) and once
+with the `collective` fault rt_faults has (the host fallback), records
+the mesh layer's two spans. A rename in the program then fails here
+instead of leaving a null in the ledger.
 """
 
 import json
@@ -46,7 +49,7 @@ def _listed_specs():
 SPECS = _listed_specs()
 
 
-def _dense_chunk_run():
+def _dense_chunk_run(mesh=None):
     rng = np.random.default_rng(0)
     n = 8000
     pid, pk = rng.integers(0, 900, n), rng.integers(0, 300, n)
@@ -64,11 +67,34 @@ def _dense_chunk_run():
                             partition_extractor=lambda r: r[1],
                             value_extractor=lambda r: r[2])
     acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0, total_delta=1e-6)
-    engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=1))
+    engine = pdp.DPEngine(acc, pdp.TPUBackend(mesh=mesh, noise_seed=1))
     result = engine.aggregate(pdp.ChunkSource(chunks, encode_mode="host"),
                               params, ex)
     acc.compute_budgets()
     assert dict(result)
+
+
+def _meshed_chunk_runs():
+    """The dense chunked job on four of the suite's virtual CPU devices
+    (perfbench/tests/test_mesh_metric_sources.py's meshed_job): its rows
+    are device-resident, so the sound job takes the collective reshard
+    (span reshard.collective) and the one whose collective is made to
+    fail degrades to the host permutation (span reshard.host, counter
+    reshard_host_fallbacks)."""
+    import jax
+    from pipelinedp_tpu.parallel import make_mesh
+    from pipelinedp_tpu.runtime import faults
+
+    mesh = make_mesh(devices=jax.devices()[:4])
+    assert mesh.devices.size == 4
+    before = telemetry.snapshot()
+    _dense_chunk_run(mesh)
+    assert "reshard.collective" in trace.trace_summary()["spans"]
+    assert "reshard.host" not in trace.trace_summary()["spans"]
+    with faults.inject(faults.FaultSchedule([faults.Fault("collective")])):
+        _dense_chunk_run(mesh)
+    assert "reshard.host" in trace.trace_summary()["spans"]
+    assert telemetry.delta(before)["reshard_host_fallbacks"] == 1
 
 
 def _dense_encoded_run():
@@ -183,6 +209,7 @@ def recorded():
         _dense_percentile_run()
         _blocked_run(row_chunk=1000)
         _blocked_run(row_chunk=None)
+        _meshed_chunk_runs()
         counters = {name for name, n in telemetry.snapshot().items() if n}
         return set(trace.trace_summary()["spans"]) | counters
     finally:
