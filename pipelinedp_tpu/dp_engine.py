@@ -225,7 +225,9 @@ class DPEngine:
         self._check_select_private_partitions(col, params, data_extractors)
         self._check_budget_accountant_compatibility(False, [], False)
 
-        with self._budget_accountant.scope(weight=params.budget_weight):
+        from pipelinedp_tpu.runtime import trace as rt_trace
+        with self._budget_accountant.scope(weight=params.budget_weight), \
+                rt_trace.span("graph_build"):
             self._report_generators.append(
                 report_generator.ReportGenerator(params, "select_partitions"))
             if isinstance(self._backend, pipeline_backend.TPUBackend):

@@ -1170,16 +1170,17 @@ def _select_kept_pairs(pid, pk, valid, key: jax.Array, l0: int,
     """
     i32 = jnp.int32
     P = n_partitions
-    pid_sent = jnp.where(valid, pid, jnp.iinfo(i32).max).astype(i32)
-    pk_sent = jnp.where(valid, pk, P).astype(i32)
-    hp0, hp1 = _pair_hash(pid_sent, pk_sent, key)
-    (spid, _, _, spk), pay = _sort_rows([pid_sent, hp0, hp1, pk_sent],
-                                        [valid])
-    svalid = pay[0]
-    new_pair = segment_ops.boundary_mask(spid, spk)
-    new_pid = segment_ops.boundary_mask(spid)
-    pair_rank = segment_ops.segment_rank_of_segments(new_pair, new_pid)
-    kept_pair = new_pair & svalid & (pair_rank < l0)
+    with jax.named_scope("select_pairs"):
+        pid_sent = jnp.where(valid, pid, jnp.iinfo(i32).max).astype(i32)
+        pk_sent = jnp.where(valid, pk, P).astype(i32)
+        hp0, hp1 = _pair_hash(pid_sent, pk_sent, key)
+        (spid, _, _, spk), pay = _sort_rows([pid_sent, hp0, hp1, pk_sent],
+                                            [valid])
+        svalid = pay[0]
+        new_pair = segment_ops.boundary_mask(spid, spk)
+        new_pid = segment_ops.boundary_mask(spid)
+        pair_rank = segment_ops.segment_rank_of_segments(new_pair, new_pid)
+        kept_pair = new_pair & svalid & (pair_rank < l0)
     return spk, kept_pair
 
 
@@ -1193,15 +1194,19 @@ def select_kept_pair_stream(pid, pk, valid, rng_key, l0: int,
     int32-max sentinel and sink to the tail). The resulting
     partition-ascending stream is what the blocked selection path
     (parallel/large_p.select_partitions_blocked) bins into partition
-    blocks — dense [P] state never exists on any device.
+    blocks — dense [P] state never exists on any device. How many pairs
+    survived is not an output: the blocked drivers read it from the block
+    offsets they fetch anyway (the sentinel sorts above every partition
+    id), and a count left on the device would be a second source of it.
 
-    Returns (spk_sorted int32[n], n_kept int32[]).
+    Returns spk_sorted int32[n].
     """
     spk, kept_pair = _select_kept_pairs(pid, pk, valid, rng_key, l0,
                                         n_partitions)
-    sort_key = jnp.where(kept_pair, spk, jnp.iinfo(jnp.int32).max)
-    (spk_sorted,), _ = _sort_rows([sort_key], [])
-    return spk_sorted, kept_pair.sum()
+    with jax.named_scope("select_compact"):
+        sort_key = jnp.where(kept_pair, spk, jnp.iinfo(jnp.int32).max)
+        (spk_sorted,), _ = _sort_rows([sort_key], [])
+    return spk_sorted
 
 
 select_kept_pair_stream = rt_aot.aot_probe(
@@ -1538,7 +1543,7 @@ def lazy_select_partitions(backend, col, params, data_extractors,
         f"{pre_threshold_str})")
     rows = col
 
-    def generator():
+    def materialise():
         encoded = _encode_input(backend, rows, data_extractors)
         selection = selection_ops.selection_params_from_host(
             strategy, budget.eps, budget.delta,
@@ -1572,12 +1577,13 @@ def lazy_select_partitions(backend, col, params, data_extractors,
                         selection, **runtime_kwargs)
             vocab = encoded.partition_vocab
             n_real = len(vocab)
-            if hasattr(vocab, "prefetch"):
-                vocab.prefetch(idx for idx in kept_ids if idx < n_real)
-            for idx in kept_ids:
-                if idx < n_real:
-                    # staticcheck: disable=release-taint — sanctioned release: partition keys are decoded ONLY at indices the DP selection kernel kept (noise + threshold); the selection mechanism registered with the ledger is the sanitizer
-                    yield vocab[idx]
+            with rt_trace.span("post_process"):
+                if hasattr(vocab, "prefetch"):
+                    vocab.prefetch(idx for idx in kept_ids if idx < n_real)
+                for idx in kept_ids:
+                    if idx < n_real:
+                        # staticcheck: disable=release-taint — sanctioned release: partition keys are decoded ONLY at indices the DP selection kernel kept (noise + threshold); the selection mechanism registered with the ledger is the sanitizer
+                        yield vocab[idx]
             return
         interceptor = _active_launch_interceptor()
         if backend.mesh is not None:
@@ -1642,6 +1648,14 @@ def lazy_select_partitions(backend, col, params, data_extractors,
                 if idx < n_real:
                     # staticcheck: disable=release-taint — sanctioned release: partition keys are decoded ONLY at indices the DP selection kernel kept (noise + threshold); the selection mechanism registered with the ledger is the sanitizer
                     yield vocab[idx]
+
+    def generator():
+        # The root span of one materialised selection, as lazy_aggregate's
+        # `aggregate` is of an aggregation: its `agg` sequence number is
+        # the request identifier every span of the job inherits, and it
+        # is open across yields, from the first pull to exhaustion.
+        with rt_trace.span("select_partitions", agg=rt_trace.next_agg()):
+            yield from materialise()
 
     return generator()
 

@@ -105,8 +105,41 @@ def keep_probabilities(counts: jnp.ndarray,
     return jnp.where(n <= 0, 0.0, probs)
 
 
+# A float32 uniform draw is k * 2^-23 with k uniform over [0, 2^23).
+_F32_UNIFORM_CELLS = float(1 << 23)
+
+
+def keep_from_uniforms(u: jnp.ndarray, tie: jnp.ndarray,
+                       probs: jnp.ndarray) -> jnp.ndarray:
+    """Bernoulli(probs) from two float32 uniform draws.
+
+    A float32 uniform takes only the 2^23 values k * 2^-23, so `u < p`
+    keeps with probability ceil(p * 2^23) / 2^23: a probability in the
+    delta tail is rounded UP to the next multiple of 1.19e-7 (2.5e-7, what
+    truncated-geometric selection gives a one-user partition at delta =
+    1e-6 and l0 = 4, becomes 3.58e-7: measured on the chip over the AOL
+    log's ten million near-singleton queries, PERF.md section 6, PR 37) —
+    a delta the budget does not hold. So the one cell of width 2^-23 that
+    contains p is split by `tie`: below the cell keep, above it drop,
+    inside it keep with probability frac(p * 2^23). Every decision `u < p`
+    made stays as it was but a share 2^-23 of them, and P(keep) is p to
+    within 2^-46.
+    """
+    scaled = probs * _F32_UNIFORM_CELLS  # exact: a power of two
+    cell = jnp.floor(scaled)
+    k = u * _F32_UNIFORM_CELLS  # the integer k, exactly
+    return (k < cell) | ((k == cell) & (tie < scaled - cell))
+
+
 def sample_keep_decisions(key: jax.Array, counts: jnp.ndarray,
                           params: SelectionParams) -> jnp.ndarray:
-    """Bernoulli keep decision per partition."""
+    """Bernoulli keep decision per partition. A float64 draw (x64 on)
+    resolves any keep probability a budget gives; a float32 one (the
+    chip) takes a second draw for the cell that holds it
+    (keep_from_uniforms)."""
     probs = keep_probabilities(counts, params)
-    return jax.random.uniform(key, counts.shape) < probs
+    u = jax.random.uniform(key, counts.shape)
+    if u.dtype != jnp.float32:
+        return u < probs
+    tie = jax.random.uniform(jax.random.fold_in(key, 1), counts.shape)
+    return keep_from_uniforms(u, tie, probs)

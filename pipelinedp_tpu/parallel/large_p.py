@@ -1135,18 +1135,19 @@ def _selection_block_trace(spk_kept, lo, length, base, c_actual, key,
     applied to standalone selection.
     """
     from pipelinedp_tpu.ops import selection_ops
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    valid = idx < length
-    rel = jnp.where(valid,
-                    jnp.take(spk_kept, lo + idx, mode="clip") - base,
-                    c_actual).astype(jnp.int32)
-    counts = jnp.zeros((c_actual + 1,), jnp.int32).at[rel].add(
-        valid.astype(jnp.int32))[:c_actual]
-    if psum_axis is not None:
-        counts = jax.lax.psum(counts, psum_axis)
-    keep = selection_ops.sample_keep_decisions(key, counts, selection)
-    order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-    return keep.sum(), order
+    with jax.named_scope("selection_block"):
+        idx = jnp.arange(cap, dtype=jnp.int32)
+        valid = idx < length
+        rel = jnp.where(valid,
+                        jnp.take(spk_kept, lo + idx, mode="clip") - base,
+                        c_actual).astype(jnp.int32)
+        counts = jnp.zeros((c_actual + 1,), jnp.int32).at[rel].add(
+            valid.astype(jnp.int32))[:c_actual]
+        if psum_axis is not None:
+            counts = jax.lax.psum(counts, psum_axis)
+        keep = selection_ops.sample_keep_decisions(key, counts, selection)
+        order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
+        return keep.sum(), order
 
 
 @functools.partial(jax.jit,
@@ -1180,7 +1181,7 @@ def _sharded_select_compact(pid, pk, valid, rows_key, boundaries, l0: int,
     def per_shard(pid_s, pk_s, valid_s, key_r, boundaries_r):
         shard_idx = jax.lax.axis_index(SHARD_AXIS)
         key_s = jax.random.fold_in(key_r, shard_idx)
-        spk_sorted, _ = executor.select_kept_pair_stream(
+        spk_sorted = executor.select_kept_pair_stream(
             pid_s, pk_s, valid_s, key_s, l0, n_partitions)
         starts = jnp.searchsorted(spk_sorted, boundaries_r,
                                   side="left").astype(jnp.int32)
@@ -1392,19 +1393,40 @@ def select_partitions_blocked(pid,
     pass 2 bins it into partition blocks and transfers only each block's
     kept ids — O(rows + kept) host traffic at any P.
 
+    Where the wall time goes is read from rt_trace: contribution_bounding
+    (the host's pad to the row capacity, then p1.upload: host columns
+    going up, blocking until they are there, counted in h2d_bytes, then
+    pass 1's launch), block_offsets (the host's first wait for the
+    device: pass 1's two sorts and the search), and per block dispatch /
+    drain (release_wait inside) / consume; the staged ids come down in
+    the last drain. Two counters say what pass 2 worked on:
+    selection_pairs, the pairs that survived dedupe and l0 — its ONE
+    source is the last block offset (every surviving pair sorts below
+    it, every dropped row's sentinel above), which the host holds
+    anyway — and selection_block_rows, the row_cap rows each dispatched
+    block program gathers and scatters, however few of them are pairs.
+
     Returns kept_partition_ids int64[M], ascending.
     """
     P = n_partitions
     key_l0, key_sel = jax.random.split(rng_key)
-    if not isinstance(pid, jax.Array):
+    on_host = not isinstance(pid, jax.Array)
+    if on_host:
         pid, pk, valid = np.asarray(pid), np.asarray(pk), np.asarray(valid)
     cap = round_capacity(len(pid))
     t_p1 = time.perf_counter()
-    with rt_trace.span("contribution_bounding"):
-        spk_sorted, _ = executor.select_kept_pair_stream(
-            jnp.asarray(_pad_to(pid, cap, 0)),
-            jnp.asarray(_pad_to(pk, cap, 0)),
-            jnp.asarray(_pad_to(valid, cap, False)), key_l0, l0, P)
+    with rt_trace.span("contribution_bounding", rows=len(pid)):
+        rows_in = (_pad_to(pid, cap, 0), _pad_to(pk, cap, 0),
+                   _pad_to(valid, cap, False))
+        if on_host:
+            nbytes = _nbytes(*rows_in)
+            rt_telemetry.record("h2d_bytes", nbytes)
+            with rt_trace.span("p1.upload", bytes=nbytes):
+                rows_in = jax.block_until_ready(
+                    tuple(jnp.asarray(a) for a in rows_in))
+        spk_sorted = executor.select_kept_pair_stream(*rows_in, key_l0, l0,
+                                                      P)
+        del rows_in  # pass 2 reads the compacted stream only
     _seed_pass1(time.perf_counter() - t_p1)
 
     C0 = min(block_partitions, P)
@@ -1415,11 +1437,15 @@ def select_partitions_blocked(pid,
 
     def run_range(base, C, gen, end):
         n_blocks = -(-(end - base) // C)
-        block_starts = host_fetch(
-            jnp.searchsorted(spk_sorted,
-                             jnp.asarray(_block_boundaries(base, C,
-                                                           n_blocks)),
-                             side="left"))
+        # The fetch waits for pass 1 (dispatched async) and the search.
+        with rt_trace.span("block_offsets", blocks=n_blocks):
+            block_starts = host_fetch(
+                jnp.searchsorted(spk_sorted,
+                                 jnp.asarray(_block_boundaries(base, C,
+                                                               n_blocks)),
+                                 side="left"))
+        if end == P:  # the plan's last range: its last boundary is >= P
+            rt_telemetry.record("selection_pairs", int(block_starts[-1]))
         row_cap = _range_row_cap(block_starts)
 
         def consume(j, result):
@@ -1467,12 +1493,17 @@ def select_partitions_blocked(pid,
                     b_base, c_actual, _block_noise_key(key_sel, gen, j),
                     selection, row_cap))
 
-        _dispatch_blocks(block_iter(), consume, retry_policy=retry,
-                         overlap=overlap)
+        dispatched = _dispatch_blocks(block_iter(), consume,
+                                      retry_policy=retry, overlap=overlap)
+        if dispatched:
+            rt_telemetry.record("selection_block_rows",
+                                dispatched * row_cap)
 
     rt_retry.run_with_degradation(run_range, P, C0, journal=journal,
                                   job_id=job)
-    drain.materialize()
+    with rt_trace.span("drain"):
+        drain.materialize()
+    rt_telemetry.record("release_dispatches")  # the drain the ids took
 
     if not kept_ids:
         return np.zeros(0, np.int64)

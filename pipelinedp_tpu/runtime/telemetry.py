@@ -175,6 +175,19 @@ REGISTRY: Dict[str, Metric] = {
                  "partitions a materialised aggregation with percentiles "
                  "built quantile trees for (the launch's n_partitions), "
                  "added once per such aggregation"),
+        _counter("selection_pairs",
+                 "(privacy id, partition) pairs that survived dedupe and "
+                 "l0 bounding in a blocked standalone selection "
+                 "(large_p.select_partitions_blocked), read once a job "
+                 "from the last block offset the host fetches anyway: "
+                 "what pass 2's blocks have to count"),
+        _counter("selection_block_rows",
+                 "rows the selection block programs of a blocked "
+                 "standalone selection gathered and scattered: the "
+                 "range's shared row capacity (large_p._range_row_cap, "
+                 "the largest block's pairs rounded up) times the block "
+                 "programs dispatched. Beside selection_pairs it says "
+                 "what the shared capacity costs on skewed keys"),
         _counter("aot_cache_hits",
                  "warm-path dispatches served by an ahead-of-time "
                  "compiled executable from the process-wide "

@@ -227,6 +227,84 @@ def test_blocked_block_kernel_compiles(chip):
     assert " sort(" in compiled.as_text()
 
 
+def _selection(l0=4):
+    """The whole (1, 1e-6) on the selection, as keys-1e7-select's job."""
+    from pipelinedp_tpu.aggregate_params import PartitionSelectionStrategy
+    from pipelinedp_tpu.ops import selection_ops
+
+    return selection_ops.selection_params_from_host(
+        PartitionSelectionStrategy.TRUNCATED_GEOMETRIC, 1.0, 1e-6, l0, None)
+
+
+SELECTION_SCOPES = ("select_pairs", "select_compact", "selection_block")
+
+
+def test_selection_programs_compile_under_their_scopes(chip):
+    """The blocked selection's two programs (perfbench/configs/
+    keys-1e7-select.json: P = 10,154,742, l0 = 4, C = 2^20; rows and the
+    block's gather capacity reduced): pass 1's two sorts carry
+    `select_pairs` and `select_compact`, the block program's gather,
+    scatter and sort `selection_block`, so a profiler trace attributes the
+    device time to the three stages by `tf_op`."""
+    keys = 10_154_742
+    stream = _x32(lambda: executor.select_kept_pair_stream.lower(
+        chip((ROWS,), I32), chip((ROWS,), I32), chip((ROWS,), np.bool_),
+        chip((2,), U32), 4, keys)).compile()
+    _fits(stream)
+    sorts = [line for line in stream.as_text().splitlines()
+             if " sort(" in line]
+    assert len(sorts) == 2, len(sorts)
+    assert "select_pairs" in sorts[0] and "select_compact" in sorts[1], sorts
+    block = _x32(lambda: large_p._selection_block_kernel.lower(
+        chip((ROWS,), I32), chip((), I32), chip((), I32), chip((), I32),
+        BLOCK, chip((2,), U32), _selection(),
+        mesh_lib.round_capacity(ROWS))).compile()
+    _fits(block)
+    text = block.as_text()
+    for op in (" sort(", " scatter(", " gather("):
+        lines = [line for line in text.splitlines() if op in line]
+        assert lines and all("selection_block" in line for line in lines), op
+    assert " f64[" not in text
+
+
+def test_no_aggregation_program_carries_a_selection_scope(chip):
+    """`_select_kept_pairs` is shared with the dense selection body and
+    with nothing an aggregation runs: the dense release, blocked pass 1
+    and the blocked block program lower without the three scopes (their
+    lowered text with debug locations; nothing is compiled here), the
+    dense selection with `select_pairs` alone."""
+
+    def lowered():
+        _, cfg, stds, _ = _common.build_spec(MOVIES)
+        scalars = [chip((), F32)] * 5
+        tail = (chip((len(stds),), F32), chip((2,), U32))
+        dense = executor.aggregate_release_kernel.lower(
+            *_rows(chip, ROWS), *scalars, *tail, cfg)
+        pass1 = large_p._bounded_compact_kernel.lower(
+            *_rows(chip, ROWS), *scalars, chip((2,), U32), cfg)
+        scalar_i, scalar_f = chip((), I32), chip((), F32)
+        block = large_p._block_kernel_dev.lower(
+            chip((ROWS,), I32), chip((ROWS,), np.bool_),
+            {"sum": chip((ROWS,), F32)}, None, scalar_i, scalar_i,
+            scalar_i, scalar_f, scalar_f, scalar_f, *tail,
+            dataclasses.replace(cfg, n_partitions=BLOCK),
+            mesh_lib.round_capacity(ROWS))
+        select = executor.select_partitions_release_kernel.lower(
+            chip((ROWS,), I32), chip((ROWS,), I32), chip((ROWS,), np.bool_),
+            chip((2,), U32), 4, MOVIES, _selection())
+        return dense, pass1, block, select
+
+    *aggregations, select = _x32(lowered)
+    own = ("bound_sort", "p1_bound_compact", "block_finalize")
+    for program, scope in zip(aggregations, own):
+        text = program.as_text(debug_info=True)
+        assert f")/{scope}" in text  # this text shows scopes: its own
+        assert not any(scope in text for scope in SELECTION_SCOPES)
+    text = select.as_text(debug_info=True)
+    assert "select_pairs" in text
+    assert "select_compact" not in text and "selection_block" not in text
+
+
 def test_donated_append_and_grow_compile(chip):
     """The streaming accumulator's pair at real 2^24-row buffers. The
     append really donates on the chip (on CPU it is a warned no-op, so

@@ -15,8 +15,10 @@ partitions than one histogram chunk holds (the lazy descent), counts the
 quantile trees and their row passes. A fifth, the dense ChunkSource job
 again on a four-device mesh, once sound (the collective reshard) and once
 with the `collective` fault rt_faults has (the host fallback), records
-the mesh layer's two spans. A rename in the program then fails here
-instead of leaving a null in the ledger.
+the mesh layer's two spans. A sixth, a blocked standalone selection
+through `DPEngine.select_partitions`, records the spans and counters the
+cell keys1e7-select-blocked reads. A rename in the program then fails
+here instead of leaving a null in the ledger.
 """
 
 import json
@@ -193,6 +195,44 @@ def _blocked_run(row_chunk):
         row_chunk=row_chunk)
 
 
+def _blocked_select_run():
+    """`DPEngine.select_partitions` over 2,048 pre-encoded keys with a
+    threshold of 512: the blocked selection route in four blocks of 512.
+    What a job of the cell keys1e7-select-blocked records, it records."""
+    from pipelinedp_tpu import columnar
+
+    rng = np.random.default_rng(6)
+    P, n, users = 1 << 11, 6000, 300
+    encoded = columnar.EncodedData(
+        pid=rng.integers(0, users, n).astype(np.int32),
+        pk=(P * rng.random(n)**3).astype(np.int32),
+        values=np.zeros(n, np.float32), partition_vocab=range(P),
+        n_privacy_ids=users)
+    acc = pdp.NaiveBudgetAccountant(total_epsilon=20.0, total_delta=1e-3)
+    engine = pdp.DPEngine(acc, pdp.TPUBackend(
+        noise_seed=7, large_partition_threshold=1 << 9,
+        block_partitions=1 << 9))
+    spans_before = {name: row["count"] for name, row in
+                    trace.trace_summary()["spans"].items()}
+    before = telemetry.snapshot()
+    kept = engine.select_partitions(
+        encoded, pdp.SelectPartitionsParams(max_partitions_contributed=4),
+        pdp.DataExtractors())
+    acc.compute_budgets()
+    assert list(kept)
+    opened = {name for name, row in trace.trace_summary()["spans"].items()
+              if row["count"] > spans_before.get(name, 0)}
+    assert {"graph_build", "select_partitions", "contribution_bounding",
+            "p1.upload", "block_offsets", "dispatch", "release_wait",
+            "drain", "consume", "post_process"} <= opened, sorted(opened)
+    counted = telemetry.delta(before)
+    assert 0 < counted["selection_pairs"] <= 4 * users
+    assert counted["selection_block_rows"] >= counted["selection_pairs"]
+    assert counted["h2d_bytes"] >= n * 9  # pid, pk, valid of every row
+    assert counted["d2h_bytes"] > 0
+    assert counted["release_dispatches"] == 4 + 1  # four blocks, one drain
+
+
 # Counters no tiny run is sure to move: a program already built in this
 # process fires no compile event.
 UNREACHED_COUNTERS = {"backend_compiles"}
@@ -210,6 +250,7 @@ def recorded():
         _blocked_run(row_chunk=1000)
         _blocked_run(row_chunk=None)
         _meshed_chunk_runs()
+        _blocked_select_run()
         counters = {name for name, n in telemetry.snapshot().items() if n}
         return set(trace.trace_summary()["spans"]) | counters
     finally:
