@@ -714,11 +714,16 @@ def _descend_trees(children_of, n_trees: int, min_v, max_v,
     enforcement of compute_quantiles, unrolled over the static tree height.
     THE single copy of the descent arithmetic: the dense path supplies
     ``children_of`` as precomputed-histogram gathers, the lazy path as
-    on-demand segment sums — so the two executions cannot drift.
+    on-demand searches of the sorted rows — so the two executions cannot
+    drift.
 
-    children_of(level, parent) -> non-negative noisy counts [n_trees, B] of
-    each tree's ``parent`` node's children at ``level`` (parents live at
-    level-1; the root is node 0 at level 0).
+    children_of(level, parent, within) -> (counts, edges): the non-negative
+    noisy counts [n_trees, B] of each tree's ``parent`` node's children at
+    ``level`` (parents live at level-1; the root is node 0 at level 0),
+    and whatever the supplier wants back at the next level. ``within`` is
+    None at the root and (the last call's edges, the child chosen there)
+    below it: the lazy path carries each child's range of rows in it, the
+    dense path nothing.
     """
     B, h = cfg.branching, cfg.tree_height
     L = B**h
@@ -728,7 +733,7 @@ def _descend_trees(children_of, n_trees: int, min_v, max_v,
     results = []
     for q in cfg.quantiles:
         node = jnp.zeros(n_trees, dtype=jnp.int32)
-        children = children_of(1, node)
+        children, edges = children_of(1, node, None)
         total = children.sum(axis=-1)
         target = q * total
         for level in range(1, h + 1):
@@ -745,7 +750,7 @@ def _descend_trees(children_of, n_trees: int, min_v, max_v,
             target = target - before
             node = node * B + child  # node == 0 at level 1
             if level < h:
-                nxt = children_of(level + 1, node)
+                nxt, edges = children_of(level + 1, node, (edges, child))
                 child_mass = jnp.take_along_axis(children, child[:, None],
                                                  axis=1)[:, 0]
                 target = target / jnp.maximum(child_mass,
@@ -775,10 +780,12 @@ def _descend_quantiles(noisy_levels, min_v, max_v, cfg: KernelConfig):
     C = noisy_levels[0].shape[0]
     arange_b = jnp.arange(B, dtype=jnp.int32)
 
-    def children_of(level, parent):
+    def children_of(level, parent, within):
+        del within  # the histogram holds every node: nothing to carry
         idxs = parent[:, None] * B + arange_b
         return jnp.maximum(
-            jnp.take_along_axis(noisy_levels[level - 1], idxs, axis=1), 0.0)
+            jnp.take_along_axis(noisy_levels[level - 1], idxs, axis=1),
+            0.0), None
 
     return _descend_trees(children_of, C, min_v, max_v, cfg)
 
@@ -821,23 +828,61 @@ def _noisy_node_counts(counts: jnp.ndarray, keys: jax.Array, std,
     return counts.astype(f) + draws.astype(f) * scale
 
 
+def _first_at_least(col: jnp.ndarray, lo, hi, bound):
+    """First position in [lo, hi) of the ascending int32 `col` whose element
+    is at least `bound`, hi where none is: one binary search per element of
+    `bound` (lo, hi broadcast against it), all in step, one gathered element
+    a search and step. The loop runs while any range is open, so its length
+    follows the longest range handed in, not len(col)."""
+    lo = jnp.broadcast_to(lo, bound.shape)
+    hi = jnp.broadcast_to(hi, bound.shape)
+
+    def halve(ranges):
+        lo, hi = ranges
+        mid = lo + (hi - lo) // 2
+        # A closed range may sit at len(col): clipped, and not moved.
+        below = jnp.take(col, mid, mode="clip") < bound
+        is_open = lo < hi
+        return (jnp.where(is_open & below, mid + 1, lo),
+                jnp.where(is_open & ~below, mid, hi))
+
+    lo, _ = jax.lax.while_loop(lambda ranges: jnp.any(ranges[0] < ranges[1]),
+                               halve, (lo, hi))
+    return lo
+
+
 def _lazy_quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
                            cfg: KernelConfig,
                            psum_axis: Optional[str] = None,
                            secure_tables=None):
-    """Per-partition DP quantiles by lazy root-to-leaf descent.
+    """Per-partition DP quantiles by lazy root-to-leaf descent over rows
+    sorted ONCE by (partition, leaf).
 
-    Instead of materializing (and rescanning rows for) every chunk of the
-    dense [P, leaves] histogram, each descent level segment-sums the rows
-    into only the B children of every partition's CURRENT node ([P, B]
-    memory), noising them with per-node deterministic noise
-    (_node_noise_keys) — the released values are identical in distribution
-    to noising the whole tree and reading the descent path. Total work is
-    O(n_quantiles * height * n_rows + P * B) regardless of P, replacing the
-    chunked path's O(n_rows * ceil(P / quantile_chunk)).
+    Instead of materializing every chunk of the dense [P, leaves]
+    histogram, each descent level counts only the B children of every
+    partition's CURRENT node ([P, B] memory) and noises them with per-node
+    deterministic noise (_node_noise_keys) — the released values are
+    identical in distribution to noising the whole tree and reading the
+    descent path.
+
+    The sort is the one pass over the rows (rows that are not kept go to the
+    sentinel partition P, behind every real run). Floor division is
+    monotone, so that order is also sorted by (partition, node at level l)
+    for every l: the rows under a partition's current node are a contiguous
+    range, the whole run at the root, and its B children's counts are the
+    differences of B + 1 positions in it — the range's two ends and B - 1
+    searches between them (_first_at_least). The chosen child's range is
+    carried to the next level beside the node (_descend_trees' `within`).
+    Work is one sort of the rows plus O(n_quantiles * height * P * B)
+    searches, each as long as the log of the longest range.
+
+    Under psum_axis every shard sorts and searches its own rows; the [P, B]
+    counts are psum'd before the noise, whose key is replicated, so every
+    shard chooses the same child and narrows its own range to it.
     """
     row_pk, row_leaf, row_keep = qrows
     B, h = cfg.branching, cfg.tree_height
+    L = B**h
     P = cfg.n_partitions
     f = _ftype()
     i32 = jnp.int32
@@ -850,29 +895,46 @@ def _lazy_quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
     arange_b = jnp.arange(B, dtype=i32)
     partition_ids = jnp.arange(P, dtype=i32)
 
-    def noisy_children(level, parent):
+    part = jnp.where(row_keep & (row_pk < P), row_pk, P).astype(i32)
+    leaf = row_leaf.astype(i32)
+    run_ends = jnp.arange(P + 1, dtype=i32)
+    if (P + 1) * L <= 2**31:
+        # Every (partition, leaf), the sentinel's too, fits ONE int32 key
+        # (a shape, never an option): one sort operand, and a search
+        # compares the same column against the partition's offset + leaf.
+        col = jax.lax.sort(part * L + leaf)
+        offset = partition_ids * L
+        starts = jnp.searchsorted(col, run_ends * L, side="left")
+    else:
+        part_sorted, col = jax.lax.sort((part, leaf), num_keys=2)
+        offset = jnp.zeros(P, dtype=i32)
+        starts = jnp.searchsorted(part_sorted, run_ends, side="left")
+    starts = starts.astype(i32)
+
+    def noisy_children(level, parent, within):
         """Noisy counts of each partition's `parent` node's B children at
-        `level` (levels 1..h; parent ids live at level-1)."""
-        shift = B**(h - level)
-        row_node = (row_leaf // shift).astype(i32)
-        par = parent[jnp.minimum(row_pk, P - 1)]
-        in_path = row_keep & (row_node // B == par) & (row_pk < P)
-        seg = jnp.where(in_path, row_pk * B + (row_node % B), P * B)
-        counts = jax.ops.segment_sum(in_path.astype(i32), seg,
-                                     num_segments=P * B + 1)[:P * B].reshape(
-                                         P, B)
+        `level` (levels 1..h; parent ids live at level-1), and the B + 1
+        row positions that bound them."""
+        if within is None:
+            lo, hi = starts[:-1], starts[1:]
+        else:
+            edges, child = within
+            lo = jnp.take_along_axis(edges, child[:, None], axis=1)[:, 0]
+            hi = jnp.take_along_axis(edges, child[:, None] + 1, axis=1)[:, 0]
+        node_ids = parent[:, None] * B + arange_b  # the children, level l
+        first_leaf = offset[:, None] + node_ids[:, 1:] * B**(h - level)
+        inner = _first_at_least(col, lo[:, None], hi[:, None], first_leaf)
+        edges = jnp.concatenate([lo[:, None], inner, hi[:, None]], axis=1)
+        counts = edges[:, 1:] - edges[:, :-1]
         if psum_axis is not None:
             counts = jax.lax.psum(counts, psum_axis)
-        node_ids = parent * B  # level-l ids of child 0
-        node_ids = node_ids[:, None] + arange_b
         keys = _node_noise_keys(jax.random.fold_in(key, level), node_ids,
                                 partition_ids)
-        return _noisy_node_counts(counts, keys, std, cfg, secure_tables,
-                                  qidx)
+        noisy = _noisy_node_counts(counts, keys, std, cfg, secure_tables,
+                                   qidx)
+        return jnp.maximum(noisy, 0.0), edges
 
-    per_partition = _descend_trees(
-        lambda level, parent: jnp.maximum(noisy_children(level, parent), 0.0),
-        P, min_v, max_v, cfg)
+    per_partition = _descend_trees(noisy_children, P, min_v, max_v, cfg)
     return {
         name: per_partition[:, j].astype(f)
         for j, name in enumerate(plan_names)
@@ -887,16 +949,26 @@ def _lazy_quantiles(cfg: KernelConfig) -> bool:
 
 def quantile_row_passes(cfg: KernelConfig) -> int:
     """Passes over the bounded row stream that quantile_outputs takes in
-    one launch, from the static config alone: one scatter-add per quantile
-    and tree level on the lazy path (every descent step re-reads the rows),
-    one for the whole histogram on the one-chunk dense path, none without
-    percentiles. The telemetry counter `quantile_row_passes` has no other
-    source, and shares _lazy_quantiles with the dispatch below."""
-    if not cfg.quantiles:
+    one launch, from the static config alone: the one sort by (partition,
+    leaf) on the lazy path (the descent then searches the sorted rows and
+    passes over them no more), the one scatter-add of the whole histogram
+    on the one-chunk dense path, none without percentiles. The telemetry
+    counter `quantile_row_passes` has no other source."""
+    return 1 if cfg.quantiles else 0
+
+
+def quantile_node_searches(cfg: KernelConfig) -> int:
+    """Boundary positions the lazy descent looks up in the sorted rows in
+    one launch, from the static config alone: B - 1 a partition, level and
+    quantile (what is left of the tree's random-access work scales with
+    it); none where one dense histogram chunk holds every partition's
+    leaves, none without percentiles. The telemetry counter
+    `quantile_node_searches` has no other source, and shares
+    _lazy_quantiles with the dispatch below."""
+    if not _lazy_quantiles(cfg):
         return 0
-    if _lazy_quantiles(cfg):
-        return len(cfg.quantiles) * cfg.tree_height
-    return 1
+    return (len(cfg.quantiles) * cfg.tree_height * cfg.n_partitions *
+            (cfg.branching - 1))
 
 
 def quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
@@ -914,8 +986,9 @@ def quantile_outputs(qrows, min_v, max_v, stds, key: jax.Array,
     Two regimes: when one chunk covers every partition (the default 65536-
     leaf tree covers 512 partitions per chunk) the dense histogram is built
     in a single pass. Larger partition spaces switch to the lazy descent
-    (_lazy_quantile_outputs): O(n_q * height) row passes total instead of
-    one per chunk, with [P, branching] peak memory.
+    (_lazy_quantile_outputs): one sort of the rows by (partition, leaf),
+    then searches of the sorted rows for the children of each partition's
+    current node, with [P, branching] peak memory.
     """
     if _lazy_quantiles(cfg):
         return _lazy_quantile_outputs(qrows, min_v, max_v, stds, key, cfg,
@@ -1900,10 +1973,14 @@ def lazy_aggregate(backend, col, params: AggregateParams, data_extractors,
                                  numeric_mode=numeric_mode)
         if cfg.quantiles:
             # Once per materialised aggregation with percentiles: the row
-            # passes its trees take (on the blocked route each block's
-            # program takes them over its own rows) and the trees built.
+            # passes and node searches its trees take (on the blocked route
+            # each block's program takes them over its own rows) and the
+            # trees built.
             rt_telemetry.record("quantile_row_passes",
                                 quantile_row_passes(cfg))
+            searches = quantile_node_searches(cfg)
+            if searches:  # none on the one-chunk histogram: not recorded
+                rt_telemetry.record("quantile_node_searches", searches)
             rt_telemetry.record("quantile_trees", n_partitions)
         stds = compute_noise_stds(compound, params)
         secure_tables = None

@@ -168,9 +168,18 @@ REGISTRY: Dict[str, Metric] = {
         _counter("quantile_row_passes",
                  "passes over the bounded row stream that the quantile "
                  "trees of one launch take (executor.quantile_row_passes, "
-                 "from the static config: quantiles x tree height on the "
-                 "lazy descent, 1 on the one-chunk dense histogram), added "
-                 "once per materialised aggregation that has percentiles"),
+                 "from the static config: 1 on either path — the lazy "
+                 "descent's one sort by (partition, leaf), the one-chunk "
+                 "dense histogram's one scatter-add), added once per "
+                 "materialised aggregation that has percentiles"),
+        _counter("quantile_node_searches",
+                 "boundary positions the lazy quantile descent looks up in "
+                 "the sorted rows in one launch "
+                 "(executor.quantile_node_searches, from the static config: "
+                 "quantiles x tree height x partitions x (branching - 1)), "
+                 "added once per materialised aggregation whose percentiles "
+                 "take the lazy descent; not recorded where the one-chunk "
+                 "dense histogram runs"),
         _counter("quantile_trees",
                  "partitions a materialised aggregation with percentiles "
                  "built quantile trees for (the launch's n_partitions), "

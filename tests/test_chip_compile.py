@@ -180,17 +180,23 @@ def test_dense_release_compiles_with_percentiles(chip):
     """The upstream movie-ratings job with its PERCENTILEs
     (perfbench/configs/netflix-percentiles.json): 17,770 quantile trees
     on the lazy descent, rows reduced to 2^12. The trees' ops carry the
-    `quantile_tree` scope a trace attributes them by; the two quantiles'
-    root-level passes have identical inputs, and the compiler merges them:
-    7 scatters for 2 quantiles x 4 levels. Full size, 2^24 rows, compiled
-    for the described v5e in this sandbox (PR 35): PERF.md section 4."""
+    `quantile_tree` scope a trace attributes them by: ONE sort of the rows
+    by (partition, leaf), the tree's one pass over them, then a search
+    loop per level and quantile whose gathers are [P, B - 1] wide — no
+    scatter, and no gather a row wide. The two quantiles' root-level
+    searches have identical inputs, and the compiler merges them: 7 loops
+    for 2 quantiles x 4 levels. Full size, 2^24 rows, compiled for the
+    described v5e in this sandbox (PR 38): PERF.md section 4."""
+    import re
+
     import pipelinedp_tpu as pdp
 
     M = pdp.Metrics
     _, cfg, stds, _ = _common.build_spec(
         MOVIES, metrics=[M.COUNT, M.SUM, M.PRIVACY_ID_COUNT,
                          M.PERCENTILE(50), M.PERCENTILE(90)], l0=2, linf=1)
-    assert executor.quantile_row_passes(cfg) == 8
+    assert executor.quantile_row_passes(cfg) == 1
+    assert executor.quantile_node_searches(cfg) == 2 * 4 * MOVIES * 15
 
     def lower():
         scalars = [chip((), F32)] * 5
@@ -201,9 +207,16 @@ def test_dense_release_compiles_with_percentiles(chip):
     compiled = _x32(lower).compile()
     _fits(compiled)
     text = compiled.as_text()
-    scatters = [line for line in text.splitlines() if " scatter(" in line]
-    assert len(scatters) == 7, len(scatters)
-    assert all("quantile_tree" in line for line in scatters), scatters
+    tree = [line for line in text.splitlines() if "quantile_tree" in line]
+    assert sum(" sort(" in line for line in tree) == 1
+    assert " scatter(" not in text
+    row_wide = re.compile(r"= \w+\[%d[,\]]" % ROWS)
+    gathers = [line for line in tree if " gather(" in line]
+    assert gathers  # the searches
+    assert not [line for line in gathers if row_wide.search(line)], gathers
+    loops = [line for line in tree
+             if " while(" in line and "searchsorted" not in line]
+    assert len(loops) == 7, len(loops)
     assert " f64[" not in text
 
 

@@ -129,8 +129,8 @@ def _dense_encoded_run():
 def _dense_percentile_run():
     """Two PERCENTILEs over 600 partitions, more than the 512 whose leaves
     one dense histogram chunk holds: the lazy descent, so
-    quantile_row_passes counts 2 quantiles x 4 levels and quantile_trees
-    the 600."""
+    quantile_row_passes counts its one sort, quantile_node_searches
+    2 quantiles x 4 levels x 600 x 15 and quantile_trees the 600."""
     from pipelinedp_tpu import columnar
 
     rng = np.random.default_rng(4)
@@ -154,7 +154,9 @@ def _dense_percentile_run():
     acc.compute_budgets()
     assert dict(result)
     counted = telemetry.delta(before)
-    assert counted["quantile_row_passes"] == 8
+    assert counted["quantile_row_passes"] == 1
+    assert counted["quantile_node_searches"] == (
+        2 * 4 * encoded.n_partitions * 15)
     assert counted["quantile_trees"] == encoded.n_partitions
 
 

@@ -79,9 +79,13 @@ def test_noise_free_percentiles_equal_the_reference(n_partitions, lazy):
     counted = telemetry.delta(before)
     keys, got = perfbench_run.release_arrays(release)
     assert len(keys) == n_partitions  # selection at this epsilon keeps all
-    # Which tree path that was: 2 quantiles x 4 levels of row passes on
-    # the lazy descent, one on the dense histogram.
-    assert counted["quantile_row_passes"] == (8 if lazy else 1)
+    # One pass over the rows on either tree path (the lazy descent's one
+    # sort, the dense histogram's one scatter-add); which path that was
+    # shows in the node searches, 2 quantiles x 4 levels x P x 15 on the
+    # lazy descent and not recorded on the histogram.
+    assert counted["quantile_row_passes"] == 1
+    assert counted.get("quantile_node_searches", 0) == (
+        2 * 4 * n_partitions * 15 if lazy else 0)
     assert counted["quantile_trees"] == n_partitions
 
     pairs = law.Pairs(pid, pk, values, g)
@@ -122,8 +126,9 @@ def test_the_laws_tree_walks_as_the_host_tree(seed):
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-9)
 
 
-def test_row_passes_follow_the_tree_path():
-    """executor.quantile_row_passes is the dispatch's own predicate."""
+def test_row_passes_and_node_searches_follow_the_tree_path():
+    """One row pass on either path; executor.quantile_node_searches is
+    the dispatch's own predicate."""
     params = pdp.AggregateParams(
         metrics=[pdp.Metrics.COUNT, pdp.Metrics.PERCENTILE(50),
                  pdp.Metrics.PERCENTILE(90)],
@@ -132,10 +137,14 @@ def test_row_passes_follow_the_tree_path():
     from pipelinedp_tpu import combiners
     compound = combiners.create_compound_combiner(
         params, pdp.NaiveBudgetAccountant(1.0, 1e-6))
-    passes = {p: executor.quantile_row_passes(executor.make_kernel_config(
-        params, compound, p, private_selection=False, selection_params=None))
+    cfgs = {p: executor.make_kernel_config(
+        params, compound, p, private_selection=False, selection_params=None)
         for p in (300, 512, 513, 17770)}
-    assert passes == {300: 1, 512: 1, 513: 8, 17770: 8}
+    assert {p: executor.quantile_row_passes(c) for p, c in cfgs.items()
+            } == {300: 1, 512: 1, 513: 1, 17770: 1}
+    assert {p: executor.quantile_node_searches(c) for p, c in cfgs.items()
+            } == {300: 0, 512: 0, 513: 2 * 4 * 513 * 15,
+                  17770: 2 * 4 * 17770 * 15}
 
 
 # ---------------------------------------------------------------------------

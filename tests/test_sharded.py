@@ -16,6 +16,23 @@ EXTRACTORS = pdp.DataExtractors(privacy_id_extractor=lambda r: r[0],
                                 value_extractor=lambda r: r[2])
 
 
+def _serve_the_scatter_form(monkeypatch):
+    """Puts the lazy quantile descent's reference, the scatter form served
+    until PR 38 (tests/test_executor_quantiles.py), in the served form's
+    place; the list returned fills as the reference is traced."""
+    from pipelinedp_tpu import executor
+    from tests.test_executor_quantiles import (
+        scatter_form_lazy_quantile_outputs)
+    traced = []
+
+    def reference_form(*args, **kwargs):
+        traced.append(True)
+        return scatter_form_lazy_quantile_outputs(*args, **kwargs)
+
+    monkeypatch.setattr(executor, "_lazy_quantile_outputs", reference_form)
+    return traced
+
+
 def _aggregate(backend, rows, params, public=None, eps=HUGE_EPS):
     accountant = pdp.NaiveBudgetAccountant(total_epsilon=eps,
                                            total_delta=1e-5)
@@ -237,6 +254,50 @@ class TestShardedEngineParity:
         for pk in public:
             assert 30.0 <= result[pk].percentile_50 <= 70.0
 
+    def test_lazy_descent_sharded_releases_what_the_scatter_form_did(
+            self, monkeypatch):
+        # The job of test_percentile_sharded_multichunk, noise ON (eps 1),
+        # twice under one noise seed: as served (every shard sorts its own
+        # rows by (partition, leaf) and searches them; the [P, B] counts
+        # psum'd) and with the scatter form that was served before put in
+        # its place. The released percentiles are the same bytes.
+        import dataclasses
+        import jax
+        from pipelinedp_tpu import executor
+        orig = executor.make_kernel_config
+
+        def forced_chunk(*a, **kw):
+            return dataclasses.replace(orig(*a, **kw), quantile_chunk=2)
+
+        monkeypatch.setattr(executor, "make_kernel_config", forced_chunk)
+        mesh = make_mesh(n_devices=8)
+        rows = [("u%d" % i, "pk%d" % (i % 5), float((i * 37) % 100))
+                for i in range(1000)]
+        params = pdp.AggregateParams(
+            metrics=[pdp.Metrics.PERCENTILE(50), pdp.Metrics.PERCENTILE(90)],
+            max_partitions_contributed=1,
+            max_contributions_per_partition=1,
+            min_value=0.0,
+            max_value=100.0)
+        public = ["pk%d" % i for i in range(5)]
+
+        def release():
+            jax.clear_caches()  # the form is read when the body is traced
+            result = _aggregate(pdp.TPUBackend(mesh=mesh, noise_seed=6),
+                                rows, params, public, eps=1.0)
+            return {pk: (m.percentile_50, m.percentile_90)
+                    for pk, m in result.items()}
+
+        served = release()
+        traced = _serve_the_scatter_form(monkeypatch)
+        reference = release()
+        jax.clear_caches()
+        assert traced  # the second release did run the reference
+        assert set(served) == set(public)
+        assert served == reference
+        # The noise is on: at eps 1 the answers are not the true quantiles'.
+        assert len({v for pair in served.values() for v in pair}) > 2
+
     def test_vector_sum_sharded(self):
         mesh = make_mesh(n_devices=8)
         rows = [("u%d" % (i % 50), "pk%d" % (i % 3),
@@ -443,6 +504,44 @@ class TestShardedBlockedLargeP:
         # (partition 150 also catches one sparse row: 201).
         truth = np.bincount(pk, minlength=P)
         np.testing.assert_allclose(outputs["count"], truth[kept], atol=1e-4)
+
+    def test_percentile_blocked_sharded_equals_the_scatter_form(
+            self, monkeypatch):
+        # One blocked job with percentiles over the mesh, noise ON and a
+        # fixed key: three blocks of 512 trees, each on the lazy descent
+        # (quantile_chunk 64), as served and with the scatter form that
+        # was served before in its place: the same kept ids and the same
+        # bytes in every released column.
+        import dataclasses
+        import jax
+        from pipelinedp_tpu.parallel import large_p
+        mesh = make_mesh(n_devices=8)
+        P = 1500
+        metrics = [pdp.Metrics.COUNT, pdp.Metrics.PERCENTILE(50),
+                   pdp.Metrics.PERCENTILE(90)]
+        cfg, stds, (min_v, max_v, min_s, max_s, mid) = self._spec(
+            P, private=False, metrics_list=metrics, l0=P, linf=64)
+        cfg = dataclasses.replace(cfg, quantile_chunk=64)
+        stds = np.full_like(np.asarray(stds), 3.0)
+        pid, pk, values, valid = self._data(6_000, 300, P, seed=8)
+
+        def release():
+            jax.clear_caches()  # the form is read when the body is traced
+            return large_p.aggregate_blocked_sharded(
+                mesh, pid, pk, values, valid, min_v, max_v, min_s, max_s,
+                mid, stds, jax.random.PRNGKey(4), cfg, block_partitions=512)
+
+        kept, served = release()
+        traced = _serve_the_scatter_form(monkeypatch)
+        ref_kept, reference = release()
+        jax.clear_caches()
+        assert traced  # the second release did run the reference
+        assert list(kept) == list(ref_kept) == list(range(P))
+        assert sorted(served) == sorted(reference)
+        for name in served:
+            np.testing.assert_array_equal(served[name], reference[name],
+                                          err_msg=name)
+        assert len(np.unique(served["percentile_50"])) > 100  # noise is on
 
     @pytest.mark.slow
     def test_percentile_blocked_sharded(self):
