@@ -621,13 +621,18 @@ class _StagedDrain:
             (targets, rt_pipeline.KeptPrefix(arrays, k), id_base))
         self._open += 1
 
-    def end_block(self) -> None:
-        """Mark the end of one block's stage() calls; drains the oldest
-        staged block once more than max_staged_blocks are pending."""
+    def end_block(self, block: int) -> None:
+        """Mark the end of block `block`'s stage() calls (its consume
+        calls this); drains the oldest staged block once more than
+        max_staged_blocks are pending, under span p2.wait: the host
+        blocked on the copies of a block a window old."""
         self._block_sizes.append(self._open)
         self._open = 0
         while len(self._block_sizes) > self._max:
-            self._drain_n(self._block_sizes.pop(0))
+            n = self._block_sizes.pop(0)
+            if n:
+                with rt_trace.span("p2.wait", block=block):
+                    self._drain_n(n)
 
     def materialize(self) -> None:
         """Drain everything still staged (call after the dispatch loop)."""
@@ -656,13 +661,24 @@ def _seed_pass1(seconds: float) -> None:
         wd.seed_profile(seconds)
 
 
-def _pad_to(a, cap: int, fill):
+def _pad_to(a, cap: int):
     widths = ((0, cap - len(a)),) + ((0, 0),) * (a.ndim - 1)
     if isinstance(a, jax.Array):
         # Device-resident columns (streamed ingest) pad on device; np.pad
         # would silently download them.
-        return jnp.pad(a, widths, constant_values=fill)
-    return np.pad(a, widths, constant_values=fill)
+        return jnp.pad(a, widths)
+    return np.pad(a, widths)
+
+
+def _pad_rows(cap: int, *columns):
+    """Pass 1's columns padded to `cap` rows with zeros (False in the
+    valid mask), under span p1.pad (rows, cap, the bytes written). On
+    host columns the span is the host's np.pad; on jax.Array columns
+    the pad is a device dispatch and the span times only the dispatch."""
+    with rt_trace.span("p1.pad", rows=len(columns[0]), cap=cap) as sp:
+        padded = tuple(_pad_to(a, cap) for a in columns)
+        sp.set(bytes=_nbytes(*padded))
+    return padded
 
 
 def _nbytes(*arrays) -> int:
@@ -733,8 +749,9 @@ def _bound_and_compact_host_staged(pid, pk, values, valid, min_v, max_v,
     Chunks split on privacy-id boundaries (L0 bounding is global per id);
     each chunk's survivors arrive already spk-sorted, the host merges them
     with one argsort over the concatenation. The p1.* spans split the
-    round trip: host sort, per chunk pad + launch / sync / copy-down, then
-    the host merge (the upload of the merged stream is the caller's).
+    round trip: host sort, per chunk p1.chunk (its p1.pad, then the
+    launch) / sync / copy-down, then the host merge (the upload of the
+    merged stream is the caller's).
     """
     with rt_trace.span("p1.host_sort", rows=len(pid)):
         order = np.argsort(pid, kind="stable")
@@ -748,9 +765,8 @@ def _bound_and_compact_host_staged(pid, pk, values, valid, min_v, max_v,
         sl = slice(start, end)
         cap = round_capacity(end - start)
         with rt_trace.span("p1.chunk", chunk=ci, rows=end - start, cap=cap):
-            rows_in = (_pad_to(pid_s[sl], cap, 0), _pad_to(pk_s[sl], cap, 0),
-                       _pad_to(values_s[sl], cap, 0),
-                       _pad_to(valid_s[sl], cap, False))
+            rows_in = _pad_rows(cap, pid_s[sl], pk_s[sl], values_s[sl],
+                                valid_s[sl])
             rt_telemetry.record("h2d_bytes", _nbytes(*rows_in))
             spk, pair, cols, leaf, n_kept = _bounded_compact_kernel(
                 *rows_in, min_v, max_v, min_s, max_s, mid,
@@ -1052,23 +1068,26 @@ def aggregate_blocked_sharded(mesh,
                     spk_all, jnp.asarray(_block_boundaries(base, C,
                                                            n_blocks)),
                     mesh)).reshape(n_shards, n_blocks + 1)
+        if end == P:  # the plan's last range: its last boundary is >= P
+            rt_telemetry.record("pass2_rows", int(starts_r[:, -1].sum()))
         row_cap = _range_row_cap(starts_r)
 
         def consume(j, result):
             b_base = base + j * C
             if isinstance(result, _Replay):
                 append_record(result.record)
-                drain.end_block()
+                drain.end_block(j)
                 return
             n_kept, ids_sorted, outputs_sorted = result
             # Fail-closed sentinel BEFORE the journal persist: a
             # numerically poisoned block must never become a durable
             # record a later replay would release.
-            rt_numeric.check_release(
-                outputs_sorted, n_kept=n_kept,
-                numeric_mode=cfg.numeric_mode,
-                context=f"blocked meshed release (base {b_base})")
-            k = int(n_kept)  # sync; gates O(kept) transfers
+            with rt_trace.span("p2.wait", block=j):
+                rt_numeric.check_release(
+                    outputs_sorted, n_kept=n_kept,
+                    numeric_mode=cfg.numeric_mode,
+                    context=f"blocked meshed release (base {b_base})")
+                k = int(n_kept)  # sync; gates O(kept) transfers
             if journal is not None:
                 record = _materialize_block_record(ids_sorted,
                                                    outputs_sorted, k,
@@ -1080,7 +1099,7 @@ def aggregate_blocked_sharded(mesh,
                     [kept_ids, *(kept_outputs.setdefault(name, [])
                                  for name in outputs_sorted)],
                     [ids_sorted, *outputs_sorted.values()], k, b_base)
-            drain.end_block()
+            drain.end_block(j)
 
         def block_iter():
             for j in range(n_blocks):
@@ -1107,8 +1126,11 @@ def aggregate_blocked_sharded(mesh,
                     _block_noise_key(final_key, gen, j), cfg_block,
                     row_cap, mesh, secure_tables))
 
-        _dispatch_blocks(block_iter(), consume, retry_policy=retry,
-                         overlap=overlap)
+        dispatched = _dispatch_blocks(block_iter(), consume,
+                                      retry_policy=retry, overlap=overlap)
+        if dispatched:
+            rt_telemetry.record("pass2_block_rows",
+                                dispatched * row_cap * n_shards)
 
     rt_retry.run_with_degradation(run_range, P, C0, journal=journal,
                                   job_id=job)
@@ -1315,10 +1337,11 @@ def select_partitions_blocked_sharded(mesh,
             if isinstance(result, _Replay):
                 if result.record.n_kept:
                     kept_ids.append(result.record.ids)
-                drain.end_block()
+                drain.end_block(j)
                 return
             n_kept, order = result
-            k = int(n_kept)  # sync; gates the O(kept) transfer
+            with rt_trace.span("p2.wait", block=j):
+                k = int(n_kept)  # sync; gates the O(kept) transfer
             if journal is not None:
                 # Journaled runs materialize per block, as the aggregate
                 # routes' _materialize_block_record does: the record holds
@@ -1331,7 +1354,7 @@ def select_partitions_blocked_sharded(mesh,
                     kept_ids.append(ids)
             elif k:
                 drain.stage([kept_ids], [order], k, b_base)
-            drain.end_block()
+            drain.end_block(j)
 
         def block_iter():
             for j in range(n_blocks):
@@ -1394,12 +1417,14 @@ def select_partitions_blocked(pid,
     kept ids — O(rows + kept) host traffic at any P.
 
     Where the wall time goes is read from rt_trace: contribution_bounding
-    (the host's pad to the row capacity, then p1.upload: host columns
-    going up, blocking until they are there, counted in h2d_bytes, then
-    pass 1's launch), block_offsets (the host's first wait for the
-    device: pass 1's two sorts and the search), and per block dispatch /
-    drain (release_wait inside) / consume; the staged ids come down in
-    the last drain. Two counters say what pass 2 worked on:
+    (p1.pad, the host's pad to the row capacity, then p1.upload: host
+    columns going up, blocking until they are there, counted in
+    h2d_bytes, then pass 1's launch), block_offsets (the host's first
+    wait for the device: pass 1's two sorts and the search), and per
+    block dispatch / drain (release_wait inside) / consume (p2.wait
+    inside: int(n_kept), already on the host, and the staged copies of
+    a block a window old); the staged ids come down in the last drain.
+    Two counters say what pass 2 worked on:
     selection_pairs, the pairs that survived dedupe and l0 — its ONE
     source is the last block offset (every surviving pair sorts below
     it, every dropped row's sentinel above), which the host holds
@@ -1416,8 +1441,7 @@ def select_partitions_blocked(pid,
     cap = round_capacity(len(pid))
     t_p1 = time.perf_counter()
     with rt_trace.span("contribution_bounding", rows=len(pid)):
-        rows_in = (_pad_to(pid, cap, 0), _pad_to(pk, cap, 0),
-                   _pad_to(valid, cap, False))
+        rows_in = _pad_rows(cap, pid, pk, valid)
         if on_host:
             nbytes = _nbytes(*rows_in)
             rt_telemetry.record("h2d_bytes", nbytes)
@@ -1453,10 +1477,11 @@ def select_partitions_blocked(pid,
             if isinstance(result, _Replay):
                 if result.record.n_kept:
                     kept_ids.append(result.record.ids)
-                drain.end_block()
+                drain.end_block(j)
                 return
             n_kept, order = result
-            k = int(n_kept)  # sync; gates the O(kept) transfer
+            with rt_trace.span("p2.wait", block=j):
+                k = int(n_kept)  # sync; gates the O(kept) transfer
             if journal is not None:
                 # Journaled runs materialize per block, as the aggregate
                 # routes' _materialize_block_record does: the record holds
@@ -1469,7 +1494,7 @@ def select_partitions_blocked(pid,
                     kept_ids.append(ids)
             elif k:
                 drain.stage([kept_ids], [order], k, b_base)
-            drain.end_block()
+            drain.end_block(j)
 
         def block_iter():
             for j in range(n_blocks):
@@ -1550,8 +1575,17 @@ def aggregate_blocked(pid,
     the device (the tests' seam).
 
     Where the wall time goes is read from rt_trace: contribution_bounding
-    (staged=host|device) with its p1.* children, block_offsets, and per
-    block dispatch / drain (release_wait inside) / consume.
+    (staged=host|device) with its p1.* children (p1.pad, the host's pad
+    to the row capacity, inside each p1.chunk), block_offsets (the
+    host's first wait: pass 1), per block dispatch / drain (release_wait
+    inside: block b's scalars) / consume (p2.wait inside: the sentinel's
+    flags program, which queues behind every block in flight, int(n_kept)
+    and the staged copies of a block a window old), and the last drain.
+    On a journal-less run the host's whole wait for the device is
+    block_offsets + release_wait + p2.wait. Two counters say what pass 2
+    worked on, as select_partitions_blocked's two do: pass2_rows, the
+    bounded survivors (the last block offset), and pass2_block_rows,
+    row_cap times the block programs dispatched.
 
     retry/journal/job_id: failure-semantics knobs (module docstring).
     Journaled runs materialize each block's results at consume time (one
@@ -1597,9 +1631,7 @@ def aggregate_blocked(pid,
             rt_telemetry.record("pass1_device_resident")
             cap = round_capacity(n)
             with rt_trace.span("p1.chunk", chunk=0, rows=n, cap=cap):
-                rows_in = (_pad_to(pid, cap, 0), _pad_to(pk, cap, 0),
-                           _pad_to(values, cap, 0),
-                           _pad_to(valid, cap, False))
+                rows_in = _pad_rows(cap, pid, pk, values, valid)
                 if not device_resident:
                     nbytes = _nbytes(*rows_in)
                     rt_telemetry.record("h2d_bytes", nbytes)
@@ -1671,23 +1703,26 @@ def aggregate_blocked(pid,
                 _block_offsets_dev(
                     spk_all,
                     jnp.asarray(_block_boundaries(base, C, n_blocks))))
+        if end == P:  # the plan's last range: its last boundary is >= P
+            rt_telemetry.record("pass2_rows", int(block_starts[-1]))
         row_cap = _range_row_cap(block_starts)
 
         def consume(j, result):
             b_base = base + j * C
             if isinstance(result, _Replay):
                 append_record(result.record)
-                drain.end_block()
+                drain.end_block(j)
                 return
             n_kept, ids_sorted, outputs_sorted = result
             # Fail-closed sentinel BEFORE the journal persist: a
             # numerically poisoned block must never become a durable
             # record a later replay would release.
-            rt_numeric.check_release(
-                outputs_sorted, n_kept=n_kept,
-                numeric_mode=cfg.numeric_mode,
-                context=f"blocked release (base {b_base})")
-            k = int(n_kept)  # sync; gates O(kept) transfers
+            with rt_trace.span("p2.wait", block=j):
+                rt_numeric.check_release(
+                    outputs_sorted, n_kept=n_kept,
+                    numeric_mode=cfg.numeric_mode,
+                    context=f"blocked release (base {b_base})")
+                k = int(n_kept)  # sync; gates O(kept) transfers
             if journal is not None:
                 # Journaled runs materialize per block (one sync each) so
                 # the record is durable the moment the block is consumed —
@@ -1705,7 +1740,7 @@ def aggregate_blocked(pid,
                     [kept_ids, *(kept_outputs.setdefault(name, [])
                                  for name in outputs_sorted)],
                     [ids_sorted, *outputs_sorted.values()], k, b_base)
-            drain.end_block()
+            drain.end_block(j)
 
         def block_iter():
             for j in range(n_blocks):
@@ -1733,12 +1768,15 @@ def aggregate_blocked(pid,
                     _block_noise_key(final_key, gen, j), cfg_block,
                     row_cap, secure_tables))
 
-        _dispatch_blocks(block_iter(), consume, retry_policy=retry,
-                         overlap=overlap)
+        dispatched = _dispatch_blocks(block_iter(), consume,
+                                      retry_policy=retry, overlap=overlap)
+        if dispatched:
+            rt_telemetry.record("pass2_block_rows", dispatched * row_cap)
 
     rt_retry.run_with_degradation(run_range, P, C0, journal=journal,
                                   job_id=job)
-    drain.materialize()
+    with rt_trace.span("drain"):
+        drain.materialize()
     # Each block emits kept partitions in ascending relative id (the compact
     # sort is stable) and blocks are consumed in ascending order, so the
     # concatenation is already globally ascending.

@@ -308,13 +308,11 @@ def _fallback_select_partitions(args, kwargs, job):
     def go(mesh, pid, pk, valid, rng_key, l0, n_partitions, selection,
            reshard="auto", retry=None, job_id=None):
         del mesh, reshard, job_id
-        from pipelinedp_tpu.parallel.large_p import _pad_to
-        cap = round_capacity(len(pid))
+        from pipelinedp_tpu.parallel.large_p import _pad_rows
+        rows_in = _pad_rows(round_capacity(len(pid)), pid, pk, valid)
         return rt_retry.retry_call(
             lambda: executor.select_partitions_release_kernel(
-                jnp.asarray(_pad_to(pid, cap, 0)),
-                jnp.asarray(_pad_to(pk, cap, 0)),
-                jnp.asarray(_pad_to(valid, cap, False)), rng_key, l0,
+                *(jnp.asarray(a) for a in rows_in), rng_key, l0,
                 n_partitions, selection),
             retry, what="single-device select_partitions dispatch")
 
@@ -331,18 +329,16 @@ def _fallback_aggregate_arrays(args, kwargs, job):
            stds, rng_key, cfg, secure_tables=None, reshard="auto",
            retry=None, job_id=None):
         del mesh, reshard, job_id
-        from pipelinedp_tpu.parallel.large_p import _pad_to
+        from pipelinedp_tpu.parallel.large_p import _pad_rows
         if isinstance(values, jax.Array):
             values = values.astype(executor._ftype())
         else:
             values = np.asarray(values, dtype=np.dtype(executor._ftype()))
-        cap = round_capacity(len(pid))
+        rows_in = _pad_rows(round_capacity(len(pid)), pid, pk, values,
+                            valid)
         return rt_retry.retry_call(
             lambda: executor.aggregate_release_kernel(
-                jnp.asarray(_pad_to(pid, cap, 0)),
-                jnp.asarray(_pad_to(pk, cap, 0)),
-                jnp.asarray(_pad_to(values, cap, 0)),
-                jnp.asarray(_pad_to(valid, cap, False)), min_v, max_v,
+                *(jnp.asarray(a) for a in rows_in), min_v, max_v,
                 min_s, max_s, mid, jnp.asarray(stds), rng_key, cfg,
                 secure_tables),
             retry, what="single-device aggregation dispatch")

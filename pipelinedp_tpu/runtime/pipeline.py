@@ -1,6 +1,6 @@
 """Device-resident streaming executor: overlapped ingest -> aggregate -> drain.
 
-PR 6's ``e2e_phase_breakdown`` proved the ~200x kernel-vs-end-to-end gap
+A bare kernel ran some 200x faster than the end-to-end job, and the gap
 is NOT the DP math: host-side encode, per-call dispatch/compile round
 trips and serialized engine stages dominate the warm path. This module is
 the engine's answer — the pieces that turn ``DPEngine.aggregate`` into a

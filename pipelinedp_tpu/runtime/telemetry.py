@@ -197,6 +197,18 @@ REGISTRY: Dict[str, Metric] = {
                  "the largest block's pairs rounded up) times the block "
                  "programs dispatched. Beside selection_pairs it says "
                  "what the shared capacity costs on skewed keys"),
+        _counter("pass2_rows",
+                 "bounded survivors that pass 2 of a blocked aggregation "
+                 "bins (large_p.aggregate_blocked and its meshed twin), "
+                 "read once a job from the last block offset the host "
+                 "fetches anyway (summed over shards on a mesh)"),
+        _counter("pass2_block_rows",
+                 "rows the block programs of a blocked aggregation "
+                 "gathered: the range's shared row capacity "
+                 "(large_p._range_row_cap) times the block programs "
+                 "dispatched, times the shards on a mesh. Beside "
+                 "pass2_rows it says what the shared capacity costs on "
+                 "skewed keys"),
         _counter("aot_cache_hits",
                  "warm-path dispatches served by an ahead-of-time "
                  "compiled executable from the process-wide "

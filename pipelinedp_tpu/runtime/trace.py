@@ -45,8 +45,8 @@ run into an exportable, attributable trace:
     time, instant counts, transferred bytes (the sum of ``bytes=`` span
     attributes — host_fetch and the reshard staging set them) and
     per-entry-point compile stats. Both reach operators through
-    ``TPUBackend.dump_trace(path)`` / ``TPUBackend.trace_summary()`` and
-    the bench receipt's ``e2e_phase_breakdown`` / ``trace_summary`` keys.
+    ``TPUBackend.dump_trace(path)`` / ``TPUBackend.trace_summary()``;
+    the benchmark's per-layer metrics read the same summary.
 
 Epoch discipline: buffers are process-wide and bounded (``buffer_limit``
 events; excess events are counted in ``dropped_events``, never silently

@@ -225,8 +225,9 @@ def _blocked_select_run():
     opened = {name for name, row in trace.trace_summary()["spans"].items()
               if row["count"] > spans_before.get(name, 0)}
     assert {"graph_build", "select_partitions", "contribution_bounding",
-            "p1.upload", "block_offsets", "dispatch", "release_wait",
-            "drain", "consume", "post_process"} <= opened, sorted(opened)
+            "p1.pad", "p1.upload", "block_offsets", "dispatch",
+            "release_wait", "drain", "consume", "p2.wait",
+            "post_process"} <= opened, sorted(opened)
     counted = telemetry.delta(before)
     assert 0 < counted["selection_pairs"] <= 4 * users
     assert counted["selection_block_rows"] >= counted["selection_pairs"]

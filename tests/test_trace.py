@@ -513,6 +513,25 @@ def _span_events():
             if e["ph"] == "X"]
 
 
+def _assert_pass2_waits(events, dispatched):
+    """Each p2.wait is a child of its block's consume, lies inside no
+    release_wait, and every dispatched block's consume opened one (a
+    journal-less serial run)."""
+    by_id = {e["args"]["id"]: e for e in events}
+    waits = [e for e in events if e["name"] == "p2.wait"]
+    assert len(waits) >= dispatched, (len(waits), dispatched)
+    release_waits = [e for e in events if e["name"] == "release_wait"]
+    slack = 0.01  # ts/dur are microseconds rounded to 3 places
+    for wait in waits:
+        consume = by_id[wait["args"]["parent"]]
+        assert consume["name"] == "consume", consume
+        assert consume["args"]["block"] == wait["args"]["block"]
+        for rw in release_waits:
+            assert (wait["ts"] >= rw["ts"] + rw["dur"] - slack or
+                    wait["ts"] + wait["dur"] <= rw["ts"] + slack), (wait,
+                                                                    rw)
+
+
 class TestBackendIntegration:
 
     def test_trace_knob_validation(self):
@@ -588,8 +607,8 @@ class TestBackendIntegration:
                     resident_before)
         spans = trace.trace_summary()["spans"]
         for expected in ("aggregate_blocked", "contribution_bounding",
-                         "p1.chunk", "block_offsets", "dispatch", "drain",
-                         "release_wait", "consume"):
+                         "p1.chunk", "p1.pad", "block_offsets", "dispatch",
+                         "drain", "release_wait", "consume", "p2.wait"):
             assert expected in spans, (expected, sorted(spans))
         assert spans["dispatch"]["count"] >= 2  # several blocks
         root = spans["aggregate_blocked"]["inclusive_s"]
@@ -597,6 +616,26 @@ class TestBackendIntegration:
             assert 0.0 <= stats["exclusive_s"] <= root + 1e-6, (
                 name, stats, root)
         json.dumps(trace.to_trace_events())  # every attribute exports
+        # One p1.pad in each p1.chunk: the chunk's four columns written
+        # out to its capacity (pid, pk, value, valid).
+        events = _span_events()
+        by_id = {e["args"]["id"]: e for e in events}
+        row_bytes = 9 + np.dtype(large_p.executor._ftype()).itemsize
+        chunks = [e for e in events if e["name"] == "p1.chunk"]
+        pads = [e for e in events if e["name"] == "p1.pad"]
+        assert sorted(e["args"]["parent"] for e in pads) == sorted(
+            e["args"]["id"] for e in chunks)
+        for pad in pads:
+            chunk = by_id[pad["args"]["parent"]]
+            assert (pad["args"]["rows"], pad["args"]["cap"]) == (
+                chunk["args"]["rows"], chunk["args"]["cap"])
+            assert pad["args"]["bytes"] == row_bytes * chunk["args"]["cap"]
+        _assert_pass2_waits(events, spans["dispatch"]["count"])
+        # The last staged copies come down under a drain of the driver's
+        # own, counted in no release_dispatches.
+        (last,) = [e for e in events
+                   if e["name"] == "drain" and "block" not in e["args"]]
+        assert by_id[last["args"]["parent"]]["name"] == "aggregate_blocked"
         staged = {e["args"]["staged"] for e in _span_events()
                   if e["name"] == "contribution_bounding"}
         if row_chunk < len(args[0]):
@@ -627,6 +666,8 @@ class TestBackendIntegration:
             assert spans["p1.upload"]["count"] == 1
             upload = next(e for e in _span_events()
                           if e["name"] == "p1.upload")
+            (pad,) = pads
+            assert pad["ts"] + pad["dur"] <= upload["ts"] + 0.01
             # pid + pk + value (f64 under the tests' x64) + valid, padded.
             row_bytes = 9 + np.dtype(large_p.executor._ftype()).itemsize
             assert upload["args"]["bytes"] == row_bytes * \
@@ -638,7 +679,7 @@ class TestBackendIntegration:
         run(overlap=True)
         spans_overlapped = trace.trace_summary()["spans"]
         for expected in ("aggregate_blocked", "contribution_bounding",
-                         "dispatch", "drain", "consume"):
+                         "dispatch", "drain", "consume", "p2.wait"):
             assert expected in spans_overlapped, (
                 expected, sorted(spans_overlapped))
         # The drainer thread's spans name the driver's span as their
@@ -647,10 +688,74 @@ class TestBackendIntegration:
         drainer = next(e for e in by_id.values() if e["name"] == "drainer")
         driver = by_id[drainer["args"]["parent"]]
         assert driver["tid"] != drainer["tid"]
-        drains = [e for e in by_id.values() if e["name"] == "drain"]
+        # Each block's drain on the drainer; the driver's last one (no
+        # block) on its own thread, after the drainer has joined.
+        drains = [e for e in by_id.values()
+                  if e["name"] == "drain" and "block" in e["args"]]
         assert drains and all(e["args"]["parent"] == drainer["args"]["id"]
                               and e["tid"] == drainer["tid"]
                               for e in drains)
+
+    def test_blocked_selection_pads_and_waits_where_they_happen(self):
+        """select_partitions_blocked in four blocks: p1.pad is a child of
+        contribution_bounding that ends before its sibling p1.upload
+        begins, with the three padded columns' bytes; each p2.wait sits
+        in its block's consume, outside every release_wait."""
+        import jax
+        from pipelinedp_tpu.ops import selection_ops
+        from pipelinedp_tpu.parallel import large_p
+
+        P, n, l0 = 1 << 11, 6000, 4
+        rng = np.random.default_rng(6)
+        pid = rng.integers(0, 300, n).astype(np.int32)
+        pk = (P * rng.random(n)**3).astype(np.int32)
+        valid = np.ones(n, bool)
+        selection = selection_ops.selection_params_from_host(
+            pdp.PartitionSelectionStrategy.TRUNCATED_GEOMETRIC, 20.0, 1e-3,
+            l0, None)
+        run = functools.partial(large_p.select_partitions_blocked, pid, pk,
+                                valid, jax.random.PRNGKey(7), l0, P,
+                                selection, block_partitions=1 << 9)
+        run()  # warm
+        trace.enable()
+        assert len(run()) > 0
+        events = _span_events()
+        by_name = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        (bounding,) = by_name["contribution_bounding"]
+        (pad,) = by_name["p1.pad"]
+        (upload,) = by_name["p1.upload"]
+        assert pad["args"]["parent"] == bounding["args"]["id"]
+        assert upload["args"]["parent"] == bounding["args"]["id"]
+        assert pad["ts"] + pad["dur"] <= upload["ts"] + 0.01
+        cap = large_p.round_capacity(n)
+        assert (pad["args"]["rows"], pad["args"]["cap"],
+                pad["args"]["bytes"]) == (n, cap, 9 * cap)
+        assert len(by_name["dispatch"]) == 4
+        _assert_pass2_waits(events, len(by_name["dispatch"]))
+
+    def test_elastic_floor_pads_under_p1_pad(self):
+        """The meshed selection's single-device floor pads its columns
+        under p1.pad, as the blocked drivers do."""
+        import jax
+        from pipelinedp_tpu.ops import selection_ops
+        from pipelinedp_tpu.parallel import large_p, sharded
+
+        P, n, l0 = 64, 300, 2
+        rng = np.random.default_rng(8)
+        selection = selection_ops.selection_params_from_host(
+            pdp.PartitionSelectionStrategy.TRUNCATED_GEOMETRIC, 20.0, 1e-3,
+            l0, None)
+        args = (None, rng.integers(0, 40, n).astype(np.int32),
+                rng.integers(0, P, n).astype(np.int32), np.ones(n, bool),
+                jax.random.PRNGKey(9), l0, P, selection)
+        trace.enable()
+        sharded._fallback_select_partitions(args, {}, "floor")
+        (pad,) = [e for e in _span_events() if e["name"] == "p1.pad"]
+        cap = large_p.round_capacity(n)
+        assert (pad["args"]["rows"], pad["args"]["cap"],
+                pad["args"]["bytes"]) == (n, cap, 9 * cap)
 
     def test_chunk_aggregate_spans_share_one_root(self):
         """A ChunkSource aggregation records the ingest and release-wait
@@ -709,7 +814,12 @@ class TestBackendIntegration:
         import glob
 
         import jax
+        from pipelinedp_tpu.parallel import large_p
+        blocked = functools.partial(large_p.aggregate_blocked,
+                                    *_blocked_args(),
+                                    block_partitions=1 << 10)
         _chunk_job(5)  # warm
+        blocked()
         trace.enable()
         jax.profiler.start_trace(str(tmp_path))
         try:
@@ -717,6 +827,7 @@ class TestBackendIntegration:
             with jax.profiler.TraceAnnotation("test_anchor"):
                 pass
             _chunk_job(6)
+            blocked()  # a tiny blocked job: its pad and its pass-2 waits
         finally:
             jax.profiler.stop_trace()
         (xplane,) = glob.glob(
@@ -741,7 +852,8 @@ class TestBackendIntegration:
             on_profiler_us, on_rt_trace_us)
         assert stats["id"] == exported["ingest"]["args"]["id"]
         assert stats["agg"] == exported["ingest"]["args"]["agg"]
-        for name in ("rt:aggregate", "rt:ingest.wait", "rt:release_wait"):
+        for name in ("rt:aggregate", "rt:ingest.wait", "rt:release_wait",
+                     "rt:p1.pad", "rt:p2.wait"):
             assert name in annotations, (name, sorted(annotations))
 
 
@@ -827,3 +939,63 @@ class TestUntracedCounters:
         assert len(kept) <= drained and drained % (1 << 10) == 0
         assert moved["d2h_bytes"] == fetched_bytes + control + \
             drained * (4 + len(outputs) * f)
+
+
+def _all_rows_survive(n=4000, P=1 << 12):
+    """_blocked_args whose rows all survive the bounding (l0 = 2, linf =
+    3): each privacy id holds two rows, in two partitions."""
+    args = list(_blocked_args(n=n, P=P))
+    rng = np.random.default_rng(4)
+    args[0] = (np.arange(n) // 2).astype(np.int32)
+    pk = rng.integers(0, P // 2, n)
+    pk[1::2] += P // 2  # an id's second row lies in another partition
+    args[1] = pk.astype(np.int32)
+    return args
+
+
+class TestPass2Counters:
+    """pass2_rows and pass2_block_rows count with tracing off."""
+
+    @pytest.mark.parametrize("row_chunk", [None, 1000],
+                             ids=["device_resident", "host_staged"])
+    def test_counters_follow_the_block_offsets(self, row_chunk):
+        """pass2_rows is the last block offset — here every row, since
+        every row survives — and pass2_block_rows the shared row capacity
+        times the blocks dispatched; the last drain dispatches nothing."""
+        from pipelinedp_tpu.parallel import large_p
+        args = _all_rows_survive()
+        n, C = len(args[0]), 1 << 10
+        run = functools.partial(large_p.aggregate_blocked, *args,
+                                block_partitions=C, row_chunk=row_chunk)
+        run()  # warm
+        assert not trace.enabled()
+        before = telemetry.snapshot()
+        run()
+        counted = telemetry.delta(before)
+        per_block = np.bincount(args[1] // C, minlength=4)
+        dispatched = int(np.count_nonzero(per_block))
+        assert counted["pass2_rows"] == n
+        assert counted["release_dispatches"] == dispatched
+        assert counted["pass2_block_rows"] == dispatched * \
+            large_p.round_capacity(int(per_block.max()))
+
+    def test_meshed_counters_sum_over_shards(self, monkeypatch):
+        from pipelinedp_tpu.parallel import large_p, make_mesh
+        mesh = make_mesh(n_devices=4)
+        args = _all_rows_survive()
+        caps = []
+        range_row_cap = large_p._range_row_cap
+
+        def spy(starts):
+            caps.append(range_row_cap(starts))
+            return caps[-1]
+
+        monkeypatch.setattr(large_p, "_range_row_cap", spy)
+        before = telemetry.snapshot()
+        large_p.aggregate_blocked_sharded(mesh, *args,
+                                          block_partitions=1 << 10)
+        counted = telemetry.delta(before)
+        (row_cap,) = caps
+        assert counted["pass2_rows"] == len(args[0])
+        assert counted["pass2_block_rows"] == \
+            4 * row_cap * counted["release_dispatches"]
