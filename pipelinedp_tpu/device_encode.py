@@ -59,6 +59,7 @@ from jax.sharding import PartitionSpec as P
 
 from pipelinedp_tpu.parallel import mesh as mesh_lib
 from pipelinedp_tpu.parallel.mesh import SHARD_AXIS, host_fetch, shard_map
+from pipelinedp_tpu.runtime import telemetry as rt_telemetry
 from pipelinedp_tpu.runtime import trace as rt_trace
 
 # Invalid/pad marker: both uint32 lanes at their maximum. The host hash
@@ -124,7 +125,7 @@ def merge_hash_uniques(
 ) -> Tuple[np.ndarray, Optional[np.ndarray], int, Optional[np.ndarray]]:
     """Merges per-chunk unique (h1, h2[, key][, pos]) tuples.
 
-    Fully vectorized (one lexsort over the total chunk-unique count —
+    Fully vectorized (one sort over the total chunk-unique count —
     never rows): dedupes by (h1, h2) pair, verifies every primary hash
     maps to exactly one secondary hash (two secondaries = two distinct
     raw keys collided on h1 -> HashCollisionError), and returns
@@ -132,9 +133,16 @@ def merge_hash_uniques(
     the hash-sorted decode table HashVocab searches at selection time,
     with each hash's FIRST-occurrence key and (when positions are
     given) minimum stream position, from which the pod path derives the
-    code order on host.
+    code order on host. The entries it sorts are the counter
+    hash_unique_entries.
+
+    The sort is ONE stable argsort of h1. Each chunk holds a hash at
+    most once and the chunks come in stream order (both callers
+    concatenate them so), so a hash's entries lie in stream order and
+    the first of them is the first occurrence.
     """
     h1 = _concat(h1_chunks)
+    rt_telemetry.record("hash_unique_entries", len(h1))
     h2 = _concat(h2_chunks)
     keys = _concat(key_chunks, dtype=object) if key_chunks is not None \
         else None
@@ -143,27 +151,24 @@ def merge_hash_uniques(
     if len(h1) == 0:
         return (h1, (keys if keys is None else keys[:0]), 0,
                 (pos if pos is None else pos[:0]))
-    sort_keys = (h2, h1) if pos is None else (pos, h2, h1)
-    order = np.lexsort(sort_keys)
+    order = np.argsort(h1, kind="stable")
     s1, s2 = h1[order], h2[order]
     new1 = np.empty(len(s1), bool)
     new1[0] = True
     np.not_equal(s1[1:], s1[:-1], out=new1[1:])
-    pair_new = new1.copy()
-    pair_new[1:] |= s2[1:] != s2[:-1]
-    n_h1 = int(new1.sum())
-    n_pairs = int(pair_new.sum())
-    if n_pairs != n_h1:
-        # Name one offender: a pair-start that is not an h1-start means
-        # its h1 already appeared with a different h2.
-        bad = np.nonzero(pair_new & ~new1)[0][0]
+    # An h1 run whose h2 changes: two distinct raw keys collided on h1.
+    clash = ~new1[1:] & (s2[1:] != s2[:-1])
+    if clash.any():
+        bad = int(np.argmax(clash)) + 1
         raise HashCollisionError(
             f"uint64 hash collision among {what} keys: primary hash "
             f"{int(s1[bad])} maps to (at least) two distinct raw keys "
             f"(secondary lanes {int(s2[bad - 1])} != {int(s2[bad])}) — "
-            f"{n_pairs - n_h1} colliding pair(s) total")
-    return (s1[new1], None if keys is None else keys[order][new1], n_h1,
-            None if pos is None else pos[order][new1])
+            f"{len(np.unique(s1[1:][clash]))} colliding primary hash(es) "
+            f"total")
+    first = order[new1]
+    return (s1[new1], None if keys is None else keys[first],
+            int(new1.sum()), None if pos is None else pos[first])
 
 
 # ---------------------------------------------------------------------------

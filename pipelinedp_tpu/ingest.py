@@ -430,40 +430,76 @@ _FNV_OFFSETS = (np.uint64(0xCBF29CE484222325),
 _FNV_PRIME = np.uint64(0x100000001B3)
 
 
-def _vector_hash_fixed_width(raw: np.ndarray) -> Tuple[np.ndarray,
-                                                       np.ndarray]:
-    """Both hash lanes of a fixed-width 'U'/'S' key column in ONE pass
-    over the character matrix: vectorized FNV-1a over the code units
-    (one multiply-xor per character column per lane) finished with the
-    splitmix64 bijection. ~50x the throughput of a per-row C hash —
-    this is what keeps the hash-device mode's host work to 'read the
-    bytes once'."""
-    n = len(raw)
-    raw = np.ascontiguousarray(raw)
-    if raw.dtype.kind == "U":
-        width = raw.dtype.itemsize // 4
-        mat = raw.view(np.uint32).reshape(n, width) if width else None
-    else:
-        width = raw.dtype.itemsize
-        mat = raw.view(np.uint8).reshape(n, width) if width else None
+def _fnv_ragged(units: np.ndarray, starts: np.ndarray,
+                lengths: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Both hash lanes of n keys whose code units are
+    ``units[starts[i] : starts[i] + lengths[i]]``: FNV-1a over the code
+    units, code unit j of a key salted by its position j, finished with
+    the splitmix64 bijection.
+
+    Zero code units never touch the hash: the same key hashes
+    identically whatever array width it arrived in (numpy itself strips
+    trailing NULs, so skipping them mirrors its key equality); the
+    position salt keeps interior characters order-sensitive. Vectorized
+    over keys, one step per code-unit position: the keys, longest first,
+    are a prefix of that order at every position, so position j steps
+    only the keys longer than j and the work is the keys' characters,
+    not keys x the widest key. The code units walked, in bytes, are the
+    counter hash_key_bytes."""
+    from pipelinedp_tpu.runtime import telemetry as rt_telemetry
+
+    n = len(lengths)
+    rt_telemetry.record("hash_key_bytes",
+                        int(lengths.sum()) * units.dtype.itemsize)
+    order = np.argsort(lengths, kind="stable")[::-1]
+    longest = lengths[order]
+    # Keys longer than j, for every position j: a prefix of `order`.
+    longer = np.searchsorted(-longest, -np.arange(longest[0] if n else 0),
+                             side="left")
+    at = starts[order].astype(np.int64)
     h0 = np.full(n, _FNV_OFFSETS[0])
     h1 = np.full(n, _FNV_OFFSETS[1])
-    if mat is not None:
-        for j in range(mat.shape[1]):
-            col = mat[:, j].astype(np.uint64)
-            # Zero code units (the fixed-width padding) must not touch
-            # the hash: the same key hashes identically whatever array
-            # width it arrived in — numpy itself strips trailing NULs,
-            # so skipping them mirrors its key equality. The position
-            # salt keeps interior characters order-sensitive.
-            live = col != 0
-            step0 = (h0 ^ (col + np.uint64(0x9E3779B9 * (j + 1)))) * \
-                _FNV_PRIME
-            step1 = (h1 ^ (col + np.uint64(0xC2B2AE35 * (j + 2)))) * \
-                _FNV_PRIME
-            h0 = np.where(live, step0, h0)
-            h1 = np.where(live, step1, h1)
-    return _splitmix64(h0, 0), _splitmix64(h1, 1)
+    for j, k in enumerate(longer):
+        col = units[at[:k] + j].astype(np.uint64)
+        live = col != 0
+        a0, a1 = h0[:k], h1[:k]
+        step0 = (a0 ^ (col + np.uint64(0x9E3779B9 * (j + 1)))) * _FNV_PRIME
+        step1 = (a1 ^ (col + np.uint64(0xC2B2AE35 * (j + 2)))) * _FNV_PRIME
+        h0[:k] = np.where(live, step0, a0)
+        h1[:k] = np.where(live, step1, a1)
+    out0, out1 = np.empty(n, np.uint64), np.empty(n, np.uint64)
+    out0[order], out1[order] = h0, h1
+    return _splitmix64(out0, 0), _splitmix64(out1, 1)
+
+
+def _vector_hash_fixed_width(raw: np.ndarray) -> Tuple[np.ndarray,
+                                                       np.ndarray]:
+    """Both hash lanes of a fixed-width 'U'/'S' key column (_fnv_ragged
+    over its character matrix: each key to its last nonzero code unit).
+    ~50x the throughput of a per-row C hash — this is what keeps the
+    hash-device mode's host work to 'read the bytes once'."""
+    n = len(raw)
+    raw = np.ascontiguousarray(raw)
+    unit = np.uint32 if raw.dtype.kind == "U" else np.uint8
+    width = raw.dtype.itemsize // np.dtype(unit).itemsize
+    units = raw.view(unit).reshape(n * width)
+    return _fnv_ragged(units, np.arange(n, dtype=np.int64) * width,
+                       np.strings.str_len(raw).astype(np.int64))
+
+
+def _vector_hash_str_objects(raw: np.ndarray) -> Tuple[np.ndarray,
+                                                       np.ndarray]:
+    """Both hash lanes of an object column whose every element is a
+    ``str``: _fnv_ragged over the code points of the keys laid end to
+    end, the hash _vector_hash_fixed_width gives the same keys as a 'U'
+    array, without the array: that has every key as wide as the chunk's
+    widest (a 100-character key makes a 2^20-row chunk 400 MB)."""
+    keys = raw.tolist()
+    lengths = np.fromiter(map(len, keys), np.int64, count=len(keys))
+    units = np.frombuffer(
+        "".join(keys).encode("utf-32-le", "surrogatepass"), np.uint32)
+    starts = np.cumsum(lengths) - lengths
+    return _fnv_ragged(units, starts, lengths)
 
 
 def hash_key_column_pair(raw) -> Tuple[np.ndarray, np.ndarray]:
@@ -495,7 +531,7 @@ def hash_key_column_pair(raw) -> Tuple[np.ndarray, np.ndarray]:
         # hash, never be silently stringified.
         inferred = _pd.api.types.infer_dtype(raw, skipna=False)
         if inferred == "string":
-            pair = _vector_hash_fixed_width(raw.astype(np.str_))
+            pair = _vector_hash_str_objects(raw)
         elif inferred in ("integer", "boolean"):
             bits = _canonical_numeric(raw.astype(np.int64)
                                       if inferred == "integer" else
@@ -522,18 +558,20 @@ def _hash_uniques(h1: np.ndarray, h2: np.ndarray, raw):
     """Chunk-local distinct (h1, h2) pairs + one representative raw key
     per pair (first occurrence) — the order-independent per-chunk
     contribution to collision detection and the deferred decode table.
-    One lexsort over the chunk, no global state."""
+    One stable sort of h1 over the chunk, no global state. Two keys that
+    share an h1 stay apart by h2 (not deduped where their rows
+    interleave), and the merge names that collision."""
     if len(h1) == 0:
         empty = np.empty(0, np.uint64)
         return empty, empty, (raw[:0] if raw is not None else None), \
             np.empty(0, np.int64)
-    order = np.lexsort((h2, h1))
+    order = np.argsort(h1, kind="stable")
     s1, s2 = h1[order], h2[order]
     new = np.empty(len(s1), bool)
     new[0] = True
     new[1:] = (s1[1:] != s1[:-1]) | (s2[1:] != s2[:-1])
     # Representative row per pair: the first occurrence IN CHUNK ORDER
-    # (lexsort is stable, so within a pair run row indices ascend).
+    # (the sort is stable, so within a run row indices ascend).
     first = order[new]
     return s1[new], s2[new], (raw[first] if raw is not None else None), \
         first.astype(np.int64)
@@ -893,7 +931,8 @@ def _stream_encode_hash_device(chunks, public_partitions, nonfinite,
                 chunks, public_partitions=public_partitions,
                 nonfinite=nonfinite, encode_threads=encode_threads,
                 pipeline_depth=pipeline_depth, encode_mode="host")
-        bufs = acc.finalize()
+        with rt_trace.span("ingest.finalize"):
+            bufs = acc.finalize()
         if bufs is None:
             return _hash_empty_encoded(public, value_dtype,
                                        partition_vocab)

@@ -633,6 +633,7 @@ class DeviceRowAccumulator:
 
     def _append_now(self, pid, pk, values, n_real: int, chunk: int) -> None:
         import jax.numpy as jnp
+        import numpy as np
         nbytes = int(pid.nbytes) + int(pk.nbytes) + int(values.nbytes)
         if not isinstance(pid, jnp.ndarray):
             rt_telemetry.record("h2d_bytes", nbytes)
@@ -644,11 +645,20 @@ class DeviceRowAccumulator:
                 self._n += n_real
                 self._refresh_accounting()
                 return
-            chunk_bufs = (jnp.asarray(pid), jnp.asarray(pk),
-                          jnp.asarray(values))
+            # A host column of rows wider than one value (the hash route's
+            # (n, 3) hash rows) goes up flat and takes its rows' shape on
+            # the device, in the append (_append_fn) or below: the
+            # transfer of a two-dimensional host array lays it out into
+            # the chip's layout on the host, in pieces, each of them a
+            # profiler event (PERF.md section 5).
+            chunk_bufs = tuple(
+                jnp.asarray(a.reshape(-1))
+                if isinstance(a, np.ndarray) and a.ndim > 1 else
+                jnp.asarray(a) for a in (pid, pk, values))
             if self._bufs is None:
                 # The first bucket-padded chunk IS the buffer.
-                self._bufs = chunk_bufs
+                self._bufs = tuple(b.reshape(a.shape) for a, b in
+                                   zip((pid, pk, values), chunk_bufs))
                 self._n = n_real
                 self._refresh_accounting()
                 return

@@ -253,6 +253,16 @@ REGISTRY: Dict[str, Metric] = {
                  "64-bit key-hash collisions the hash-device encode "
                  "detector caught (each one fell back to the exact host "
                  "encoder or raised HashCollisionError)"),
+        _counter("hash_unique_entries",
+                 "chunk-unique (h1, h2) entries the hash-device encode's "
+                 "unique merge sorts (device_encode.merge_hash_uniques), "
+                 "privacy ids and partition keys together, added by every "
+                 "call of the merge"),
+        _counter("hash_key_bytes",
+                 "bytes of code units the hash-device encode's string "
+                 "hash walks (ingest._fnv_ragged): every key's characters "
+                 "once, 4 B a character of a str, 1 B of bytes; numeric "
+                 "keys add nothing"),
         _counter("trace_dropped_events",
                  "trace events dropped because the bounded trace buffer "
                  "was full (trace_summary flags the epoch as truncated)"),

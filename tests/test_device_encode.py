@@ -66,6 +66,27 @@ def _assert_kernel_input_parity(host, dev):
 # ---------------------------------------------------------------------------
 
 
+def _fnv_per_column(raw):
+    """The string hash in its plainest form: FNV-1a over EVERY column of
+    the fixed-width character matrix, a zero code unit leaving the hash
+    as it was — the reference the ragged form must equal."""
+    n = len(raw)
+    unit = np.uint32 if raw.dtype.kind == "U" else np.uint8
+    mat = np.ascontiguousarray(raw).view(unit).reshape(n, -1)
+    h0 = np.full(n, ingest._FNV_OFFSETS[0])
+    h1 = np.full(n, ingest._FNV_OFFSETS[1])
+    for j in range(mat.shape[1]):
+        col = mat[:, j].astype(np.uint64)
+        live = col != 0
+        step0 = (h0 ^ (col + np.uint64(0x9E3779B9 * (j + 1)))) * \
+            ingest._FNV_PRIME
+        step1 = (h1 ^ (col + np.uint64(0xC2B2AE35 * (j + 2)))) * \
+            ingest._FNV_PRIME
+        h0 = np.where(live, step0, h0)
+        h1 = np.where(live, step1, h1)
+    return ingest._splitmix64(h0, 0), ingest._splitmix64(h1, 1)
+
+
 class TestHashKeyColumn:
 
     def test_deterministic_and_lane_independent(self):
@@ -114,6 +135,31 @@ class TestHashKeyColumn:
         # And character ORDER still matters.
         assert ingest.hash_key_column(np.asarray(["ab"]))[0] != \
             ingest.hash_key_column(np.asarray(["ba"]))[0]
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_string_hash_is_the_per_column_fnv(self, seed):
+        """The ragged hash (keys longest first, a position steps only the
+        keys longer than it) gives what the loop over every column of
+        the fixed-width matrix gave, on 'U', 'S' and object columns of
+        str: interior NULs, empty keys, astral code points, lone
+        surrogates, widths far past the longest key."""
+        rng = np.random.default_rng(seed)
+        alphabet = list("abcxyz \x00\u00e9\u6f22\U0001f600\ud800")
+        keys = ["".join(rng.choice(alphabet, int(rng.integers(0, 40))))
+                for _ in range(300)]
+        objects = np.array(keys + [None], dtype=object)[:-1]
+        fixed = objects.astype(np.str_)
+        want = _fnv_per_column(fixed)
+        for column in (fixed, fixed.astype("<U90"), objects):
+            got = ingest.hash_key_column_pair(column)
+            top = np.uint64((1 << 64) - 2)
+            assert all(np.array_equal(g, np.minimum(w, top))
+                       for g, w in zip(got, want))
+        as_bytes = np.array([k.encode("utf-8", "surrogatepass")
+                             for k in keys], dtype="S")
+        assert all(np.array_equal(g, w) for g, w in zip(
+            ingest._vector_hash_fixed_width(as_bytes),
+            _fnv_per_column(as_bytes)))
 
     def test_composite_tuple_keys_stable(self):
         raw = np.empty(3, object)
@@ -196,6 +242,52 @@ class TestMergeHashUniques:
         assert list(k) == ["c", "a", "b"]
         assert n == 3
         assert list(p) == [2, 0, 1]
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_one_sort_equals_the_lexsort(self, seed):
+        """Chunk uniques in stream order, merged by the one stable sort
+        of h1, give the table a lexsort of (h1, h2, pos) gives: each
+        hash once, with its first key and position."""
+        rng = np.random.default_rng(seed)
+        n = 2000
+        h1 = rng.integers(0, 300, n).astype(np.uint64) * np.uint64(2**40)
+        h2 = h1 ^ np.uint64(12345)
+        raw = np.array([f"k{int(h)}-{i}" for i, h in enumerate(h1)],
+                       dtype=object)
+        bounds = [0, 400, 900, 1500, n]
+        chunks = [(ingest._hash_uniques(h1[a:b], h2[a:b], raw[a:b]), a)
+                  for a, b in zip(bounds, bounds[1:])]
+        parts = ([c[0] for c, _ in chunks], [c[1] for c, _ in chunks],
+                 [c[2] for c, _ in chunks], [c[3] + a for c, a in chunks])
+        got = device_encode.merge_hash_uniques(*parts)
+        c1, c2, ck, cp = (np.concatenate(p) for p in parts)
+        order = np.lexsort((cp, c2, c1))
+        new = np.r_[True, c1[order][1:] != c1[order][:-1]]
+        assert got[2] == int(new.sum()) == len(np.unique(h1))
+        for g, w in ((got[0], c1), (got[1], ck), (got[3], cp)):
+            assert np.array_equal(g, w[order][new])
+        # The first occurrence: the key of each hash's lowest row.
+        first = {}
+        for i, h in enumerate(h1):
+            first.setdefault(int(h), raw[i])
+        assert list(got[1]) == [first[int(h)] for h in got[0]]
+
+    def test_collision_inside_a_chunk_raises(self):
+        h1 = np.array([5, 5, 7], np.uint64)
+        h2 = np.array([1, 2, 3], np.uint64)
+        u = ingest._hash_uniques(h1, h2, None)
+        assert list(u[0]) == [5, 5, 7] and list(u[1]) == [1, 2, 3]
+        with pytest.raises(device_encode.HashCollisionError,
+                           match="primary hash 5"):
+            device_encode.merge_hash_uniques([u[0]], [u[1]])
+        # The two keys' rows interleaved: a pair is kept more than once,
+        # and the merge still names the collision.
+        u = ingest._hash_uniques(np.array([5, 5, 5, 7], np.uint64),
+                                 np.array([1, 2, 1, 3], np.uint64), None)
+        assert list(u[1]) == [1, 2, 1, 3]
+        with pytest.raises(device_encode.HashCollisionError,
+                           match="primary hash 5"):
+            device_encode.merge_hash_uniques([u[0]], [u[1]])
 
     def test_collision_raises(self):
         h1 = [np.array([5], np.uint64), np.array([5], np.uint64)]
@@ -571,3 +663,30 @@ class TestAccumulatorFills:
         assert bufs[0].shape[0] == executor.row_bucket(5)
         tail = np.asarray(bufs[0])[5:]
         assert (tail == sent).all()
+
+    def test_wide_rows_donated_equal_staged(self):
+        """(n, 3) hash rows cross flat and take their shape on the device
+        (the first chunk, and every append after it): the donated buffers
+        equal the staged ones."""
+        sent = int(device_encode._U32_MAX)
+        fills = (sent, sent, 0)
+        rng = np.random.default_rng(4)
+        out = {}
+        for donate in (False, True):
+            acc = rt_pipeline.DeviceRowAccumulator(donate=donate,
+                                                   fills=fills)
+            for n in (5, 11, 3):
+                h = device_encode.pack_hash_rows(ingest.hash_key_column(
+                    rng.integers(0, 9, n)))
+                k = device_encode.pack_hash_rows(ingest.hash_key_column(
+                    rng.integers(0, 4, n)))
+                v = rng.random(n)
+                if donate:
+                    h, k, v = ingest._pad_chunk_rows(
+                        h, k, v, executor.row_bucket(n), fills)
+                acc.append(h, k, v, n)
+            out[donate] = [np.asarray(b) for b in acc.finalize()]
+            rng = np.random.default_rng(4)
+        for staged, donated in zip(out[False], out[True]):
+            assert staged.shape == donated.shape
+            assert np.array_equal(staged, donated)

@@ -17,8 +17,10 @@ again on a four-device mesh, once sound (the collective reshard) and once
 with the `collective` fault rt_faults has (the host fallback), records
 the mesh layer's two spans. A sixth, a blocked standalone selection
 through `DPEngine.select_partitions`, records the spans and counters the
-cell keys1e7-select-blocked reads. A rename in the program then fails
-here instead of leaving a null in the ledger.
+cell keys1e7-select-blocked reads. A seventh, string keys through
+`ChunkSource(encode_mode="hash_device")` on the dense route and on the
+blocked one, records what the two hash-route cells read. A rename in the
+program then fails here instead of leaving a null in the ledger.
 """
 
 import json
@@ -241,6 +243,48 @@ def _blocked_select_run():
     assert counted["release_dispatches"] == 4 + 1  # four blocks, one drain
 
 
+def _hash_text_runs():
+    """String partition keys (some 60 characters wide) and integer ids
+    through `ChunkSource(encode_mode="hash_device")`, once on the dense
+    route and once on the blocked one (a threshold of 64, four blocks of
+    64): the unique merge, the device codes and the hash route's two
+    counters, and on the blocked route pass 1 fed from the streamed
+    device-resident rows."""
+    rng = np.random.default_rng(8)
+    n, P = 6000, 256
+    words = np.array([f"query {k} " + "x" * (k % 50) for k in range(P)],
+                     dtype=object)
+    pid = rng.integers(0, 400, n).astype(np.int32)
+    pk = words[(P * rng.random(n) ** 2).astype(np.int64)]
+    values = rng.uniform(0, 2, n)
+    chunks = [(pid[i:i + 1500], pk[i:i + 1500], values[i:i + 1500])
+              for i in range(0, n, 1500)]
+    params = pdp.AggregateParams(
+        metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+        noise_kind=pdp.NoiseKind.LAPLACE,
+        max_partitions_contributed=4,
+        max_contributions_per_partition=1,
+        min_value=0.0,
+        max_value=2.0)
+    ex = pdp.DataExtractors(privacy_id_extractor=lambda r: r[0],
+                            partition_extractor=lambda r: r[1],
+                            value_extractor=lambda r: r[2])
+    for route in ({}, dict(large_partition_threshold=64,
+                           block_partitions=64)):
+        acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0,
+                                        total_delta=1e-3)
+        engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=9, **route))
+        before = telemetry.snapshot()
+        result = engine.aggregate(
+            pdp.ChunkSource(chunks, encode_mode="hash_device"), params, ex)
+        acc.compute_budgets()
+        assert set(dict(result)) <= set(words)
+        counted = telemetry.delta(before)
+        assert counted["hash_key_bytes"] == 4 * sum(map(len, pk))
+        assert counted["hash_unique_entries"] > P
+        assert ("pass1_device_resident" in counted) == bool(route)
+
+
 # Counters no tiny run is sure to move: a program already built in this
 # process fires no compile event.
 UNREACHED_COUNTERS = {"backend_compiles"}
@@ -259,6 +303,7 @@ def recorded():
         _blocked_run(row_chunk=None)
         _meshed_chunk_runs()
         _blocked_select_run()
+        _hash_text_runs()
         counters = {name for name, n in telemetry.snapshot().items() if n}
         return set(trace.trace_summary()["spans"]) | counters
     finally:
