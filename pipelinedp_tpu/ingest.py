@@ -902,9 +902,18 @@ def _stream_encode_hash_device(chunks, public_partitions, nonfinite,
             rt_telemetry.record("pipeline_device_encode_chunks",
                                 chunk=idx)
         ingest_span.set(rows=n_rows)
+        with rt_trace.span("ingest.finalize"):
+            bufs = acc.finalize()
+        # On an accelerator the factorize needs only the hash rows, so
+        # it is queued on the device BEFORE the host merge below and
+        # runs under it; the lookup kernel needs the merged table first.
+        factorized = None
+        if bufs is not None and not device_encode.prefers_lookup_codes():
+            factorized = _dispatch_hash_factorize(bufs[0], bufs[1], public)
         # Collision safety gate: nothing derived from the device codes
         # is released past this point unless every primary hash maps to
-        # exactly one (secondary hash, key) identity.
+        # exactly one (secondary hash, key) identity — on a collision
+        # the dispatched codes are dropped unread.
         try:
             with rt_trace.span("ingest.unique_merge"):
                 pid_table = device_encode.merge_hash_uniques(
@@ -931,23 +940,46 @@ def _stream_encode_hash_device(chunks, public_partitions, nonfinite,
                 chunks, public_partitions=public_partitions,
                 nonfinite=nonfinite, encode_threads=encode_threads,
                 pipeline_depth=pipeline_depth, encode_mode="host")
-        with rt_trace.span("ingest.finalize"):
-            bufs = acc.finalize()
         if bufs is None:
             return _hash_empty_encoded(public, value_dtype,
                                        partition_vocab)
-        pid_hash, pk_col, values = bufs
-        return _finalize_hash_codes(pid_hash, pk_col, values, public,
-                                    partition_vocab, pid_table, pk_table)
+        return _finalize_hash_codes(*bufs, public, partition_vocab,
+                                    pid_table, pk_table, factorized)
+
+
+def _dispatch_hash_factorize(pid_hash, pk_col, public: bool):
+    """Queues the device sort/unique factorize of both key columns
+    (device_encode.factorize_codes; the public partition column is
+    already coded) and returns (pid codes, pk codes, distinct counts)
+    as device arrays, unread: jit dispatch does not block, so the host
+    goes on to the unique merge while the device sorts."""
+    import jax.numpy as jnp
+
+    from pipelinedp_tpu import device_encode
+    from pipelinedp_tpu.runtime import trace as rt_trace
+
+    with rt_trace.span("ingest.device_codes"):
+        pid_codes, n_pid = device_encode.factorize_codes(pid_hash)
+        if public:
+            return pid_codes, pk_col, jnp.stack([n_pid])
+        pk, n_pk = device_encode.factorize_codes(pk_col)
+        return pid_codes, pk, jnp.stack([n_pid, n_pk])
 
 
 def _finalize_hash_codes(pid_hash, pk_col, values, public: bool,
-                         partition_vocab, pid_table, pk_table
-                         ) -> columnar.EncodedData:
+                         partition_vocab, pid_table, pk_table,
+                         factorized) -> columnar.EncodedData:
     """Device code assignment + deferred-decode vocabulary of the hash
     stream route (runs inside the ingest span, under its own sub-span
     so the e2e phase breakdown separates in-jit code assignment from
-    the host hashing)."""
+    the host hashing).
+
+    Two interchangeable in-jit code-assignment kernels (identical
+    codes): the self-contained sort/unique factorize on accelerators,
+    already dispatched before the merge (``factorized``, from
+    _dispatch_hash_factorize), and the host-table binary-search lookup
+    on CPU (``factorized`` None), where XLA's comparator sort is the
+    wrong tool — see device_encode.prefers_lookup_codes."""
     import jax.numpy as jnp
 
     from pipelinedp_tpu import device_encode
@@ -955,47 +987,38 @@ def _finalize_hash_codes(pid_hash, pk_col, values, public: bool,
     from pipelinedp_tpu.runtime import trace as rt_trace
 
     with rt_trace.span("ingest.device_codes"):
-        # Two interchangeable in-jit code-assignment kernels (identical
-        # codes): the self-contained sort/unique factorize on
-        # accelerators, the host-table binary-search lookup on CPU,
-        # where XLA's comparator sort is the wrong tool — see
-        # device_encode.prefers_lookup_codes.
-        lookup = device_encode.prefers_lookup_codes()
-        if lookup:
+        pk = pk_col
+        if factorized is not None:
+            pid_codes, pk, n_dev = factorized
+        else:
             pid_codes = device_encode.lookup_codes(
                 pid_hash,
                 *device_encode.build_lookup_table(pid_table[0],
                                                   pid_table[3]))
             n_privacy_ids = pid_table[2]
-        else:
-            pid_codes, n_pid_dev = device_encode.factorize_codes(
-                pid_hash)
+            if not public:
+                pk = device_encode.lookup_codes(
+                    pk_col, *device_encode.build_lookup_table(
+                        pk_table[0], pk_table[3]))
         if public:
-            if not lookup:
-                n_privacy_ids = int(mesh_lib.host_fetch(n_pid_dev))
             vocab = partition_vocab
-            pk = pk_col
         else:
             s1, keys, n_pk, pos = pk_table
-            if lookup:
-                pk = device_encode.lookup_codes(
-                    pk_col, *device_encode.build_lookup_table(s1, pos))
-            else:
-                pk, n_pk_dev = device_encode.factorize_codes(pk_col)
-                n_stats = mesh_lib.host_fetch(jnp.stack([n_pid_dev,
-                                                         n_pk_dev]))
-                n_privacy_ids = int(n_stats[0])
-                if int(n_stats[1]) != n_pk:
-                    raise RuntimeError(
-                        f"device factorize found {int(n_stats[1])} "
-                        f"distinct partition hashes but the host unique "
-                        f"merge found {n_pk} (internal invariant)")
             # Code order (global first occurrence) is host-derivable
             # from the chunk uniques' positions — decode then needs
             # zero device->host traffic.
             vocab = device_encode.HashVocab(
                 n_pk, s1, keys,
                 hash_by_code_host=s1[np.argsort(pos, kind="stable")])
+        if factorized is not None:
+            # The one wait on the device's factorize, after all host work.
+            n_stats = mesh_lib.host_fetch(n_dev)
+            n_privacy_ids = int(n_stats[0])
+            if not public and int(n_stats[1]) != n_pk:
+                raise RuntimeError(
+                    f"device factorize found {int(n_stats[1])} "
+                    f"distinct partition hashes but the host unique "
+                    f"merge found {n_pk} (internal invariant)")
         # Pad rows factorize to -1; the pad_rows convention is pid 0.
         pid = jnp.maximum(pid_codes, 0)
         return columnar.EncodedData(pid=pid, pk=pk, values=values,

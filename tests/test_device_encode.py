@@ -302,9 +302,20 @@ class TestMergeHashUniques:
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture(params=["lookup", "factorize"])
+def code_path(request, monkeypatch):
+    """Forces the hash route's in-jit code assignment. The CPU backend
+    picks the lookup kernel by itself, so without this the tests would
+    never run the accelerator's order: factorize dispatched before the
+    host unique merge, its counts read after it."""
+    monkeypatch.setattr(device_encode, "prefers_lookup_codes",
+                        lambda: request.param == "lookup")
+    return request.param
+
+
 class TestStreamEncodeParity:
 
-    def test_kernel_inputs_bit_identical(self):
+    def test_kernel_inputs_bit_identical(self, code_path):
         pids, pks, vals = _stream()
         host = ingest.stream_encode_columns(_chunks(pids, pks, vals))
         dev = ingest.stream_encode_columns(_chunks(pids, pks, vals),
@@ -313,7 +324,8 @@ class TestStreamEncodeParity:
         assert list(dev.partition_vocab) == list(host.partition_vocab)
 
     @pytest.mark.parametrize("threads,depth", [(1, 1), (2, 8)])
-    def test_pipelined_hash_encode_identical(self, threads, depth):
+    def test_pipelined_hash_encode_identical(self, threads, depth,
+                                            code_path):
         pids, pks, vals = _stream()
         serial = ingest.stream_encode_columns(
             _chunks(pids, pks, vals), encode_mode="hash_device")
@@ -323,7 +335,7 @@ class TestStreamEncodeParity:
         for a, b in zip(_padded(serial), _padded(piped)):
             assert np.array_equal(a, b)
 
-    def test_nonfinite_drop_keeps_code_alignment(self):
+    def test_nonfinite_drop_keeps_code_alignment(self, code_path):
         pids, pks, vals = _stream()
         vals = vals.copy()
         vals[2] = np.nan  # early drop: later codes must not shift
@@ -335,7 +347,7 @@ class TestStreamEncodeParity:
             encode_mode="hash_device")
         _assert_kernel_input_parity(host, dev)
 
-    def test_public_partitions(self):
+    def test_public_partitions(self, code_path):
         pids, pks, vals = _stream()
         public = [f"p{i}" for i in range(20)]
         host = ingest.stream_encode_columns(
@@ -347,13 +359,13 @@ class TestStreamEncodeParity:
         assert dev.public_encoded and \
             list(dev.partition_vocab) == public
 
-    def test_empty_stream(self):
+    def test_empty_stream(self, code_path):
         enc = ingest.stream_encode_columns([],
                                            encode_mode="hash_device")
         assert enc.n_rows == 0 and len(enc.partition_vocab) == 0
         assert enc.n_privacy_ids == 0
 
-    def test_hash_vocab_decodes_lazily(self):
+    def test_hash_vocab_decodes_lazily(self, code_path):
         pids, pks, vals = _stream()
         host = ingest.stream_encode_columns(_chunks(pids, pks, vals))
         dev = ingest.stream_encode_columns(_chunks(pids, pks, vals),
@@ -435,10 +447,34 @@ def _collide_keys(monkeypatch, victim="p1", target="p0"):
     monkeypatch.setattr(ingest, "hash_key_column_pair", colliding)
 
 
+def _record_calls(monkeypatch):
+    """Wraps the hash route's four steps of code assignment so a test
+    reads the order the route called them in: "factorize" and "lookup"
+    (the two in-jit kernels), "merge" (the host unique merge) and
+    "fetch" (the device->host read of the distinct counts)."""
+    from pipelinedp_tpu.parallel import mesh as mesh_lib
+    calls = []
+
+    def wrap(owner, attr, label):
+        fn = getattr(owner, attr)
+
+        def recorded(*args, **kwargs):
+            calls.append(label)
+            return fn(*args, **kwargs)
+
+        monkeypatch.setattr(owner, attr, recorded)
+
+    wrap(device_encode, "factorize_codes", "factorize")
+    wrap(device_encode, "lookup_codes", "lookup")
+    wrap(device_encode, "merge_hash_uniques", "merge")
+    wrap(mesh_lib, "host_fetch", "fetch")
+    return calls
+
+
 class TestCollisionSafety:
 
     def test_detector_trips_counts_and_falls_back_bit_identically(
-            self, monkeypatch):
+            self, monkeypatch, code_path):
         pids, pks, vals = _stream()
         host = ingest.stream_encode_columns(_chunks(pids, pks, vals))
         _collide_keys(monkeypatch)
@@ -453,9 +489,11 @@ class TestCollisionSafety:
         assert np.array_equal(np.asarray(enc.pk), np.asarray(host.pk))
         assert list(enc.partition_vocab) == list(host.partition_vocab)
 
-    def test_one_shot_iterator_raises_actionably(self, monkeypatch):
+    def test_one_shot_iterator_raises_actionably(self, monkeypatch,
+                                                code_path):
         pids, pks, vals = _stream()
         _collide_keys(monkeypatch)
+        calls = _record_calls(monkeypatch)
         before = rt_telemetry.snapshot()
         with pytest.raises(device_encode.HashCollisionError,
                            match="one-shot iterator"):
@@ -464,8 +502,13 @@ class TestCollisionSafety:
                 encode_mode="hash_device")
         assert rt_telemetry.delta(before).get(
             "ingest_hash_collisions", 0) == 1
+        # No device code was used: a factorize dispatched before the
+        # merge is dropped with its counts never read.
+        assert "merge" in calls
+        assert "fetch" not in calls and "lookup" not in calls
 
-    def test_privacy_id_collision_also_trips(self, monkeypatch):
+    def test_privacy_id_collision_also_trips(self, monkeypatch,
+                                             code_path):
         pids, pks, vals = _stream()
         _collide_keys(monkeypatch, victim="u1", target="u2")
         before = rt_telemetry.snapshot()
@@ -490,6 +533,57 @@ class TestCollisionSafety:
         assert np.array_equal(np.asarray(enc.pid), np.asarray(host.pid))
         assert np.array_equal(np.asarray(enc.pk), np.asarray(host.pk))
         assert list(enc.partition_vocab) == list(host.partition_vocab)
+
+
+class TestFinalizeOrder:
+    """The hash route's end on an accelerator: both factorizes are
+    queued on the device before the host merges the chunk uniques, and
+    the counts are read only after the merge. The lookup kernel needs
+    the merged table, so there the merge comes first."""
+
+    @pytest.mark.parametrize("public,expected", [
+        (False, {"factorize": ["factorize", "factorize", "merge", "merge",
+                               "fetch"],
+                 "lookup": ["merge", "merge", "lookup", "lookup"]}),
+        (True, {"factorize": ["factorize", "merge", "fetch"],
+                "lookup": ["merge", "lookup"]}),
+    ])
+    def test_call_order(self, monkeypatch, code_path, public, expected):
+        pids, pks, vals = _stream()
+        calls = _record_calls(monkeypatch)
+        ingest.stream_encode_columns(
+            _chunks(pids, pks, vals), encode_mode="hash_device",
+            public_partitions=([f"p{i}" for i in range(20)]
+                               if public else None))
+        assert calls == expected[code_path]
+
+    def test_merge_and_codes_spans_are_siblings(self, code_path):
+        from pipelinedp_tpu.runtime import trace as rt_trace
+        pids, pks, vals = _stream()
+        with rt_trace.scoped():
+            seen = len(rt_trace.to_trace_events()["traceEvents"])
+            ingest.stream_encode_columns(_chunks(pids, pks, vals),
+                                         encode_mode="hash_device")
+            events = rt_trace.to_trace_events()["traceEvents"][seen:]
+        spans = [e for e in events if e["ph"] == "X"]
+        merge = [e for e in spans if e["name"] == "ingest.unique_merge"]
+        codes = [e for e in spans if e["name"] == "ingest.device_codes"]
+        assert len(merge) == 1
+        assert len(codes) == (2 if code_path == "factorize" else 1)
+        ids = {e["args"]["id"] for e in merge + codes}
+        assert all(e["args"].get("parent") not in ids
+                   for e in merge + codes)
+        # Both hang off the one ingest span, in the route's order.
+        (ingest_span,) = [e for e in spans if e["name"] == "ingest"]
+        assert {e["args"]["parent"] for e in merge + codes} == \
+            {ingest_span["args"]["id"]}
+        starts = sorted(merge + codes, key=lambda e: e["ts"])
+        order = [e["name"] for e in starts]
+        if code_path == "factorize":
+            assert order == ["ingest.device_codes", "ingest.unique_merge",
+                             "ingest.device_codes"]
+        else:
+            assert order == ["ingest.unique_merge", "ingest.device_codes"]
 
 
 # ---------------------------------------------------------------------------
