@@ -386,9 +386,7 @@ def dense_phases(pdp, sizes, seed, tmpdir):
             n_movies=sizes.movies, seed=seed))
 
     def file_release():
-        source = pdp.ChunkSource(
-            (u, m, r.astype(np.float32))
-            for u, m, r in netflix_format.parse_file_chunks(path))
+        source = pdp.ChunkSource.from_netflix_file(path)
         return aggregate(pdp, pdp.TPUBackend(noise_seed=seed), source,
                          params, eps=1.0)
 

@@ -8,21 +8,23 @@ File format (reference examples/movie_view_ratings/common_utils.py:33-60):
     <next_movie_id>:
     ...
 
-Parsing is vectorized: lines are split into a string array, header lines
-are detected in one pass, and each data line picks up its movie id by a
-cumulative-header index — no per-line Python loop, feeding straight into
-the columnar ingest path (pipelinedp_tpu.columnar.encode_columns).
-parse_file_chunks streams the same parse in bounded-memory chunks for the
-overlapped ingest pipeline (pipelinedp_tpu.ingest).
+Parsing is the package's one reader of the format,
+pipelinedp_tpu.textfile (numpy over the file's bytes, no Python object
+per line); the functions here are the example's thin API over it. A
+pipeline that aggregates a file hands the engine
+``pdp.ChunkSource.from_netflix_file(path)`` instead, which parses the
+file's byte ranges on the encode workers.
 """
 
-import re
+import os
 from dataclasses import dataclass
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
-Columns = Tuple[np.ndarray, np.ndarray, np.ndarray]
+from pipelinedp_tpu import textfile
+
+Columns = textfile.Columns
 
 
 @dataclass
@@ -32,103 +34,19 @@ class MovieView:
     rating: int
 
 
-def _parse_lines(lines: np.ndarray, last_movie: Optional[int],
-                 context: str) -> Tuple[Optional[Columns], Optional[int]]:
-    """Vectorized parse of a line array; `last_movie` is the header carried
-    in from the previous chunk (None at file start)."""
-    lines = lines[np.char.str_len(lines) > 0]
-    if len(lines) == 0:
-        return None, last_movie
-    is_header = np.char.endswith(lines, ":")
-    headers = np.char.rstrip(lines[is_header], ":").astype(np.int64)
-    # Each data line belongs to the most recent header above it (index -1 =
-    # the carried-in header from the previous chunk).
-    movie_of_line = np.cumsum(is_header) - 1
-    data_mask = ~is_header
-    if last_movie is None and bool((movie_of_line[data_mask] < 0).any()):
-        raise ValueError(
-            f"{context}: data lines before the first 'movie_id:' header")
-    table = np.concatenate(
-        [[last_movie if last_movie is not None else -1], headers])
-    movie_col = table[movie_of_line[data_mask] + 1]
-    data_lines = lines[data_mask]
-    if len(data_lines) == 0:
-        cols = None
-    else:
-        # "user_id,rating,date" -> first two comma-separated fields.
-        first = np.char.partition(data_lines, ",")
-        users = first[:, 0].astype(np.int64)
-        ratings = np.char.partition(first[:, 2], ",")[:, 0].astype(np.int64)
-        cols = (users, movie_col, ratings)
-    new_last = int(headers[-1]) if len(headers) else last_movie
-    return cols, new_last
+def _no_header(filename: str) -> ValueError:
+    return ValueError(f"{filename}: no 'movie_id:' header lines found")
 
 
 def parse_file_columns(filename: str) -> Columns:
     """Parses a Netflix-format file into (user_ids, movie_ids, ratings)."""
-    with open(filename) as f:
-        lines = np.array(f.read().split("\n"))
-    cols, last = _parse_lines(lines, None, filename)
-    if last is None:
-        raise ValueError(f"{filename}: no 'movie_id:' header lines found")
-    if cols is None:
-        empty = np.zeros(0, np.int64)
-        return empty, empty.copy(), empty.copy()
-    return cols
-
-
-_HEADER_RE = re.compile(rb"(?m)^\d+:\r?$")
-
-
-def _next_header_offset(filename: str, pos: int,
-                        limit: Optional[int]) -> Optional[int]:
-    """Byte offset of the first 'movie_id:' header line starting at or
-    after `pos` (snapped forward to a line start) and before `limit`
-    (None = end of file). None if no such header exists.
-
-    Chunked binary scan with one regex search per chunk — a single movie
-    section spanning many shards would otherwise cost per-line Python
-    readline loops on exactly the multi-million-row files this path is
-    for.
-    """
     with open(filename, "rb") as f:
-        if pos > 0:
-            f.seek(pos - 1)
-            if f.read(1) != b"\n":
-                # Snap forward to the next line start, chunked.
-                while True:
-                    chunk = f.read(1 << 16)
-                    if not chunk:
-                        return None
-                    i = chunk.find(b"\n")
-                    if i != -1:
-                        f.seek(f.tell() - (len(chunk) - i - 1))
-                        break
-        carry = b""
-        carry_off = f.tell()
-        while True:
-            buf = f.read(1 << 20)
-            if not buf:
-                # Last line may lack a trailing newline.
-                if carry and _HEADER_RE.fullmatch(carry.rstrip(b"\r")):
-                    if limit is None or carry_off < limit:
-                        return carry_off
-                return None
-            buf = carry + buf
-            cut = buf.rfind(b"\n")
-            if cut == -1:
-                carry = buf
-                continue
-            m = _HEADER_RE.search(buf[:cut + 1])
-            if m:
-                off = carry_off + m.start()
-                if limit is not None and off >= limit:
-                    return None
-                return off
-            carry = buf[cut + 1:]
-            carry_off += cut + 1
-            if limit is not None and carry_off >= limit:
-                return None
+        data = f.read()
+    # A file of no line but empty ones has no header; any other line is
+    # a header or (before the first header) an error.
+    if not data.strip(b"\r\n"):
+        raise _no_header(filename)
+    return textfile.parse_netflix(data, filename)
 
 
 def parse_file_chunks(
@@ -136,10 +54,9 @@ def parse_file_chunks(
         chunk_bytes: int = 1 << 24,
         byte_range: Optional[Tuple[int, int]] = None) -> Iterator[Columns]:
     """Streams (user_ids, movie_ids, ratings) column chunks from a
-    Netflix-format file in bounded memory.
-
-    Chunks split at line boundaries; the current movie header carries
-    across chunks, so concatenating all chunks equals parse_file_columns.
+    Netflix-format file in bounded memory: the rows of the movie sections
+    whose header starts in each `chunk_bytes` of the file, so
+    concatenating all chunks equals parse_file_columns.
 
     byte_range=(lo, hi) parses one HOST SHARD for multi-process ingest
     (ingest.encode_shard): the shard owns every movie section whose
@@ -149,52 +66,19 @@ def parse_file_chunks(
     of a contiguous cover of the file equals the whole-file parse, with
     every line parsed exactly once.
     """
-    start_off, end_off = 0, None
-    if byte_range is not None:
-        lo, hi = byte_range
-        start_off = _next_header_offset(filename, lo, hi)
-        if start_off is None:
-            return  # no section starts in this shard
-        end_off = _next_header_offset(filename, hi, None)
-    last_movie: Optional[int] = None
-    carry = b""
-    # Binary reads throughout: the range offsets come from the binary
-    # header probe, and text-mode universal-newline translation would
-    # make len(buf) undercount CRLF files against those byte offsets.
-    with open(filename, "rb") as f:
-        f.seek(start_off)
-        remaining = None if end_off is None else end_off - start_off
-        while True:
-            to_read = (chunk_bytes if remaining is None else min(
-                chunk_bytes, remaining))
-            if to_read <= 0:
-                break
-            buf = f.read(to_read)
-            if not buf:
-                break
-            if remaining is not None:
-                remaining -= len(buf)
-            buf = carry + buf
-            cut = buf.rfind(b"\n")
-            if cut == -1:
-                carry = buf
-                continue
-            carry = buf[cut + 1:]
-            # Decoding after the cut at a newline keeps multi-byte UTF-8
-            # sequences intact (no continuation byte equals \n).
-            text = buf[:cut].decode().replace("\r", "")
-            cols, last_movie = _parse_lines(np.array(text.split("\n")),
-                                            last_movie, filename)
-            if cols is not None:
-                yield cols
-    if carry:
-        text = carry.decode().replace("\r", "")
-        cols, last_movie = _parse_lines(np.array([text]), last_movie,
-                                        filename)
-        if cols is not None:
-            yield cols
-    if last_movie is None:
-        raise ValueError(f"{filename}: no 'movie_id:' header lines found")
+    lo, hi = (0, os.path.getsize(filename)) if byte_range is None \
+        else byte_range
+    rows = 0
+    for start in range(lo, hi, chunk_bytes):
+        columns = textfile.read_netflix_range(
+            filename, start, min(start + chunk_bytes, hi))
+        rows += len(columns[0])
+        if len(columns[0]):
+            yield columns
+    if byte_range is None and not rows:
+        with open(filename, "rb") as f:
+            if not f.read().strip(b"\r\n"):
+                raise _no_header(filename)
 
 
 def parse_file(filename: str):

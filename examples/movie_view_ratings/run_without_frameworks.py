@@ -9,7 +9,10 @@ report, write results to a file.
 TPU-first difference: by default the aggregation runs on the fused columnar
 device backend (pipelinedp_tpu.TPUBackend) — one jit-compiled XLA program —
 on the accelerator JAX finds (JAX_PLATFORMS=cpu runs it CPU-only), and
-file parsing is vectorized (netflix_format.parse_file_columns).
+file parsing is vectorized (pipelinedp_tpu.textfile). With --streaming
+the engine is handed the file itself
+(pdp.ChunkSource.from_netflix_file): its byte ranges are read and parsed
+on the encode workers while earlier ranges are encoded and uploaded.
 
 Usage:
     # With the real dataset:
@@ -48,9 +51,11 @@ def main():
                         help="pure-Python local backend instead of the fused "
                         "device backend")
     parser.add_argument("--streaming", action="store_true",
-                        help="chunked overlapped ingest (parse/factorize "
-                        "each file chunk while the previous chunk uploads; "
-                        "pipelinedp_tpu.ingest) — device backend only")
+                        help="hand the engine the file: its byte ranges "
+                        "are parsed and encoded on the encode workers "
+                        "while earlier ones upload "
+                        "(pdp.ChunkSource.from_netflix_file) — device "
+                        "backend only")
     parser.add_argument("--epsilon", type=float, default=1.0)
     parser.add_argument("--delta", type=float, default=1e-6)
     args = parser.parse_args()
@@ -67,12 +72,9 @@ def main():
     if args.streaming:
         if args.local:
             parser.error("--streaming requires the device backend")
-        from pipelinedp_tpu import ingest
-        movie_views = ingest.stream_encode_columns(
-            ((u, m, r.astype("float32"))
-             for u, m, r in netflix_format.parse_file_chunks(input_file)),
-            public_partitions=public_partitions)
-        print(f"streamed {movie_views.n_rows} movie views to device")
+        movie_views = pdp.ChunkSource.from_netflix_file(input_file)
+        print(f"streaming {input_file} in {len(movie_views.chunks)} "
+              f"byte ranges")
     else:
         movie_views = netflix_format.parse_file(input_file)
         print(f"parsed {len(movie_views)} movie views")

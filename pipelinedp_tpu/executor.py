@@ -1561,7 +1561,8 @@ def stream_chunk_source(backend, source, public_list=None):
             nonfinite=source.nonfinite,
             encode_threads=threads,
             pipeline_depth=backend.pipeline_depth,
-            encode_mode=encode_mode)
+            encode_mode=encode_mode,
+            load=source._load)
 
 
 def _encode_input(backend, rows, data_extractors, public_list=None):

@@ -37,7 +37,8 @@ owns that axis).
 import dataclasses
 import logging
 from collections import Counter as collections_counter
-from typing import Any, Iterable, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -327,6 +328,15 @@ def _prepare_chunk(chunk, partition_vocab, nonfinite,
         values = np.where(mask, 0.0, values).astype(value_dtype)
     return _PreparedChunk(pid_codes, pid_uniques, pk_codes, pk_uniques,
                           values)
+
+
+def _loaded(load, worker):
+    """The encode worker of a source whose items are loaded where they are
+    encoded (ChunkSource.from_netflix_file's byte ranges): `worker`
+    applied to `load(item)`."""
+    if load is None:
+        return worker
+    return lambda item: worker(load(item))
 
 
 def _pad_chunk_rows(pid, pk, values, cap: int, fills=(0, -1, 0)):
@@ -650,9 +660,15 @@ def stream_encode_columns(
         nonfinite: str = "error",
         encode_threads: int = 0,
         pipeline_depth: Optional[int] = None,
-        encode_mode: str = "host") -> columnar.EncodedData:
+        encode_mode: str = "host",
+        load: Optional[Callable[[Any], Any]] = None) -> columnar.EncodedData:
     """Encodes and uploads (pid_raw, pk_raw, values) column chunks,
     overlapping each chunk's device copy with the next chunk's parsing.
+
+    load: None, or the function that turns one item of `chunks` into its
+    columns (the byte-range reader of ChunkSource.from_netflix_file); it
+    runs where the item is encoded, on the encode worker when
+    encode_threads >= 1, not on the feeder thread.
 
     encode_threads=0 (the default) is the serial path: one loop,
     device copies overlapping the next chunk's parse only through jax's
@@ -696,7 +712,7 @@ def stream_encode_columns(
     if encode_mode == "hash_device":
         return _stream_encode_hash_device(chunks, public_partitions,
                                           nonfinite, encode_threads,
-                                          pipeline_depth)
+                                          pipeline_depth, load)
     value_dtype = np.dtype(executor._ftype())
 
     pid_enc = ChunkedVocabEncoder()
@@ -718,14 +734,15 @@ def stream_encode_columns(
         return _stream_encode_pipelined(chunks, partition_vocab, nonfinite,
                                         value_dtype, pid_enc, pk_enc,
                                         encoded_data, encode_threads,
-                                        pipeline_depth)
+                                        pipeline_depth, load)
 
     dev_pid, dev_pk, dev_vals = [], [], []
     # The ingest span covers parse+factorize+upload for the whole stream;
     # its row count attribute lets trace summaries report ingest rate.
     with rt_trace.span("ingest") as ingest_span:
         n_rows = 0
-        for pid_raw, pk_raw, values in chunks:
+        for pid_raw, pk_raw, values in (chunks if load is None else
+                                        map(load, chunks)):
             pid = pid_enc.encode(pid_raw)
             if partition_vocab is not None:
                 pk = columnar.encode_with_vocab(
@@ -758,8 +775,8 @@ def stream_encode_columns(
 def _stream_encode_pipelined(chunks, partition_vocab, nonfinite,
                              value_dtype, pid_enc, pk_enc, encoded_data,
                              encode_threads: int,
-                             pipeline_depth: Optional[int]
-                             ) -> columnar.EncodedData:
+                             pipeline_depth: Optional[int],
+                             load=None) -> columnar.EncodedData:
     """The pipelined body of stream_encode_columns: thread-pool chunk
     factorization -> bounded staging queue -> sequential vocab merge ->
     device-resident bucket accumulation (runtime/pipeline.py)."""
@@ -773,10 +790,10 @@ def _stream_encode_pipelined(chunks, partition_vocab, nonfinite,
 
     acc = rt_pipeline.DeviceRowAccumulator(
         batch_rows=rt_pipeline.APPEND_BATCH_ROWS)
-    worker = functools.partial(_prepare_chunk,
-                               partition_vocab=partition_vocab,
-                               nonfinite=nonfinite,
-                               value_dtype=value_dtype)
+    worker = _loaded(load, functools.partial(_prepare_chunk,
+                                             partition_vocab=partition_vocab,
+                                             nonfinite=nonfinite,
+                                             value_dtype=value_dtype))
     with rt_trace.span("ingest", threads=encode_threads) as ingest_span:
         n_rows = 0
         for idx, prep in enumerate(
@@ -831,8 +848,8 @@ def _hash_empty_encoded(public: bool, value_dtype,
 
 def _stream_encode_hash_device(chunks, public_partitions, nonfinite,
                                encode_threads: int,
-                               pipeline_depth: Optional[int]
-                               ) -> columnar.EncodedData:
+                               pipeline_depth: Optional[int],
+                               load=None) -> columnar.EncodedData:
     """The encode_mode="hash_device" body of stream_encode_columns.
 
     Chunk workers hash (thread pool when encode_threads >= 1, exactly
@@ -864,10 +881,10 @@ def _stream_encode_hash_device(chunks, public_partitions, nonfinite,
         fills=fills, batch_rows=rt_pipeline.APPEND_BATCH_ROWS)
     pid_u1, pid_u2, pid_pos = [], [], []
     pk_u1, pk_u2, pk_keys, pk_pos = [], [], [], []
-    worker = functools.partial(_prepare_hash_chunk,
-                               partition_vocab=partition_vocab,
-                               nonfinite=nonfinite,
-                               value_dtype=value_dtype)
+    worker = _loaded(load, functools.partial(_prepare_hash_chunk,
+                                             partition_vocab=partition_vocab,
+                                             nonfinite=nonfinite,
+                                             value_dtype=value_dtype))
     with rt_trace.span("ingest", encode="hash_device",
                        threads=encode_threads) as ingest_span:
         n_rows = 0
@@ -939,7 +956,8 @@ def _stream_encode_hash_device(chunks, public_partitions, nonfinite,
             return stream_encode_columns(
                 chunks, public_partitions=public_partitions,
                 nonfinite=nonfinite, encode_threads=encode_threads,
-                pipeline_depth=pipeline_depth, encode_mode="host")
+                pipeline_depth=pipeline_depth, encode_mode="host",
+                load=load)
         if bufs is None:
             return _hash_empty_encoded(public, value_dtype,
                                        partition_vocab)

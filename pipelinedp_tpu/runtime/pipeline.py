@@ -39,6 +39,9 @@ device-resident pipeline instead of one serial batch call:
     collection; the executor routes it through the pipelined
     ``ingest.stream_encode_columns`` under the backend's
     ``encode_threads`` / ``pipeline_depth`` knobs.
+    ``ChunkSource.from_netflix_file``'s items are byte ranges of a text
+    file, each read and parsed by the encode worker that then encodes it,
+    so the feeder only counts ranges.
   * **Overlapped drain** (``copy_to_host_async``) — the shared
     async-copy helper (moved here from parallel/large_p.py so the
     executor's dense drain can use it without an import cycle): result
@@ -73,7 +76,7 @@ import math
 import queue
 import threading
 from concurrent import futures as _futures
-from typing import Any, Iterable, Iterator, Optional
+from typing import Any, Callable, Iterable, Iterator, Optional
 
 from pipelinedp_tpu.runtime import faults as rt_faults
 from pipelinedp_tpu.runtime import telemetry as rt_telemetry
@@ -155,6 +158,25 @@ class ChunkSource:
         self.chunks = chunks
         self.nonfinite = nonfinite
         self.encode_mode = encode_mode
+        # None, or the function from one item of `chunks` to its columns,
+        # run on the encode worker: set by from_netflix_file alone.
+        self._load: Optional[Callable[[Any], Any]] = None
+
+    @classmethod
+    def from_netflix_file(cls, path, range_bytes: int = 1 << 24,
+                          encode_mode: Optional[str] = None):
+        """A Netflix Prize format text file (``<movie_id>:`` header lines,
+        ``<user_id>,<rating>,<date>`` data lines) as (user, movie, rating)
+        rows. The items are the file's byte ranges of `range_bytes`; each
+        is read and parsed (``textfile.read_netflix_range``: the movie
+        sections whose header starts in the range) on the encode worker
+        that encodes it. The rows arrive in file order, so the release is
+        the one an in-memory ChunkSource over the parsed columns gives."""
+        from pipelinedp_tpu import textfile
+        ranges = textfile.NetflixFileRanges(path, range_bytes)
+        source = cls(ranges.ranges, encode_mode=encode_mode)
+        source._load = ranges.load
+        return source
 
 
 def _validate_window(encode_threads: int, depth: int) -> None:

@@ -263,6 +263,13 @@ REGISTRY: Dict[str, Metric] = {
                  "hash walks (ingest._fnv_ragged): every key's characters "
                  "once, 4 B a character of a str, 1 B of bytes; numeric "
                  "keys add nothing"),
+        _counter("file_bytes_parsed",
+                 "bytes of text file the byte-range reader parsed "
+                 "(textfile.read_netflix_range: a range's owned movie "
+                 "sections, the read past its end included)"),
+        _counter("file_rows_parsed",
+                 "data rows the byte-range reader parsed out of a text "
+                 "file (textfile.read_netflix_range)"),
         _counter("trace_dropped_events",
                  "trace events dropped because the bounded trace buffer "
                  "was full (trace_summary flags the epoch as truncated)"),

@@ -23,6 +23,10 @@ EXAMPLES = [
         "examples/movie_view_ratings/run_without_frameworks.py",
         "--generate_rows", "5000", "--pld_accounting", "--local"
     ],
+    [
+        "examples/movie_view_ratings/run_without_frameworks.py",
+        "--generate_rows", "5000", "--streaming"
+    ],
     ["examples/restaurant_visits/run_private_api.py", "--rows", "1000"],
     ["examples/restaurant_visits/run_parameter_tuning.py", "--rows", "1000"],
     ["examples/codelab/codelab.py"],

@@ -19,8 +19,12 @@ the mesh layer's two spans. A sixth, a blocked standalone selection
 through `DPEngine.select_partitions`, records the spans and counters the
 cell keys1e7-select-blocked reads. A seventh, string keys through
 `ChunkSource(encode_mode="hash_device")` on the dense route and on the
-blocked one, records what the two hash-route cells read. A rename in the
-program then fails here instead of leaving a null in the ledger.
+blocked one, records what the two hash-route cells read. An eighth, a
+Netflix-format file through `ChunkSource.from_netflix_file`, records the
+read and the parse of its byte ranges (spans ingest.read, ingest.parse;
+counters file_bytes_parsed, file_rows_parsed), what netflix-sum-file
+reads. A rename in the program then fails here instead of leaving a null
+in the ledger.
 """
 
 import json
@@ -285,6 +289,42 @@ def _hash_text_runs():
         assert ("pass1_device_resident" in counted) == bool(route)
 
 
+def _netflix_file_run():
+    """A Netflix-format file of 3,000 rows in byte ranges of 4 KiB through
+    `ChunkSource.from_netflix_file`: each range read and parsed on an
+    encode worker, every byte and row of the file counted once."""
+    import tempfile
+
+    from examples.movie_view_ratings import netflix_format
+
+    with tempfile.TemporaryDirectory() as directory:
+        path = os.path.join(directory, "views.txt")
+        netflix_format.generate_file(path, 3000, n_users=200, n_movies=30,
+                                     seed=10)
+        params = pdp.AggregateParams(
+            metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+            noise_kind=pdp.NoiseKind.LAPLACE,
+            max_partitions_contributed=2,
+            max_contributions_per_partition=1,
+            min_value=1.0,
+            max_value=5.0)
+        ex = pdp.DataExtractors(privacy_id_extractor=lambda r: r[0],
+                                partition_extractor=lambda r: r[1],
+                                value_extractor=lambda r: r[2])
+        acc = pdp.NaiveBudgetAccountant(total_epsilon=50.0,
+                                        total_delta=1e-6)
+        engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=11))
+        before = telemetry.snapshot()
+        result = engine.aggregate(
+            pdp.ChunkSource.from_netflix_file(path, range_bytes=4096),
+            params, ex)
+        acc.compute_budgets()
+        assert dict(result)
+        counted = telemetry.delta(before)
+        assert counted["file_bytes_parsed"] == os.path.getsize(path)
+        assert counted["file_rows_parsed"] == 3000
+
+
 # Counters no tiny run is sure to move: a program already built in this
 # process fires no compile event.
 UNREACHED_COUNTERS = {"backend_compiles"}
@@ -304,6 +344,7 @@ def recorded():
         _meshed_chunk_runs()
         _blocked_select_run()
         _hash_text_runs()
+        _netflix_file_run()
         counters = {name for name, n in telemetry.snapshot().items() if n}
         return set(trace.trace_summary()["spans"]) | counters
     finally:
