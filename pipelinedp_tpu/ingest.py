@@ -11,13 +11,15 @@ time toward max(host encode, device transfer) instead of their sum.
 With encode_threads >= 1 the same entry point routes through the
 device-resident streaming executor (runtime/pipeline.py): the heavy,
 order-independent half of vocabulary encoding (chunk_factorize) runs per
-chunk on a host thread pool feeding a bounded staging queue, the cheap
+chunk on a host thread pool feeding a bounded staging queue, the
 sequential half (ChunkedVocabEncoder.merge) stitches the global
 vocabulary in stream order on the consumer, and rows accumulate into
 persistent, buffer-donated device buffers (DeviceRowAccumulator) sized
 to the executor.pad_rows power-of-two buckets — so the pipelined
 encoding is bit-identical to the serial one, down to the padded kernel
-input arrays.
+input arrays. The merge looks non-negative integer keys of bounded range
+up in one persistent code table indexed by the key, and any other key in
+a pandas Index.
 
 The result is a device-resident EncodedData whose columns are jax arrays;
 the executor pads it on device (executor.pad_rows) and the engine accepts
@@ -71,8 +73,10 @@ def chunk_factorize(raw) -> Tuple[np.ndarray, np.ndarray]:
     The order-independent, C-speed half of ChunkedVocabEncoder.encode —
     pure and thread-safe, so the streaming executor
     (runtime/pipeline.py) can run it per chunk on the host thread pool
-    while the cheap sequential half (``ChunkedVocabEncoder.merge``)
-    stitches the global vocabulary in stream order on the consumer.
+    while the sequential half (``ChunkedVocabEncoder.merge``: a lookup
+    of the chunk's uniques in the integer code table, or in a pandas
+    Index for other keys, and one gather of the chunk's codes) stitches
+    the global vocabulary in stream order on the consumer.
     """
     raw = columnar._as_key_array(raw)
     if _pd is not None:
@@ -100,29 +104,64 @@ def chunk_factorize(raw) -> Tuple[np.ndarray, np.ndarray]:
     return codes.astype(np.int32), uniques
 
 
+# The code table of ChunkedVocabEncoder: integer keys in [0, limit) are
+# looked up by address, where limit is _CODE_TABLE_ROWS_MULTIPLE times the
+# rows merged so far (at least _CODE_TABLE_MIN_KEYS, at most
+# _CODE_TABLE_MAX_KEYS). The int32 table doubles as it grows, so it holds
+# fewer than 16 entries (64 bytes) a row merged, and at most 256 MiB.
+_CODE_TABLE_ROWS_MULTIPLE = 8
+_CODE_TABLE_MIN_KEYS = 1 << 16
+_CODE_TABLE_MAX_KEYS = 1 << 26
+
+
 class ChunkedVocabEncoder:
     """Incremental first-occurrence vocabulary encoding across chunks.
 
     Feeding chunks in order yields exactly the codes columnar.factorize
-    would assign to the concatenation — on the pandas path and on the
-    vectorized numpy fallback, including NaN unification (all NaN keys
-    share one code, kept out of the sorted vocabulary where comparisons
-    would mis-place it) and cross-chunk dtype promotion (a later chunk
-    with a wider string / finer numeric dtype widens the stored
-    vocabulary instead of truncating new keys). Per chunk: factorization
-    (C speed) followed by a vectorized remap of the chunk's uniques
-    against a sorted copy of the vocabulary (searchsorted + insert,
-    O(V + new·log new)). Only key types numpy cannot order fall back to
-    a per-unique dict loop, which canonicalizes NaN through the same
-    shared sentinel columnar.factorize's last-resort branch uses.
+    would assign to the concatenation. With pandas, integer keys go to a
+    direct-address code table and every other key to a pandas Index; the
+    vectorized numpy fallback and the dict loop serve where pandas is
+    missing.
+
+    The code table: while every chunk's uniques are non-negative integers
+    (dtype kind i or u) below the limit (_CODE_TABLE_ROWS_MULTIPLE x the
+    rows merged so far, at least _CODE_TABLE_MIN_KEYS and at most
+    _CODE_TABLE_MAX_KEYS), the encoder keeps an int32 array code_of[key],
+    -1 where no code is assigned, and the keys in code order. A chunk's
+    uniques look themselves up in it, the unknown ones take the next codes
+    in the chunk's first-occurrence order and are written back: a gather
+    per chunk, where an Index re-hashes each chunk's uniques and rebuilds
+    its hash engine whenever it grows. The first chunk outside that rule
+    (a negative or too large key, a float, string or object chunk, or an
+    integer dtype whose promotion with the vocabulary's is not an integer)
+    builds the Index once from the keys in code order, and the pandas path
+    serves from that chunk on. Codes, ``vocabulary`` (values and dtype,
+    which follows the Index's promotion) and ``len`` are those the pandas
+    path alone gives.
+
+    Without pandas: factorization (C speed) followed by a vectorized remap
+    of the chunk's uniques against a sorted copy of the vocabulary
+    (searchsorted + insert, O(V + new·log new)), including NaN
+    unification (all NaN keys share one code, kept out of the sorted
+    vocabulary where comparisons would mis-place it) and cross-chunk dtype
+    promotion (a later chunk with a wider string / finer numeric dtype
+    widens the stored vocabulary instead of truncating new keys). Only key
+    types numpy cannot order fall back to a per-unique dict loop, which
+    canonicalizes NaN through the same shared sentinel
+    columnar.factorize's last-resort branch uses.
     """
 
     def __init__(self):
+        self._rows = 0  # rows merged so far (sets the code table's limit)
+        self._table = None  # int32 code_of[key], -1 where unassigned
+        self._table_keys: List[np.ndarray] = []  # new keys, in code order
+        self._table_dtype = None  # the dtype the pandas Index would hold
         self._index = None  # pandas Index (fast path)
         self._sorted_vocab = None  # numpy fallback: sorted non-NaN uniques
         self._sorted_codes = None  # global code of each sorted entry
         self._nan_code: Optional[int] = None  # shared code for NaN keys
-        self._next_code = 0  # total codes assigned on the numpy fallback
+        # Total codes assigned on the code table or the numpy fallback.
+        self._next_code = 0
         self._dict: Optional[dict] = None  # unorderable-key last resort
 
     def encode(self, raw) -> np.ndarray:
@@ -139,6 +178,13 @@ class ChunkedVocabEncoder:
         concatenation — the pipelined encode calls this on the consumer
         while workers factorize chunks ahead."""
         if _pd is not None:
+            self._rows += len(codes)
+            if self._index is None:
+                if self._fits_code_table(uniques):
+                    return self._merge_code_table(codes, uniques)
+                if self._table is not None:
+                    self._index = _pd.Index(self.vocabulary)
+                    self._table, self._table_keys = None, []
             uniques = _pd.Index(uniques)
             if self._index is None:
                 self._index = uniques
@@ -157,6 +203,48 @@ class ChunkedVocabEncoder:
         except TypeError:  # unorderable mixed-type keys
             self._spill_to_dict()
             return self._remap_dict(codes, uniques)
+
+    def _fits_code_table(self, uniques: np.ndarray) -> bool:
+        """Whether the code table takes this chunk: the class docstring's
+        rule."""
+        if uniques.dtype.kind not in "iu" or (
+                self._table_dtype is not None and np.promote_types(
+                    self._table_dtype, uniques.dtype).kind not in "iu"):
+            return False
+        if not len(uniques):
+            return True
+        limit = min(max(_CODE_TABLE_ROWS_MULTIPLE * self._rows,
+                        _CODE_TABLE_MIN_KEYS), _CODE_TABLE_MAX_KEYS)
+        return int(uniques.min()) >= 0 and int(uniques.max()) < limit
+
+    def _merge_code_table(self, codes: np.ndarray,
+                          uniques: np.ndarray) -> np.ndarray:
+        from pipelinedp_tpu.runtime import telemetry as rt_telemetry
+
+        if self._table_dtype is None:
+            self._table_dtype = uniques.dtype
+        top = int(uniques.max()) + 1 if len(uniques) else 0
+        size = 0 if self._table is None else len(self._table)
+        if self._table is None or top > size:
+            grown = np.full(min(max(top, 2 * size), _CODE_TABLE_MAX_KEYS),
+                            -1, np.int32)
+            if size:
+                grown[:size] = self._table
+            self._table = grown
+        look = self._table[uniques]
+        new = np.flatnonzero(look < 0)
+        if len(new):
+            look[new] = np.arange(self._next_code,
+                                  self._next_code + len(new), dtype=np.int32)
+            keys = uniques[new]
+            self._table[keys] = look[new]
+            self._table_dtype = np.promote_types(self._table_dtype,
+                                                 keys.dtype)
+            self._table_keys.append(keys)
+            self._next_code += len(new)
+        rt_telemetry.record("vocab_table_merges")
+        # take: about twice as fast as indexing by the int32 codes.
+        return look.take(codes)
 
     def _remap_sorted(self, codes: np.ndarray,
                       uniques: np.ndarray) -> np.ndarray:
@@ -266,6 +354,11 @@ class ChunkedVocabEncoder:
     def vocabulary(self) -> Sequence[Any]:
         if self._index is not None:
             return np.asarray(self._index)
+        if self._table is not None:
+            if len(self._table_keys) != 1:
+                self._table_keys = [np.concatenate(
+                    self._table_keys or [np.empty(0, self._table_dtype)])]
+            return self._table_keys[0].astype(self._table_dtype)
         if self._sorted_vocab is not None:
             dt = self._sorted_vocab.dtype
             if self._nan_code is not None:
@@ -290,7 +383,7 @@ class ChunkedVocabEncoder:
     def __len__(self) -> int:
         if self._index is not None:
             return len(self._index)
-        if self._sorted_vocab is not None:
+        if self._table is not None or self._sorted_vocab is not None:
             return self._next_code
         return len(self._dict or ())
 

@@ -258,6 +258,13 @@ REGISTRY: Dict[str, Metric] = {
                  "unique merge sorts (device_encode.merge_hash_uniques), "
                  "privacy ids and partition keys together, added by every "
                  "call of the merge"),
+        _counter("vocab_table_merges",
+                 "key columns of a chunk that the host vocabulary merge "
+                 "looked up in its integer code table "
+                 "(ingest.ChunkedVocabEncoder): one per column per chunk "
+                 "while the keys stay non-negative integers inside the "
+                 "table's limit, none once a column left it for the "
+                 "pandas Index"),
         _counter("hash_key_bytes",
                  "bytes of code units the hash-device encode's string "
                  "hash walks (ingest._fnv_ragged): every key's characters "

@@ -17,7 +17,88 @@ from examples.movie_view_ratings import netflix_format  # noqa: E402
 HUGE_EPS = 1e7
 
 
+def _int_id_chunks(dtype, sparse=False, n=12_000, size=1_500):
+    """Heavy-tailed integer ids in chunks; sparse: spaced like the Netflix
+    Prize's customer ids (k * 5 + 6)."""
+    rng = np.random.default_rng(2)
+    ids = (rng.random(n)**2 * 3000).astype(np.int64)
+    if sparse:
+        ids = ids * 5 + 6
+    ids = ids.astype(dtype)
+    return [ids[i:i + size] for i in range(0, n, size)]
+
+
+# Chunk streams for the integer code table, with the chunks it merges
+# before a chunk leaves its rule and the pandas Index serves the rest.
+CODE_TABLE_CASES = {
+    "netflix_ids": (_int_id_chunks(np.int64, sparse=True), 8),
+    "int32": (_int_id_chunks(np.int32), 8),
+    "int64": (_int_id_chunks(np.int64), 8),
+    "uint32": (_int_id_chunks(np.uint32), 8),
+    "single_chunk": ([np.array([5, 5, 7, 5, 9, 7])], 1),
+    "empty_chunks": ([np.zeros(0, np.int64), np.array([4, 2, 4]),
+                      np.zeros(0, np.int64), np.array([2, 8])], 4),
+    "table_grows": ([np.arange(10_000) % 100,
+                     np.array([70_000, 5, 69_999])], 2),
+    "key_beyond_limit": ([np.array([3, 1, 3]), np.array([1, 5_000_000, 2]),
+                          np.array([2, 7])], 1),
+    "negative_key": ([np.array([3, 1]), np.array([-1, 3]),
+                      np.array([4, -1])], 1),
+    "float_after_int": ([np.array([1, 3, 5]), np.array([3.0, 2.5, 1.0]),
+                         np.array([5, 2])], 1),
+    "string_after_int": ([np.array([1, 3]),
+                          np.array(["3", "a", 1], dtype=object)], 1),
+    "mixed_widths": ([np.zeros(0, np.int8), np.array([3, 1], np.int32),
+                      np.array([1, 9], np.int64),
+                      np.array([9, 2], np.uint8)], 4),
+    "uint64_after_int64": ([np.array([1, 3]),
+                            np.array([2, 3], np.uint64)], 1),
+    # merge_host_vocabularies skips an empty host vocabulary.
+    "host_vocabularies": ([np.array([11, 6, 21]), np.array([6, 16]),
+                           np.zeros(0, np.int64), np.array([26, 11])], 3),
+}
+
+
 class TestChunkedVocabEncoder:
+
+    @staticmethod
+    def _table_run(case):
+        """(codes of each chunk, vocabulary, len, code table merges)."""
+        from pipelinedp_tpu.runtime import telemetry
+
+        chunks = CODE_TABLE_CASES[case][0]
+        before = telemetry.snapshot()
+        if case == "host_vocabularies":
+            vocab, codes = ingest.merge_host_vocabularies(chunks)
+            n = len(vocab)
+        else:
+            enc = ingest.ChunkedVocabEncoder()
+            codes = [enc.encode(chunk) for chunk in chunks]
+            vocab, n = enc.vocabulary, len(enc)
+        merges = telemetry.delta(before).get("vocab_table_merges", 0)
+        return codes, vocab, n, merges
+
+    @pytest.mark.parametrize("case", list(CODE_TABLE_CASES))
+    def test_code_table_matches_pandas_path(self, monkeypatch, case):
+        chunks, table_merges = CODE_TABLE_CASES[case]
+        codes, vocab, n, merges = self._table_run(case)
+        assert merges == table_merges
+        monkeypatch.setattr(ingest.ChunkedVocabEncoder, "_fits_code_table",
+                            lambda self, uniques: False)
+        want_codes, want_vocab, want_n, merges = self._table_run(case)
+        assert merges == 0
+        for got, want in zip(codes, want_codes, strict=True):
+            assert got.dtype == want.dtype == np.int32
+            np.testing.assert_array_equal(got, want)
+        assert vocab.dtype == want_vocab.dtype
+        assert list(vocab) == list(want_vocab)
+        assert n == want_n == len(vocab)
+        flat_codes, flat_vocab = columnar.factorize(np.concatenate(chunks))
+        np.testing.assert_array_equal(np.concatenate(codes), flat_codes)
+        assert vocab.dtype == flat_vocab.dtype
+        assert list(vocab) == list(flat_vocab)
+        if case == "float_after_int":
+            np.testing.assert_array_equal(codes[1], [1, 3, 0])
 
     def test_matches_global_factorize(self):
         rng = np.random.default_rng(0)
@@ -346,6 +427,56 @@ class TestStreamEncodeEngine:
             self._chunks(pid, pk, values, 900), public_partitions=["m0"])
         with pytest.raises(ValueError, match="same public partitions"):
             self._aggregate(encoded, public=["m0", "m1"])
+
+    def test_code_table_release_is_bit_identical(self, monkeypatch):
+        """A ChunkSource job on integer ids releases the same partitions
+        and values, bit for bit under one noise seed, with the code table
+        and without it. The table merges both key columns of every chunk,
+        and no column of string keys."""
+        from pipelinedp_tpu.runtime import telemetry
+
+        rng = np.random.default_rng(12)
+        n = 6000
+        pid = (rng.random(n)**2 * 500).astype(np.int64) * 5 + 6
+        pk = (rng.random(n)**2.5 * 40).astype(np.int64) + 1
+        values = rng.choice(np.arange(1.0, 6.0), n)
+        params = pdp.AggregateParams(
+            metrics=[pdp.Metrics.COUNT, pdp.Metrics.SUM],
+            noise_kind=pdp.NoiseKind.LAPLACE,
+            max_partitions_contributed=2,
+            max_contributions_per_partition=1,
+            min_value=1.0,
+            max_value=5.0)
+        extractors = pdp.DataExtractors(privacy_id_extractor=lambda r: r[0],
+                                        partition_extractor=lambda r: r[1],
+                                        value_extractor=lambda r: r[2])
+
+        def release(pid, pk):
+            chunks = list(self._chunks(pid, pk, values, 1500))
+            acc = pdp.NaiveBudgetAccountant(total_epsilon=1.0,
+                                            total_delta=1e-6)
+            engine = pdp.DPEngine(acc, pdp.TPUBackend(noise_seed=13))
+            before = telemetry.snapshot()
+            result = engine.aggregate(
+                pdp.ChunkSource(chunks, encode_mode="host"), params,
+                extractors)
+            acc.compute_budgets()
+            result = dict(result)
+            return result, telemetry.delta(before).get(
+                "vocab_table_merges", 0)
+
+        table, merges = release(pid, pk)
+        assert merges == 2 * 4
+        with monkeypatch.context() as patch:
+            patch.setattr(ingest.ChunkedVocabEncoder, "_fits_code_table",
+                          lambda self, uniques: False)
+            index, merges = release(pid, pk)
+        assert merges == 0
+        assert table and list(table) == list(index)
+        for key, row in table.items():
+            assert tuple(row) == tuple(index[key]), key
+        _, merges = release(pid.astype(str), pk.astype(str))
+        assert merges == 0
 
     def test_empty_chunk_iter(self):
         encoded = ingest.stream_encode_columns(iter(()))
